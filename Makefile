@@ -141,18 +141,8 @@ test-protocheck: modelcheck
 native:
 	$(MAKE) -C $(NATIVE)
 
-# Run the prebuilt ASan/UBSan + TSan self-tests of the native core
-# (workqueue, expectations, event hub, reconciler, metastore). The
-# checked-in binaries are the fast path; a binary that is missing or was
-# linked against a sanitizer runtime this machine doesn't ship (ldd
-# reports 'not found') is rebuilt from source first.
+# Build and run the ASan/UBSan + TSan self-tests of the native core
+# (workqueue, expectations, event hub, reconciler, metastore). Nothing
+# under native/build/ is tracked: every binary is made from src/*.cc here.
 selftest-sanitizers:
-	@for t in selftest_asan selftest_tsan; do \
-	  bin=$(NATIVE)/build/$$t; \
-	  if ! ldd $$bin >/dev/null 2>&1 || ldd $$bin | grep -q "not found"; then \
-	    echo "rebuilding $$t (prebuilt binary not runnable here)"; \
-	    $(MAKE) -B -C $(NATIVE) build/$$t || exit 1; \
-	  fi; \
-	done
-	$(NATIVE)/build/selftest_asan
-	$(NATIVE)/build/selftest_tsan
+	$(MAKE) -C $(NATIVE) check tsan

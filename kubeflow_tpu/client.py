@@ -417,9 +417,11 @@ class TrainingClient:
             )
             detail = f": {failed.message}" if failed and failed.message else ""
             raise RuntimeError(f"train job {name} failed{detail}")
-        from kubeflow_tpu.train.metrics import extract_final_metrics
+        # the sweep collector's parser: importing the train package would
+        # pull jax into the control-plane process
+        from kubeflow_tpu.sweep.collector import final_metrics_from_log
 
-        return extract_final_metrics(self.get_job_logs(name, namespace))
+        return final_metrics_from_log(self.get_job_logs(name, namespace))
 
     def wait_for_job_conditions(
         self,

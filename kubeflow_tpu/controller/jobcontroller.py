@@ -50,6 +50,10 @@ from kubeflow_tpu.health import (
 from kubeflow_tpu.native import Expectations
 from kubeflow_tpu.runtime.rendezvous import LocalResolver
 from kubeflow_tpu.tracing import ENV_TRACE_DIR, ENV_TRACEPARENT, current_context
+from kubeflow_tpu.utils.compile_cache import (
+    ENV_JAX_CACHE_DIR,
+    resolve_cache_dir,
+)
 from kubeflow_tpu.utils.envvars import ENV_COMPILE_CACHE_DIR, ENV_STATE_DIR
 from kubeflow_tpu.utils.retry import BackoffPolicy, with_conflict_retry
 
@@ -102,9 +106,8 @@ class JobController(ControllerBase):
         # job (entries are content-keyed, so sharing one dir is safe): a
         # gang-restarted worker replays its train-step executables instead
         # of re-tracing+recompiling (utils/compile_cache.py, docs/perf.md)
-        self.compile_cache_dir = compile_cache_dir or os.path.join(
-            os.environ.get(ENV_STATE_DIR, ".kubeflow_tpu"), "compile-cache"
-        )
+        self.compile_cache_dir = resolve_cache_dir(
+            compile_cache_dir, default=True)
         self._resolvers: dict[str, LocalResolver] = {}
         # prometheus-style counters (SURVEY.md §5.5)
         self.metrics.update({
@@ -409,8 +412,11 @@ class JobController(ControllerBase):
             ))
             # restart-warm compile contract: unlike the heartbeat path the
             # cache dir is NOT per-incarnation — surviving the restart is
-            # the whole point (the restarted worker's warm_start hits it)
-            env.setdefault(ENV_COMPILE_CACHE_DIR, self.compile_cache_dir)
+            # the whole point (the restarted worker's warm_start hits it).
+            # With JAX_COMPILATION_CACHE_DIR set the pod inherits the
+            # directory with the rest of the environment.
+            if not os.environ.get(ENV_JAX_CACHE_DIR):
+                env.setdefault(ENV_COMPILE_CACHE_DIR, self.compile_cache_dir)
             c = job.spec.replica_specs[rtype].template.container
             # job-level labels (e.g. the experiment label) propagate to pods,
             # mirroring k8s template-label propagation

@@ -57,6 +57,31 @@ def _die_with_parent(runtime_pid: int) -> None:
             os._exit(1)
 
 
+#: how long a SIGKILLed pod process may take to be gone. The kernel tears
+#: a killed process down promptly; the bound only keeps a process stuck in
+#: an uninterruptible device call from wedging the runtime forever.
+KILL_REAP_TIMEOUT_S = 30.0
+
+
+def _kill_and_reap(proc: subprocess.Popen) -> None:
+    """SIGKILL a pod's whole session (pods may fork workers), then wait
+    until the process is gone. An accelerator belongs to one process at a
+    time: a successor spawned while the killed owner is still being torn
+    down finds the device busy, so every kill path reaps before it
+    returns."""
+    try:
+        os.killpg(proc.pid, signal.SIGKILL)
+    except (ProcessLookupError, PermissionError):
+        try:
+            proc.kill()
+        except ProcessLookupError:
+            pass
+    try:
+        proc.wait(timeout=KILL_REAP_TIMEOUT_S)
+    except subprocess.TimeoutExpired:
+        pass  # the _reap thread still owns the final wait
+
+
 class PodRuntime:
     """Watches pods; launches bound ones as subprocesses; reaps exits."""
 
@@ -124,14 +149,7 @@ class PodRuntime:
         with self._mu:
             procs = [proc for _, proc in self._procs.values()]
         for p in procs:
-            # kill the whole session (pods may fork workers), like _kill does
-            try:
-                os.killpg(p.pid, signal.SIGKILL)
-            except (ProcessLookupError, PermissionError):
-                try:
-                    p.kill()
-                except ProcessLookupError:
-                    pass
+            _kill_and_reap(p)
 
     # ---------------------------------------------------------------- watching
 
@@ -248,11 +266,8 @@ class PodRuntime:
                 if held_uid == pod.metadata.uid:
                     return  # already running this incarnation
                 # same name, new incarnation (gang restart): the old process
-                # must die before the new one starts
-                try:
-                    os.killpg(held_proc.pid, signal.SIGKILL)
-                except (ProcessLookupError, PermissionError):
-                    pass
+                # must be GONE before the new one starts
+                _kill_and_reap(held_proc)
             log_path = self.log_path(pod.metadata.name, pod.metadata.namespace)
             log_path.parent.mkdir(parents=True, exist_ok=True)
             env = dict(os.environ) if self.inherit_env else {}
@@ -380,14 +395,7 @@ class PodRuntime:
                     table.pop(k, None)
             held = self._procs.pop(key, None)
         if held is not None:
-            _, proc = held
-            try:
-                os.killpg(proc.pid, signal.SIGKILL)
-            except (ProcessLookupError, PermissionError):
-                try:
-                    proc.kill()
-                except ProcessLookupError:
-                    pass
+            _kill_and_reap(held[1])
 
     # -------------------------------------------------------------- liveness
 

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from kubeflow_tpu.utils import compat
 from kubeflow_tpu.parallel.mesh import (
     AXIS_CONTEXT,
     AXIS_DATA,
@@ -61,7 +60,7 @@ ACT_SPEC = P((AXIS_DATA, AXIS_FSDP, AXIS_EXPERT), AXIS_CONTEXT, None)
 
 def constrain(x: jax.Array, spec: P) -> jax.Array:
     """Sharding pin that is a no-op when no ambient mesh is set."""
-    if compat.get_abstract_mesh().empty:
+    if jax.sharding.get_abstract_mesh().empty:
         return x
     return jax.lax.with_sharding_constraint(x, spec)
 
@@ -78,11 +77,11 @@ class VocabEmbed(nn.Embed):
     """
 
     def __call__(self, inputs: jax.Array) -> jax.Array:
-        mesh = compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if mesh.empty:
             return super().__call__(inputs)
-        (table,) = compat.promote_dtype(self, self.embedding,
-                                        dtype=self.dtype, inexact=False)
+        (table,) = self.promote_dtype(self.embedding, dtype=self.dtype,
+                                      inexact=False)
         if mesh.shape.get(AXIS_MODEL, 1) > 1:
             onehot = jax.nn.one_hot(inputs, self.num_embeddings, dtype=table.dtype)
             return jnp.dot(onehot, table)

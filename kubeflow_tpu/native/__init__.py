@@ -9,36 +9,62 @@ C++ MLMD server). Built on demand with `make`; sanitizer self-tests run via
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import os
 import subprocess
-import threading
 import weakref
 from pathlib import Path
 
 _DIR = Path(__file__).parent
 _LIB_PATH = _DIR / "build" / "libkfcore.so"
-_BUILD_LOCK = threading.Lock()
+#: sha256 of the sources the library was built from, written beside it
+_HASH_PATH = _LIB_PATH.with_suffix(".so.srchash")
 _lib = None
 
 
-def ensure_built() -> Path:
-    """Build libkfcore.so if missing or stale (source newer than lib)."""
-    srcs = sorted((_DIR / "src").glob("*.cc"))
+def _source_hash() -> str:
+    """Content hash of everything that goes into libkfcore.so. Content, not
+    mtimes: a checkout or a copy of the tree does not preserve those."""
     # selftest-only sources never link into the lib — not staleness signals
-    _selftest_only = {"selftest.cc", "tsan_clockwait_shim.cc"}
-    stale = not _LIB_PATH.exists() or any(
-        s.stat().st_mtime > _LIB_PATH.stat().st_mtime
-        for s in srcs
-        if s.name not in _selftest_only
-    )
-    if stale:
-        with _BUILD_LOCK:
-            subprocess.run(
-                ["make", str(_LIB_PATH.relative_to(_DIR))],
-                cwd=_DIR,
-                check=True,
-                capture_output=True,
-            )
+    selftest_only = {"selftest.cc", "tsan_clockwait_shim.cc"}
+    h = hashlib.sha256()
+    for src in [_DIR / "Makefile", *sorted((_DIR / "src").glob("*.cc"))]:
+        if src.name not in selftest_only:
+            h.update(src.name.encode() + b"\0" + src.read_bytes() + b"\0")
+    return h.hexdigest()
+
+
+def ensure_built() -> Path:
+    """Build libkfcore.so from src/*.cc when it is missing or was built from
+    other sources (build/ is not tracked: a fresh checkout always builds).
+    Serialized by a lock file — pods and test workers may import the
+    package at the same moment."""
+    want = _source_hash()
+
+    def fresh() -> bool:
+        try:
+            return _LIB_PATH.exists() and _HASH_PATH.read_text() == want
+        except OSError:
+            return False
+
+    if fresh():
+        return _LIB_PATH
+    _LIB_PATH.parent.mkdir(exist_ok=True)
+    # one open file description per caller, so the lock also serializes
+    # threads of this process
+    with open(_LIB_PATH.parent / ".build.lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if not fresh():  # another caller may have built it meanwhile
+            try:
+                subprocess.run(
+                    ["make", "-B", str(_LIB_PATH.relative_to(_DIR))],
+                    cwd=_DIR, check=True, capture_output=True, text=True)
+            except subprocess.CalledProcessError as exc:
+                raise RuntimeError(
+                    "building kubeflow_tpu/native/build/libkfcore.so "
+                    f"failed:\n{exc.stderr[-2000:]}") from exc
+            _HASH_PATH.write_text(want)
     return _LIB_PATH
 
 

@@ -23,8 +23,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from kubeflow_tpu.utils import compat
-
 AXIS_DATA = "data"
 AXIS_FSDP = "fsdp"
 AXIS_MODEL = "model"
@@ -165,11 +163,8 @@ def in_manual_region() -> bool:
     depend on every manual-region author remembering the marker."""
     if _IN_MANUAL_REGION.get():
         return True
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
         return False
-    try:
-        manual = jax.sharding.AxisType.Manual
-        return any(t == manual for t in mesh.axis_types)
-    except AttributeError:  # older jax without axis_types/AxisType
-        return False
+    manual = jax.sharding.AxisType.Manual
+    return any(t == manual for t in mesh.axis_types)

@@ -38,7 +38,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from kubeflow_tpu.utils import compat
 from kubeflow_tpu.parallel.mesh import (
     AXIS_CONTEXT,
     AXIS_DATA,
@@ -184,7 +183,7 @@ class MoeMlp(nn.Module):
             y = jnp.einsum("te,eth->th", weight.astype(xt.dtype), down)
             return y.reshape(b, l, h)
 
-        mesh = compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         ep = 1 if mesh.empty else mesh.shape.get(AXIS_EXPERT, 1)
         if e % ep:
             raise ValueError(f"num_experts {e} not divisible by expert axis {ep}")

@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from kubeflow_tpu.utils import compat
 from kubeflow_tpu.parallel.mesh import AXIS_PIPELINE, manual_region
 
 
@@ -46,7 +45,7 @@ def _pin(tree: Any, batch_dim: int) -> Any:
     layout so the partitioner never falls back to full rematerialization."""
     from kubeflow_tpu.parallel.sharding import BATCH_AXES
 
-    if compat.get_abstract_mesh().empty:
+    if jax.sharding.get_abstract_mesh().empty:
         return tree
 
     def one(a):
@@ -110,7 +109,7 @@ def gpipe(
     leaves may be sharded over the data-like mesh axes — those shardings
     stay automatic inside the ring.
     """
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     n_stages = _n_stages(params_stacked)
     pp = 1 if mesh.empty else mesh.shape.get(axis_name, 1)
     leaves = jax.tree.leaves(x)

@@ -218,9 +218,9 @@ class RemoteClient:
                 else f": {sorted(c['type'] for c in conds)}"
             )
             raise RuntimeError(f"train job {name} failed{detail}")
-        from kubeflow_tpu.train.metrics import extract_final_metrics
+        from kubeflow_tpu.sweep.collector import final_metrics_from_log
 
-        return extract_final_metrics(self.job_logs(name, namespace))
+        return final_metrics_from_log(self.job_logs(name, namespace))
 
     # ------------------------------------------------------------- pipelines
 

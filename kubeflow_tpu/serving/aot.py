@@ -79,15 +79,22 @@ def export_predictor(
     return model_dir / AOT_FILE
 
 
+def artifact_platforms(model_dir: str | Path) -> list[str] | None:
+    """The platforms the artifact in `model_dir` was exported for; None
+    when the directory holds no (complete) artifact."""
+    model_dir = Path(model_dir)
+    if not (model_dir / AOT_FILE).exists() or not (model_dir / AOT_META).exists():
+        return None
+    meta = json.loads((model_dir / AOT_META).read_text())
+    return list(meta.get("platforms", []))
+
+
 def aot_available(model_dir: str | Path) -> bool:
     """True when an artifact exists AND targets the running backend."""
     import jax
 
-    model_dir = Path(model_dir)
-    if not (model_dir / AOT_FILE).exists() or not (model_dir / AOT_META).exists():
-        return False
-    meta = json.loads((model_dir / AOT_META).read_text())
-    return jax.default_backend() in meta.get("platforms", [])
+    platforms = artifact_platforms(model_dir)
+    return platforms is not None and jax.default_backend() in platforms
 
 
 def load_exported(model_dir: str | Path):

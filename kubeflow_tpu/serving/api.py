@@ -57,7 +57,8 @@ class PredictorSpec:
     device: str = ""
     # JAX runtime only: export + serialize the compiled predictor at deploy
     # (serving/aot.py) — replicas load the artifact without retracing, and
-    # with a KFT_COMPILE_CACHE env the restart path compiles nothing
+    # against the compile cache the deploy warmed (utils/compile_cache.py
+    # resolve_cache_dir) the restart path compiles nothing
     aot: bool = False
 
 

@@ -1077,7 +1077,6 @@ def spawn_pod(name: str, spec: dict, state_dir: str, *,
     if transport == "tcp":
         env[ENV_POD_PORT_FILE] = port_file
     env[ENV_HEARTBEAT_FILE] = hb_path
-    env["JAX_PLATFORMS"] = "cpu"
     env.update(env_extra or {})
     with open(stderr_path, "ab") as errf:
         proc = subprocess.Popen(

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import importlib
 import json
+import sys
 from pathlib import Path
 from typing import Any
 
@@ -298,6 +299,12 @@ def _load_predict_fn(model_dir: Path):
     return predict_fn, config, example
 
 
+def _log_load(name: str, how: str) -> None:
+    """Say which path loaded the predictor — on stderr: the server's log
+    takes both streams, and library callers (the CLI) own their stdout."""
+    print(f"predictor {name}: {how}", file=sys.stderr, flush=True)
+
+
 class JaxModel(Model):
     """In-tree-family predictor.
 
@@ -339,6 +346,7 @@ class JaxModel(Model):
                     "compose with beam search (num_beams == 1)")
             from kubeflow_tpu.serving.continuous import ContinuousBatcher
 
+            _log_load(self.name, "continuous engine, jit at load")
             module, variables, self.config = load_generative_model(
                 self.model_dir)
             eos = gen.get("eos_token_id")
@@ -403,6 +411,7 @@ class JaxModel(Model):
             return
 
         if aot.aot_available(self.model_dir):
+            _log_load(self.name, "loading the AOT artifact")
             self.config = json.loads((self.model_dir / CONFIG_FILE).read_text())
             meta = json.loads((self.model_dir / aot.AOT_META).read_text())
             call = aot.load_exported(self.model_dir)
@@ -417,6 +426,11 @@ class JaxModel(Model):
             self.ready = True
             return
 
+        exported_for = aot.artifact_platforms(self.model_dir)
+        _log_load(self.name, "jit at load" + (
+            f" — the AOT artifact was exported for {exported_for}, this "
+            f"backend is {jax.default_backend()!r}"
+            if exported_for is not None else ""))
         predict_fn, self.config, example = _load_predict_fn(self.model_dir)
         predict_fn = jax.jit(predict_fn)
         # warmup: trace+compile on the recorded signature

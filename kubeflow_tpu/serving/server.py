@@ -563,7 +563,9 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--explainer-class", default="")
     ap.add_argument("--port", type=int, default=8080)
     ap.add_argument("--host", default="127.0.0.1")
-    ap.add_argument("--device", default="", help="tpu|cpu (default: env)")
+    ap.add_argument("--device", default="auto",
+                    choices=["tpu", "cpu", "auto"],
+                    help="auto = what JAX_PLATFORMS says, else tpu")
     ap.add_argument("--aot", action="store_true",
                     help="jax runtime: export+serialize the compiled "
                          "predictor at load if no artifact exists; replicas "
@@ -580,17 +582,10 @@ def main(argv: list[str] | None = None) -> None:
                     help=">=0 also serves the v2 OIP over gRPC (0 = ephemeral)")
     args = ap.parse_args(argv)
 
-    if args.device:
-        from kubeflow_tpu.utils.device import select_device
+    from kubeflow_tpu.utils.device import select_device
 
-        select_device(args.device)
-
-    if os.environ.get("KFT_COMPILE_CACHE"):
-        # persistent XLA compile cache (serving/aot.py): pointed at the
-        # cache the deploy step warmed, an AOT cold start compiles nothing
-        from kubeflow_tpu.serving.aot import _compile_cache_on
-
-        _compile_cache_on(os.environ["KFT_COMPILE_CACHE"])
+    # like every other entry point: never a quiet run on the host
+    select_device(args.device)
 
     if args.runtime == "custom":
         cls = load_model_class(args.model_class)
@@ -600,14 +595,25 @@ def main(argv: list[str] | None = None) -> None:
         if args.storage_uri:
             model_dir = pull_model(args.storage_uri, f"{args.model_dir}/{args.model_name}")
         if args.runtime == "jax":
+            from kubeflow_tpu.train import metrics as metrics_lib
+            from kubeflow_tpu.utils.compile_cache import (
+                enable_persistent_cache,
+                resolve_cache_dir,
+            )
+            from kubeflow_tpu.utils.device import device_summary
+
+            # persistent XLA compile cache (utils/compile_cache.py): a
+            # replica restarted against the directory its predecessor
+            # warmed compiles nothing; inference programs are safe under it
+            cache_dir = resolve_cache_dir(default=True)
+            enable_persistent_cache(cache_dir)
+            # the device this server answers from, as jax reports it
+            metrics_lib.emit(**device_summary())
             if args.aot:
                 from kubeflow_tpu.serving.aot import aot_available, export_predictor
 
                 if not aot_available(model_dir):
-                    export_predictor(
-                        model_dir,
-                        compile_cache=os.environ.get("KFT_COMPILE_CACHE") or None,
-                    )
+                    export_predictor(model_dir, compile_cache=cache_dir)
             model = JaxModel(args.model_name, model_dir)
         else:
             from kubeflow_tpu.serving.runtimes import build_runtime

@@ -43,6 +43,13 @@ def parse_metrics(text: str, names: set[str] | None = None) -> dict[str, list[fl
     return out
 
 
+def final_metrics_from_log(text: str) -> dict[str, float]:
+    """Latest `final_*` scalars of a worker log (the train() helpers'
+    contract)."""
+    return {name: vals[-1] for name, vals in parse_metrics(text).items()
+            if name.startswith("final_")}
+
+
 def observation_from_log(
     text: str, objective_metric: str, additional: list[str] | None = None
 ) -> Observation:

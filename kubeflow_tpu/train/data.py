@@ -204,11 +204,11 @@ def prefetch_to_device(
     """
     from collections import deque
 
-    from kubeflow_tpu.parallel.sharding import shard_batch
-    from kubeflow_tpu.utils import compat
+    import jax
 
+    from kubeflow_tpu.parallel.sharding import shard_batch
     buf: deque = deque()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for b in it:
             buf.append(shard_batch(b, mesh, process_local=process_local))
             if len(buf) >= size:
@@ -314,9 +314,9 @@ class AsyncLoader:
     def _run(self) -> None:
         try:
             if self._mesh is not None:
-                from kubeflow_tpu.utils import compat
+                import jax
 
-                with compat.set_mesh(self._mesh):
+                with jax.set_mesh(self._mesh):
                     self._produce()
             else:
                 self._produce()

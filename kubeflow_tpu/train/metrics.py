@@ -9,6 +9,7 @@ can collect objectives without instrumentation.
 
 from __future__ import annotations
 
+import json
 import re
 import sys
 import time
@@ -19,13 +20,16 @@ METRIC_RE = re.compile(
 )
 
 
-def emit(step: int | None = None, file=None, **metrics: float) -> str:
-    """Print one metrics line: `step=3 loss=0.123 accuracy=0.98`."""
+def emit(step: int | None = None, file=None, **metrics: float | str) -> str:
+    """Print one metrics line: `step=3 loss=0.123 accuracy=0.98`. String
+    values (the start-up device line's `platform`, `device_kind`) print
+    JSON-quoted, which the numeric collector regex skips."""
     parts = []
     if step is not None:
         parts.append(f"step={step}")
     for k, v in metrics.items():
-        parts.append(f"{k}={float(v):.6g}")
+        parts.append(f"{k}={json.dumps(v)}" if isinstance(v, str)
+                     else f"{k}={float(v):.6g}")
     line = " ".join(parts)
     print(line, file=file or sys.stdout, flush=True)
     return line
@@ -34,16 +38,6 @@ def emit(step: int | None = None, file=None, **metrics: float) -> str:
 def parse_line(line: str) -> dict[str, float]:
     """Collector side: extract all name=value pairs from one log line."""
     return {m.group(1): float(m.group(2)) for m in METRIC_RE.finditer(line)}
-
-
-def extract_final_metrics(log_text: str) -> dict[str, float]:
-    """final_* scalars from a worker log (the train() helpers' contract)."""
-    final: dict[str, float] = {}
-    for line in log_text.splitlines():
-        final.update(
-            {k: v for k, v in parse_line(line).items() if k.startswith("final_")}
-        )
-    return final
 
 
 class TfEventsWriter:

@@ -21,9 +21,17 @@ and training gang-restart (train/trainer.py warm_start):
 Why restart-warm matters (ROADMAP item 5, papers 1909.09756 / 2011.03641):
 every gang restart previously paid a full re-trace+recompile of the train
 step — orchestration overhead capping goodput while the chips idle. With
-the cache dir injected into pod env (ENV_COMPILE_CACHE_DIR, jobcontroller)
-and surviving restarts, a restarted incarnation performs zero backend
-compilations of the train step.
+one cache directory that survives restarts, a restarted incarnation
+performs zero backend compilations of the train step.
+
+Where the cache lives — ONE resolver (`resolve_cache_dir`) for the
+trainer, the model server, the serving pod worker and the job controller:
+`JAX_COMPILATION_CACHE_DIR`, when set, is the directory and nothing here
+ever points jax anywhere else (pods inherit the parent's environment, so
+the variable reaches every worker by itself); otherwise the platform's
+processes share `DEFAULT_CACHE_DIR`, an absolute path inside the checkout
+— the path is part of nothing's key but must not move with the cwd, and
+pods run with their own.
 
 Process-global metrics land in /metrics as the kftpu_train_compile_*
 families (observability.py); `reset_compile_metrics` is the test hook.
@@ -33,6 +41,7 @@ from __future__ import annotations
 
 import hashlib
 import os
+import pickle
 import threading
 from pathlib import Path
 
@@ -63,12 +72,36 @@ _METRICS = {
 _LISTENER_INSTALLED = False
 
 
+#: jax's own variable for the persistent cache directory; where it is set
+#: the cache lives there and this module never re-points jax
+ENV_JAX_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+#: where the platform's processes keep the cache when the environment
+#: names no directory: anchored to the package location, not to the cwd
+DEFAULT_CACHE_DIR = str(
+    Path(__file__).resolve().parents[2] / ".kubeflow_tpu" / "compile-cache")
+
+
+def resolve_cache_dir(explicit: str = "", *, default: bool = False) -> str:
+    """The effective cache dir. `JAX_COMPILATION_CACHE_DIR` wins over
+    everything; then an explicit config value; then the pod env contract
+    (ENV_COMPILE_CACHE_DIR, injected by the jobcontroller); then, for
+    callers that always cache (`default=True`: the job controller and the
+    model server), DEFAULT_CACHE_DIR — else "" (caching off)."""
+    return (os.environ.get(ENV_JAX_CACHE_DIR, "")
+            or explicit
+            or os.environ.get(ENV_COMPILE_CACHE_DIR, "")
+            or (DEFAULT_CACHE_DIR if default else ""))
+
+
 def enable_persistent_cache(cache_dir: str | Path) -> None:
     """Point jax's persistent backend-compile cache at `cache_dir` and
     zero the size/time thresholds (the default thresholds skip caching
     cheap compiles — a restarted incarnation must hit the cache for EVERY
     executable, however small). Also installs the miss-counting listener
     so compile_counts() deltas are meaningful from the first compile.
+    With `JAX_COMPILATION_CACHE_DIR` set, jax already holds the directory
+    and `jax_compilation_cache_dir` is left alone.
 
     jax LATCHES the cache state at the first compile: a process that
     compiled anything before this call (e.g. a trainer whose init ran
@@ -82,18 +115,12 @@ def enable_persistent_cache(cache_dir: str | Path) -> None:
         compilation_cache as jax_cc,
     )
 
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    if not os.environ.get(ENV_JAX_CACHE_DIR):
+        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     jax_cc.reset_cache()
     install_compile_listener()
-
-
-def cache_dir_from_env(explicit: str = "") -> str:
-    """The effective cache dir: an explicit config value wins, else the
-    pod env contract (ENV_COMPILE_CACHE_DIR, injected by the
-    jobcontroller), else "" (caching off)."""
-    return explicit or os.environ.get(ENV_COMPILE_CACHE_DIR, "")
 
 
 def install_compile_listener() -> None:
@@ -160,15 +187,12 @@ def executable_path(cache_dir: str | Path, key: str) -> Path:
 def save_executable(cache_dir: str | Path, key: str, compiled) -> Path | None:
     """Serialize a compiled executable (jax.experimental
     .serialize_executable) under its key. Returns the path, or None when
-    this jax cannot serialize (the persistent backend cache still covers
-    the restart — degraded, not broken). Writes are atomic (tmp+rename)
-    so a killed pod never leaves a torn artifact for the next one."""
-    try:
-        import pickle
+    the backend refuses to serialize it (the persistent backend cache
+    still covers the restart — degraded, not broken). Writes are atomic
+    (tmp+rename) so a killed pod never leaves a torn artifact for the
+    next one."""
+    from jax.experimental.serialize_executable import serialize
 
-        from jax.experimental.serialize_executable import serialize
-    except ImportError:
-        return None
     path = executable_path(cache_dir, key)
     try:
         payload, in_tree, out_tree = serialize(compiled)
@@ -178,7 +202,7 @@ def save_executable(cache_dir: str | Path, key: str, compiled) -> Path | None:
             pickle.dump((payload, in_tree, out_tree), fh)
         os.replace(tmp, path)
     except Exception:  # noqa: BLE001 — serialization support varies by
-        # backend/version; a failed save must never fail training, and the
+        # backend; a failed save must never fail training, and the
         # persistent backend cache above still makes the restart warm
         return None
     with _MU:
@@ -211,24 +235,26 @@ def _evict_lru(exec_dir: Path,
         return
 
 
-def load_executable(cache_dir: str | Path, key: str):
+def load_executable(cache_dir: str | Path, key: str, execution_devices):
     """Deserialize a previously saved executable — trace AND compile are
-    both skipped. Returns the loaded callable, or None when absent /
-    unreadable / built by an incompatible jax (key covers version, but a
-    torn write or backend drift still degrades gracefully to None)."""
+    both skipped. `execution_devices` are the devices of the mesh the
+    program was compiled over (`mesh.devices.flat`): left to its default
+    jax binds the executable to EVERY device of the backend, and a
+    one-chip job on a four-chip host then cannot call what it loaded.
+    Returns the loaded callable, or None when absent / unreadable / built
+    by an incompatible jax (key covers version, but a torn write or
+    backend drift still degrades gracefully to None)."""
+    from jax.experimental.serialize_executable import deserialize_and_load
+
     path = executable_path(cache_dir, key)
     if not path.exists():
         return None
     try:
-        import pickle
-
-        from jax.experimental.serialize_executable import (
-            deserialize_and_load,
-        )
-
         with open(path, "rb") as fh:
             payload, in_tree, out_tree = pickle.load(fh)
-        loaded = deserialize_and_load(payload, in_tree, out_tree)
+        loaded = deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=list(execution_devices))
     except Exception:  # noqa: BLE001 — a corrupt artifact must degrade to
         # a normal (cache-warm) compile, never crash the incarnation
         try:

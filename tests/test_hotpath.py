@@ -17,6 +17,7 @@ Covers the edge contracts the perf machinery rides on:
     restart-overhead compile/restore/schedule split stay sum-exact.
 """
 
+import os
 import threading
 import time
 
@@ -169,10 +170,16 @@ class TestCompileCache:
         x = jnp.arange(16, dtype=jnp.float32)
         compiled = f.lower(x).compile()
         key = cc.executable_key(probe="roundtrip")
-        assert cc.load_executable(tmp_path, key) is None  # absent -> None
+        # a ONE-device program among the suite's eight devices: the loader
+        # is told the program's own devices, or jax binds it to all eight
+        # and the call fails ("expected 8 shards") — a one-chip job on a
+        # four-chip host at its first warm restart
+        devices = list(x.sharding.device_set)
+        assert len(devices) == 1 and len(jax.devices()) == 8
+        assert cc.load_executable(tmp_path, key, devices) is None  # absent
         assert cc.save_executable(tmp_path, key, compiled) is not None
         before = cc.compile_counts()
-        loaded = cc.load_executable(tmp_path, key)
+        loaded = cc.load_executable(tmp_path, key, devices)
         assert loaded is not None
         assert float(loaded(x)) == float(f(x))
         after = cc.compile_counts()
@@ -204,14 +211,129 @@ class TestCompileCache:
         assert sum(p.stat().st_size for p in exec_dir.iterdir()) <= 900
 
     def test_corrupt_artifact_degrades_to_none(self, tmp_path):
+        import jax
+
         from kubeflow_tpu.utils import compile_cache as cc
 
         key = cc.executable_key(probe="corrupt")
         path = cc.executable_path(tmp_path, key)
         path.parent.mkdir(parents=True)
         path.write_bytes(b"torn write of a dying pod")
-        assert cc.load_executable(tmp_path, key) is None
+        assert cc.load_executable(tmp_path, key, jax.devices()[:1]) is None
         assert not path.exists()  # quarantined by removal, not retried
+
+    def test_one_device_mesh_among_eight_reloads_and_steps(
+            self, tmp_path, tiny_data):
+        """The trainer's own warm restart on a mesh smaller than the
+        backend: the reloaded step executable must be callable."""
+        import jax
+
+        from kubeflow_tpu.models import MnistMLP
+        from kubeflow_tpu.parallel import MeshConfig, build_mesh
+        from kubeflow_tpu.train import Trainer, TrainerConfig
+
+        x, y = tiny_data
+
+        def trainer():
+            return Trainer(
+                MnistMLP(hidden=(8,)),
+                TrainerConfig(batch_size=16, log_every_steps=10**9,
+                              compile_cache_dir=str(tmp_path)),
+                mesh=build_mesh(MeshConfig(), jax.devices()[:1]))
+
+        t1 = trainer()
+        assert "train_step" in t1.warm_start(x[:16], y[:16])["compiled"]
+        jax.clear_caches()
+        t2 = trainer()
+        state = t2.init_state(x[:16])
+        info = t2.warm_start(x[:16], y[:16])
+        assert info["reloaded"] == "train_step", info
+        state, m = t2.train_step(state, (x[:16], y[:16]))
+        assert np.isfinite(float(m["loss"]))
+        assert t2._step_compiled is not None  # the reload was NOT dropped
+
+    def test_cache_dir_resolution(self, tmp_path, monkeypatch):
+        """One resolver: JAX_COMPILATION_CACHE_DIR wins over everything;
+        without it an explicit value, then the pod env contract, then —
+        for the callers that always cache — an absolute path inside the
+        checkout that does not move with the cwd."""
+        import subprocess
+        import sys
+
+        from kubeflow_tpu.utils import compile_cache as cc
+        from kubeflow_tpu.utils.envvars import ENV_COMPILE_CACHE_DIR
+
+        monkeypatch.delenv(cc.ENV_JAX_CACHE_DIR, raising=False)
+        monkeypatch.delenv(ENV_COMPILE_CACHE_DIR, raising=False)
+        assert cc.resolve_cache_dir() == ""  # standalone trainer: off
+        assert cc.resolve_cache_dir("explicit") == "explicit"
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        default = os.path.join(repo, ".kubeflow_tpu", "compile-cache")
+        assert cc.resolve_cache_dir(default=True) == default
+        assert os.path.isabs(cc.DEFAULT_CACHE_DIR)
+        monkeypatch.setenv(ENV_COMPILE_CACHE_DIR, "/pod/contract")
+        assert cc.resolve_cache_dir() == "/pod/contract"
+        assert cc.resolve_cache_dir("explicit") == "explicit"
+        monkeypatch.setenv(cc.ENV_JAX_CACHE_DIR, "/from/jax/env")
+        assert cc.resolve_cache_dir("explicit", default=True) \
+            == "/from/jax/env"
+        # identical from two different working directories
+        code = ("from kubeflow_tpu.utils.compile_cache import "
+                "resolve_cache_dir; print(resolve_cache_dir(default=True))")
+        env = {k: v for k, v in os.environ.items()
+               if k not in (cc.ENV_JAX_CACHE_DIR, ENV_COMPILE_CACHE_DIR)}
+        env["PYTHONPATH"] = repo
+        seen = {
+            subprocess.run([sys.executable, "-c", code], cwd=cwd, env=env,
+                           capture_output=True, text=True,
+                           check=True).stdout.strip()
+            for cwd in (repo, str(tmp_path))}
+        assert seen == {default}
+
+    def test_env_dir_is_never_overridden_in_code(self, tmp_path,
+                                                 monkeypatch):
+        """With JAX_COMPILATION_CACHE_DIR set, enabling the cache zeroes
+        the thresholds but leaves jax_compilation_cache_dir alone; without
+        it the directory is set."""
+        import jax
+
+        from kubeflow_tpu.utils import compile_cache as cc
+
+        updates = []
+        real_update = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda k, v: (updates.append(k), real_update(k, v))[1])
+        monkeypatch.setenv(cc.ENV_JAX_CACHE_DIR, str(tmp_path / "env"))
+        cc.enable_persistent_cache(cc.resolve_cache_dir("elsewhere"))
+        assert "jax_compilation_cache_dir" not in updates
+        assert "jax_persistent_cache_min_entry_size_bytes" in updates
+        updates.clear()
+        monkeypatch.delenv(cc.ENV_JAX_CACHE_DIR)
+        cc.enable_persistent_cache(str(tmp_path / "explicit"))
+        assert "jax_compilation_cache_dir" in updates
+        assert jax.config.jax_compilation_cache_dir \
+            == str(tmp_path / "explicit")
+
+    def test_jobcontroller_leaves_injection_to_the_environment(
+            self, tmp_path, monkeypatch):
+        """With JAX_COMPILATION_CACHE_DIR set, pods inherit it with the
+        rest of the environment: no KFTPU_COMPILE_CACHE_DIR is injected."""
+        from kubeflow_tpu.controller.fakecluster import FakeCluster
+        from kubeflow_tpu.controller.jobcontroller import JobController
+        from kubeflow_tpu.utils import compile_cache as cc
+        from kubeflow_tpu.utils.envvars import ENV_COMPILE_CACHE_DIR
+        from tests.test_tracing import make_job
+
+        monkeypatch.setenv(cc.ENV_JAX_CACHE_DIR, str(tmp_path / "env"))
+        cluster = FakeCluster()
+        ctrl = JobController(cluster)
+        assert ctrl.compile_cache_dir == str(tmp_path / "env")
+        job = make_job(tmp_path, "envjob", "pass", replicas=1)
+        cluster.create("jobs", job)
+        ctrl.reconcile(f"{job.metadata.namespace}/{job.metadata.name}")
+        (pod,) = cluster.list("pods")
+        assert ENV_COMPILE_CACHE_DIR not in pod.env
 
     def test_jobcontroller_injects_cache_dir(self, tmp_path):
         """The pod env contract carries KFTPU_COMPILE_CACHE_DIR, and the

@@ -111,6 +111,40 @@ def test_flash_attention_grad():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
+@pytest.mark.parametrize("mcfg", [
+    MeshConfig(data=8),
+    MeshConfig(data=1, fsdp=2, model=4),
+    MeshConfig(data=2, context=2, model=2),
+])
+def test_flash_under_a_mesh_runs_per_device(mcfg):
+    """Mosaic kernels cannot be partitioned automatically — on the chip a
+    flash step under any mesh of more than one device is refused unless
+    the call sits in a shard_map. Each device runs the kernel on its batch
+    rows and heads with the sequence whole; gradients (dbias summed over
+    the head shards) match the unpartitioned kernel."""
+    rng = np.random.RandomState(3)
+    q, k, v = (jnp.asarray(rng.normal(0, 1, (8, L, H, D)).astype(np.float32))
+               for _ in range(3))
+    bias = jnp.asarray(rng.normal(0, 1, (8, 1, 1, L)).astype(np.float32))
+
+    def loss(attn, q, k, v, bias):
+        return (attn(q, k, v, bias, causal=True) ** 2).sum()
+
+    flash = functools.partial(flash_attention, block=16)
+    # the reference is the same kernel with no mesh: one device, no
+    # shard_map (the dense-attention agreement is pinned above)
+    want = jax.jit(jax.grad(functools.partial(loss, flash),
+                            argnums=(0, 1, 2, 3)))(q, k, v, bias)
+    with jax.set_mesh(build_mesh(mcfg)):
+        step = jax.jit(jax.grad(functools.partial(loss, flash),
+                                argnums=(0, 1, 2, 3)))
+        assert "shard_map" in str(jax.make_jaxpr(
+            functools.partial(flash, causal=True))(q, k, v, bias))
+        got = step(q, k, v, bias)
+    for a, b in zip(want, got):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-3)
+
+
 def test_bert_with_ring_attention_trains():
     from kubeflow_tpu.models import BertConfig, BertForSequenceClassification
     from kubeflow_tpu.train import Trainer, TrainerConfig
