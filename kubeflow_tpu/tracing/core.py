@@ -17,6 +17,14 @@ Dependency-free (stdlib only) distributed tracing for the platform:
   - Disabled tracing is the NOOP_TRACER: every call returns a shared inert
     span object, no allocation beyond the kwargs dict, no locks — cheap
     enough to leave on the trainer hot path unconditionally.
+  - One clock with the device: while a `jax.profiler` session records in
+    this process, every span used as a context manager — armed, disarmed
+    or NOOP — also opens a `jax.profiler.TraceAnnotation` of its name, so
+    the program's spans lie on the trace's `/host:CPU` plane beside PJRT's
+    own and over the device's gaps. jax is never imported from here: the
+    annotation is resolved only once jax is already loaded, and outside a
+    session nothing is opened (`TraceAnnotation.is_enabled()`), which is
+    the off state — there is no switch.
 
 The platform side attaches a Tracer to the cluster (`cluster.tracer`,
 `Platform.start_tracing`); worker processes get one from the env contract
@@ -28,6 +36,7 @@ from __future__ import annotations
 
 import contextvars
 import os
+import sys
 import threading
 import time
 import uuid
@@ -55,6 +64,48 @@ _DELIVERED: contextvars.ContextVar = contextvars.ContextVar(
 
 #: sentinel: "inherit the parent from the current context"
 _INHERIT = object()
+
+#: `jax.profiler.TraceAnnotation`, kept once jax has been seen loaded
+_TRACE_ANNOTATION = None
+#: a longer string attribute stays in the flight recorder only
+_ANNOTATION_STR_MAX = 64
+
+
+def _live_annotation():
+    """`jax.profiler.TraceAnnotation` while a profiler session records in
+    this process, else None. Never imports jax (the control plane imports
+    this module and must stay jax-free): a process that has not loaded jax
+    cannot be profiling with it."""
+    global _TRACE_ANNOTATION
+    cls = _TRACE_ANNOTATION
+    if cls is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        cls = getattr(profiler, "TraceAnnotation", None)
+        if cls is None:
+            return None
+        _TRACE_ANNOTATION = cls
+    return cls if cls.is_enabled() else None
+
+
+def _annotation_stat(value) -> bool:
+    """Numbers and short strings become stats of the profiler's event."""
+    return isinstance(value, (int, float)) or (
+        isinstance(value, str) and len(value) <= _ANNOTATION_STR_MAX)
+
+
+def _annotate(cls, name: str, attrs: dict):
+    return cls(name, **{k: v for k, v in attrs.items() if _annotation_stat(v)})
+
+
+def _open_annotation(name: str, attrs: dict):
+    cls = _live_annotation()
+    return None if cls is None else _annotate(cls, name, attrs)
+
+
+def _add_stat(annotation, key: str, value) -> None:
+    """An attribute set on an entered span reaches its open annotation too."""
+    if annotation is not None and _annotation_stat(value):
+        annotation.set_metadata(**{key: value})
 
 
 class SpanContext:
@@ -88,7 +139,8 @@ class Span:
     comes from perf_counter (immune to clock steps)."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start",
-                 "duration", "attrs", "_tracer", "_t0", "_token", "_tid")
+                 "duration", "attrs", "_tracer", "_t0", "_token", "_tid",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  span_id: str, parent_id: str, attrs: dict):
@@ -103,6 +155,7 @@ class Span:
         self.duration = 0.0
         self._token = None
         self._tid = threading.get_ident()
+        self._annotation = None
 
     @property
     def context(self) -> SpanContext:
@@ -110,9 +163,8 @@ class Span:
 
     def set_attribute(self, key: str, value) -> "Span":
         self.attrs[key] = value
+        _add_stat(self._annotation, key, value)
         return self
-
-    annotate = set_attribute
 
     def end(self) -> None:
         self.duration = time.perf_counter() - self._t0
@@ -120,9 +172,13 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _CURRENT.set(self.context)
+        self._annotation = _open_annotation(self.name, self.attrs)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if self._token is not None:
             _CURRENT.reset(self._token)
             self._token = None
@@ -159,8 +215,6 @@ class _NoopSpan:
     def set_attribute(self, key: str, value) -> "_NoopSpan":
         return self
 
-    annotate = set_attribute
-
     def end(self) -> None:
         pass
 
@@ -170,6 +224,40 @@ class _NoopSpan:
 
 
 _NOOP_SPAN = _NoopSpan()
+
+
+class _ProfilerSpan(_NoopSpan):
+    """A span that no flight recorder takes, made while a profiler session
+    records: entering it opens the annotation and nothing else."""
+
+    __slots__ = ("_cls", "_name", "_attrs", "_annotation")
+
+    def __init__(self, cls, name: str, attrs: dict):
+        self._cls = cls
+        self._name = name
+        self._attrs = attrs
+        self._annotation = None
+
+    def __enter__(self) -> "_ProfilerSpan":
+        self._annotation = _annotate(self._cls, self._name, self._attrs)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
+        return False
+
+    def set_attribute(self, key: str, value) -> "_ProfilerSpan":
+        _add_stat(self._annotation, key, value)
+        return self
+
+
+def _unrecorded_span(name: str, attrs: dict) -> _NoopSpan:
+    """What a NOOP or disarmed tracer hands out: the shared inert span, or
+    while the profiler records one that carries the annotation."""
+    cls = _live_annotation()
+    return _NOOP_SPAN if cls is None else _ProfilerSpan(cls, name, attrs)
 
 
 class FlightRecorder:
@@ -203,10 +291,6 @@ class FlightRecorder:
         with self._mu:
             return list(self._ring)
 
-    def clear(self) -> None:
-        with self._mu:
-            self._ring.clear()
-
     def __len__(self) -> int:
         with self._mu:
             return len(self._ring)
@@ -236,9 +320,10 @@ class Tracer:
     def start_span(self, name: str, parent=_INHERIT, **attrs):
         """New span. `parent` may be a Span, a SpanContext, None (force a
         new root), or omitted (inherit: current context, else the tracer's
-        default_parent). A disarmed tracer returns the shared noop span."""
+        default_parent). A disarmed tracer records nothing: its span is
+        the NOOP tracer's."""
         if not self.armed:
-            return _NOOP_SPAN
+            return _unrecorded_span(name, attrs)
         if parent is _INHERIT:
             parent = _CURRENT.get() or self.default_parent
         elif isinstance(parent, Span):
@@ -340,7 +425,8 @@ class Tracer:
 
 
 class NoopTracer:
-    """Disabled tracing: every call lands on the shared inert span."""
+    """Disabled tracing: every call lands on the shared inert span, except
+    that a span made while a profiler session records still annotates it."""
 
     enabled = False
     recorder = None
@@ -348,7 +434,7 @@ class NoopTracer:
     default_parent = None
 
     def start_span(self, name: str, parent=None, **attrs) -> _NoopSpan:
-        return _NOOP_SPAN
+        return _unrecorded_span(name, attrs)
 
     span = start_span
 

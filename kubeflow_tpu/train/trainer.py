@@ -539,22 +539,27 @@ class Trainer:
         )
 
     def _loss_of(self, params, extra, x, y, rng):
-        logits, new_extra = self.apply_fn(params, extra, x, rng, True)
-        loss = self.loss_fn(logits.astype(jnp.float32), y)
-        # auxiliary objectives sown into the 'losses' collection (e.g.
-        # MoE load-balance, parallel/moe.py) join the objective here;
-        # popped so they never persist into TrainState.extra
-        aux = new_extra.pop("losses", None) if isinstance(new_extra, dict) else None
-        if aux:
-            loss = loss + sum(
-                jnp.asarray(a, jnp.float32) for a in jax.tree.leaves(aux)
-            )
+        # the device scopes of the step (train.cast, train.loss,
+        # train.grad_norm, train.optimizer) are names in the operator's
+        # profile (docs/observability.md); they change no instruction
+        with jax.named_scope("train.loss"):
+            logits, new_extra = self.apply_fn(params, extra, x, rng, True)
+            loss = self.loss_fn(logits.astype(jnp.float32), y)
+            # auxiliary objectives sown into the 'losses' collection (e.g.
+            # MoE load-balance, parallel/moe.py) join the objective here;
+            # popped so they never persist into TrainState.extra
+            aux = new_extra.pop("losses", None) if isinstance(new_extra, dict) else None
+            if aux:
+                loss = loss + sum(
+                    jnp.asarray(a, jnp.float32) for a in jax.tree.leaves(aux)
+                )
         return loss, (logits, new_extra)
 
     def _train_step(self, state: TrainState, batch) -> tuple[TrainState, dict]:
         x, y = batch
         step_rng = jax.random.fold_in(state.rng, state.step)
-        x = self._cast(x)
+        with jax.named_scope("train.cast"):
+            x = self._cast(x)
         n_acc = max(self.config.grad_accum_steps, 1)
 
         if n_acc == 1:
@@ -614,16 +619,22 @@ class Trainer:
             )
             loss, acc = loss / n_acc, acc / n_acc
 
-        updates, opt_state = self.tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        # clipping, where configured, is the first link of tx's chain
+        with jax.named_scope("train.optimizer"):
+            updates, opt_state = self.tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         new_state = state.replace(
             step=state.step + 1, params=params, opt_state=opt_state, extra=new_extra
         )
         # global grad-norm as a first-class metric: the standard training
         # health signal (divergence shows here before the loss moves), and
-        # the finiteness witness the real-dim composed execution test pins
-        return new_state, {"loss": loss, "accuracy": acc,
-                           "grad_norm": optax.global_norm(grads)}
+        # the finiteness witness the real-dim composed execution test pins.
+        # Traced last, as it was before it had a scope: the scopes are
+        # metadata and must leave the step's instructions, and so its key in
+        # the compile cache, as they were
+        with jax.named_scope("train.grad_norm"):
+            grad_norm = optax.global_norm(grads)
+        return new_state, {"loss": loss, "accuracy": acc, "grad_norm": grad_norm}
 
     def _eval_step(self, state: TrainState, batch) -> dict:
         x, y, w = batch  # w: validity mask for padded tail batches
@@ -646,12 +657,22 @@ class Trainer:
         return shard_batch(batch, self.mesh, process_local=self._process_local)
 
     def train_step(self, state: TrainState, batch) -> tuple[TrainState, dict]:
+        """Enqueue one optimizer step; returns before the device has run
+        it. `train.enqueue` is everything the host does in here to start
+        the step (with `train.place_batch`, the batch's placement, inside
+        it): on the profiler's clock while a jax.profiler session records,
+        in the flight recorder while a Tracer is armed."""
+        tracer = get_tracer()
         # ambient mesh enables P-form with_sharding_constraint pins inside
         # models (bert.constrain) without threading the mesh through
         # modules; deterministic_rng keeps traced random draws (dropout,
         # fold_in) layout-invariant — see init_state
-        with jax.set_mesh(self.mesh), self.partitioner.deterministic_rng():
-            placed = self._place(batch)
+        with tracer.span(
+                "train.enqueue",
+                path="executable" if self._step_compiled is not None else "jit",
+        ) as sp, jax.set_mesh(self.mesh), self.partitioner.deterministic_rng():
+            with tracer.span("train.place_batch"):
+                placed = self._place(batch)
             if self._step_compiled is not None:
                 try:
                     # warm_start's executable (reloaded from the compile
@@ -663,6 +684,7 @@ class Trainer:
                     return self._step_compiled(state, placed)
                 except (TypeError, ValueError):
                     self._step_compiled = None
+                    sp.set_attribute("path", "jit")
             return self._jit_train_step(state, placed)
 
     def train_steps_fused(
@@ -1042,6 +1064,13 @@ class Trainer:
 
     def _fit_loop(self, dataset, c, state, start_step, events, preempted,
                   on_epoch_end, tracer=None):
+        """The step loop of fit(). Its spans: `train.step` around each
+        `train_step` call — on an asynchronous backend that is the time to
+        ENQUEUE the step (`train.enqueue` and `train.place_batch` are its
+        children), never the device's step time, which only the device
+        trace has (`step_ms.train`); `train.wait_for_metrics` where the
+        host does wait for the device, to read the log line's numbers;
+        `train.data_load`, `train.eval`, `checkpoint.save`."""
         import os
 
         if tracer is None:
@@ -1090,7 +1119,9 @@ class Trainer:
                 hb.beat(step=global_step)
             timer.tick(items=took * c.batch_size, steps=took)
             if (global_step % c.log_every_steps) < took or global_step == total_steps:
-                last = {k: float(v) for k, v in m.items()}
+                # reading a metric is where the host waits for the device
+                with tracer.span("train.wait_for_metrics", step=global_step):
+                    last = {k: float(v) for k, v in m.items()}
                 metrics_lib.emit(
                     step=global_step,
                     **last,
