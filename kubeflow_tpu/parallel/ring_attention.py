@@ -12,11 +12,20 @@ TPU-first:
   ulysses_attention  all-to-all head scatter: re-shard (seq/ctx, heads) ->
                      (seq, heads/ctx), run dense/blockwise attention locally,
                      scatter back (DeepSpeed-Ulysses; PAPERS.md).
-  flash_attention    single-device blockwise-softmax pallas kernel (VMEM
-                     accumulators, MXU matmuls, f32 softmax), custom-VJP'd
-                     with FUSED pallas backward kernels (dq and dk/dv/dbias
-                     recompute probability tiles from the saved logsumexp —
-                     FlashAttention-2 style, no O(L²) residuals).
+  flash_attention    single-device blockwise-softmax pallas kernel (MXU
+                     matmuls, f32 softmax), custom-VJP'd. Forward: one grid
+                     step per (head group, query tile); the group's whole K
+                     and V stay in VMEM and the KV loop runs inside the
+                     kernel, stopped at the causal diagonal and started at
+                     the window's edge, a mask built only on the tiles those
+                     cross. Where a head's K and V pass the VMEM budget the
+                     KV axis goes on the grid instead. The tile is a function
+                     of the call's shapes (flash_forward_tiling), and the
+                     pallas_call's name carries branch and tile
+                     (flash_fwd_resident_q256_k512) into every trace.
+                     Backward: recomputes probability tiles from the saved
+                     logsumexp — FlashAttention-2 style, no O(L²) residuals
+                     (FLASH_BWD_IMPL chooses among its implementations).
 
 All functions share the signature of models.bert.dense_attention:
   (q, k, v, bias, dropout_rng, dropout_rate, block) -> out
@@ -33,7 +42,7 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -565,6 +574,341 @@ def ulysses_attention(q, k, v, bias, dropout_rng=None, dropout_rate=0.0,
 
 # ------------------------------------------------------------------ pallas fwd
 
+#: VMEM the forward's tile rule plans within, by its own count
+#: (`_flash_fwd_vmem_bytes`). Mosaic scopes a kernel to 16 MiB unless told
+#: otherwise. Compiling for the v5e, what it refused was resident K/V blocks
+#: past that (16k positions: counted 19 MiB); a score tile counted at 18 MiB
+#: it took, so the count is on the safe side where the tile is the large part.
+FLASH_FWD_VMEM_BUDGET = 12 * 2**20
+#: (block_q, block_k) the rule aims for, timed on the v5e (PERF.md, PR 26).
+#: K/V resident, by `causal`: `block_k` under 512 loses to the per-slice cost
+#: of the `(block_q, 1)` row statistics, which fill one lane of a register
+#: where the scores fill 128; under the diagonal a wide `block_q` computes
+#: masked scores, with no diagonal it saves loads of K and V into the MXU.
+_FLASH_FWD_RESIDENT_TARGET = {True: (256, 512), False: (512, 512)}
+#: KV on the grid: every grid step costs its half microsecond and a K/V
+#: copy, so the tiles are as wide as the budget lets them be
+_FLASH_FWD_KVGRID_TARGET = (1024, 1024)
+#: heads a grid step may take where a head is one tile (short sequences: a
+#: step a head is mostly the step's own cost), most first
+_FLASH_FWD_HEAD_GROUPS = (4, 2, 1)
+
+
+class FlashTiling(NamedTuple):
+    """How one forward call tiles. `resident`: a head's whole K and V sit in
+    VMEM and the KV loop runs inside the kernel, `heads` heads a grid step;
+    else the KV axis stays on the grid (contexts whose K and V do not fit)."""
+
+    resident: bool
+    block_q: int
+    block_k: int
+    heads: int = 1
+
+    @property
+    def name(self) -> str:
+        """The pallas_call's name: what a trace shows of how the kernel ran."""
+        branch = "resident" if self.resident else "kvgrid"
+        group = f"_g{self.heads}" if self.heads > 1 else ""
+        return f"flash_fwd_{branch}_q{self.block_q}_k{self.block_k}{group}"
+
+
+def _flash_fwd_vmem_bytes(tiling: FlashTiling, lk: int, d: int, dtype) -> int:
+    """VMEM one grid step of the forward holds: the pipeline's two buffers of
+    every block, lanes padded to 128 and sublanes to a tile, plus the float32
+    score tile three times over (scores, probabilities, and the cast or mask
+    beside them) and the running maximum, sum and accumulator."""
+    item = jnp.dtype(dtype).itemsize
+    lanes = -(-d // 128) * 128
+    bq, bk, g = tiling.block_q, tiling.block_k, tiling.heads
+    kv_rows = lk if tiling.resident else bk
+    blocks = (
+        2 * g * bq * lanes * item            # q in, out
+        + 2 * g * kv_rows * lanes * item     # k, v
+        + g * bq * 128 * 4                   # lse: one lane of 128 used
+        + 8 * kv_rows * 4                    # bias row: one sublane of 8 used
+    )
+    work = 3 * bq * max(bk, 128) * 4 + bq * (lanes + 2 * 128) * 4
+    return 2 * blocks + work
+
+
+def _largest_tile(n: int, target: int, granule: int) -> int:
+    """The largest multiple of `granule` that divides `n` and is at most
+    `target`; `granule`, which divides `n`, where there is none."""
+    return max((t for t in range(granule, min(n, target) + 1, granule)
+                if n % t == 0), default=granule)
+
+
+def flash_forward_tiling(lq: int, lk: int, d: int, dtype, causal: bool,
+                         window: int = 0, *, block_q: int = 128,
+                         block_k: int = 128, heads: int = 1,
+                         vmem_budget: int = FLASH_FWD_VMEM_BUDGET
+                         ) -> FlashTiling:
+    """The forward's tile, from what the call can see. `block_q`/`block_k`
+    are the caller's granules (they tile `lq`/`lk`; every tile is a multiple
+    of them, so a tiny test shape stays legal); `heads` is the head count,
+    which a head group has to divide to share one bias row. `window` moves
+    no tile (timed at 4,096 positions, window 1,024): the loop's bounds skip
+    what it hides."""
+    gq, gk = min(block_q, lq), min(block_k, lk)
+
+    def fits(tiling):
+        return _flash_fwd_vmem_bytes(tiling, lk, d, dtype) <= vmem_budget
+
+    target_q, target_k = _FLASH_FWD_RESIDENT_TARGET[bool(causal)]
+    bq, bk = _largest_tile(lq, target_q, gq), _largest_tile(lk, target_k, gk)
+    one_tile = (bq, bk) == (lq, lk)
+    for g in _FLASH_FWD_HEAD_GROUPS if one_tile else (1,):
+        if heads % g == 0 and fits(FlashTiling(True, bq, bk, g)):
+            return FlashTiling(True, bq, bk, g)
+    # one head's K and V do not fit beside the tiles: the KV axis goes back
+    # on the grid, and the wider tile shrinks until a step fits
+    target_q, target_k = _FLASH_FWD_KVGRID_TARGET
+    bq, bk = _largest_tile(lq, target_q, gq), _largest_tile(lk, target_k, gk)
+    while not fits(FlashTiling(False, bq, bk)) and (bq > gq or bk > gk):
+        if bq > gq and (bq >= bk or bk == gk):
+            bq = _largest_tile(lq, bq - 1, gq)
+        else:
+            bk = _largest_tile(lk, bk - 1, gk)
+    return FlashTiling(False, bq, bk)
+
+
+def _kv_tile_bounds(row0, block_q: int, block_k: int, n_kv: int, window: int):
+    """Under `causal`, the KV tiles [lo, hi) that the query tile starting at
+    row `row0` can see, and within them the tiles [lo_full, hi_full) that
+    neither the diagonal nor the window's edge crosses: those need no mask."""
+    last = row0 + block_q - 1
+    hi = jnp.minimum(last // block_k + 1, n_kv)
+    hi_full = (row0 + 1) // block_k  # last column at or under the first row
+    if window:
+        lo = jnp.maximum(row0 - (window - 1), 0) // block_k
+        # first column inside the last row's window
+        lo_full = (jnp.maximum(last - window + 1, 0) + block_k - 1) // block_k
+    else:
+        lo = lo_full = 0
+    lo_full = jnp.clip(lo_full, lo, hi)
+    return lo, lo_full, jnp.clip(hi_full, lo_full, hi), hi
+
+
+def _flash_tile(q, k, v, bias_row, carry, *, scale: float, mask):
+    """One online-softmax step of the forward: a (block_q, d) query tile
+    against a (block_k, d) KV slice. `mask` is None for a tile wholly under
+    the diagonal and inside the window, else (row0, col0, window)."""
+    m, l, acc = carry
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale  # (bq, bk)
+    s = s + bias_row.astype(jnp.float32)[None, :]
+    if mask is not None:
+        row0, col0, window = mask
+        rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        masked = cols > rows
+        if window:
+            masked = masked | (rows - cols >= window)
+        s = s + jnp.where(masked, NEG_INF, 0.0)
+    m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+    corr = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)
+    l = l * corr + p.sum(-1, keepdims=True)
+    acc = acc * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return m_new, l, acc
+
+
+def _flash_tile_init(block_q: int, d: int):
+    return (jnp.full((block_q, 1), NEG_INF, jnp.float32),
+            jnp.zeros((block_q, 1), jnp.float32),
+            jnp.zeros((block_q, d), jnp.float32))
+
+
+def _flash_resident_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
+                           *, scale: float, causal: bool, window: int,
+                           block_k: int):
+    """Forward for one (head group, query tile): the group's whole K and V
+    are in VMEM (their block does not move with the query tile, so they are
+    fetched once a group), the KV loop runs here over `block_k` slices with
+    the running maximum, sum and accumulator as its carry, and stops at the
+    diagonal; only the tiles the diagonal or the window's edge crosses build
+    a mask."""
+    heads, block_q, d = q_ref.shape
+    n_kv = k_ref.shape[1] // block_k
+    row0 = pl.program_id(1) * block_q
+    for g in range(heads):
+        q = q_ref[g]
+
+        def tile(j, carry, masked, g=g, q=q):
+            col0 = pl.multiple_of(j * block_k, block_k)
+            return _flash_tile(
+                q, k_ref[g, pl.ds(col0, block_k), :],
+                v_ref[g, pl.ds(col0, block_k), :],
+                bias_ref[0, 0, 0, pl.ds(col0, block_k)], carry, scale=scale,
+                mask=(row0, col0, window) if masked else None)
+
+        def tiles(lo, hi, masked, carry, tile=tile):
+            return jax.lax.fori_loop(
+                lo, hi, functools.partial(tile, masked=masked), carry)
+
+        carry = _flash_tile_init(block_q, d)
+        if causal:
+            lo, lo_full, hi_full, hi = _kv_tile_bounds(
+                row0, block_q, block_k, n_kv, window)
+            if window:  # else lo == lo_full: no loop to set up
+                carry = tiles(lo, lo_full, True, carry)
+            carry = tiles(lo_full, hi_full, False, carry)
+            carry = tiles(hi_full, hi, True, carry)
+        else:
+            carry = tiles(0, n_kv, False, carry)
+        m, l, acc = carry
+        o_ref[g] = (acc / l).astype(o_ref.dtype)
+        # logsumexp residual for the fused backward kernels
+        lse_ref[g] = m + jnp.log(jnp.maximum(l, 1e-30))
+
+
+def _flash_kvgrid_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
+                         m_scr, l_scr, acc_scr,
+                         *, scale: float, causal: bool, window: int):
+    """Forward for one (head, query tile, KV tile): the KV axis is the
+    grid's last, sequential, with the running maximum, sum and accumulator
+    in VMEM scratch across it. A step above the diagonal or outside the
+    window does nothing, and fetches nothing: the K/V index maps
+    (`_flash_forward_tiled`) hold it on a tile that is already there."""
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
+    ik, n_kv = pl.program_id(2), pl.num_programs(2)
+    row0 = pl.program_id(1) * block_q
+
+    @pl.when(ik == 0)
+    def _():
+        m_scr[:], l_scr[:], acc_scr[:] = _flash_tile_init(
+            block_q, q_ref.shape[2])
+
+    def step(masked):
+        m_scr[:], l_scr[:], acc_scr[:] = _flash_tile(
+            q_ref[0], k_ref[0], v_ref[0], bias_ref[0, 0, 0, :],
+            (m_scr[:], l_scr[:], acc_scr[:]), scale=scale,
+            mask=(row0, ik * block_k, window) if masked else None)
+
+    if causal:
+        lo, lo_full, hi_full, hi = _kv_tile_bounds(
+            row0, block_q, block_k, n_kv, window)
+        full = jnp.logical_and(ik >= lo_full, ik < hi_full)
+        live = jnp.logical_and(ik >= lo, ik < hi)
+        pl.when(full)(functools.partial(step, False))
+        pl.when(jnp.logical_and(live, jnp.logical_not(full)))(
+            functools.partial(step, True))
+    else:
+        step(False)
+
+    @pl.when(ik == n_kv - 1)
+    def _():
+        o_ref[0] = (acc_scr[:] / l_scr[:]).astype(o_ref.dtype)
+        lse_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
+
+
+def _flash_forward_tiled(q, k, v, bias, tiling: FlashTiling, causal: bool,
+                         window: int = 0):
+    """The forward kernel at a given tiling -> (out (B,Lq,H,D), lse
+    (B*H,Lq,1) f32). The tiling has to divide the lengths, and its head
+    group the head count."""
+    b, lq, h, d = q.shape
+    lk = k.shape[1]
+    resident, block_q, block_k, group = tiling
+    scale = 1.0 / (d**0.5)
+    # fold heads into batch: (B*H, L, D)
+    qf = q.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
+    kf = k.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
+    vf = v.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
+    n_q, n_kv = lq // block_q, lk // block_k
+    common = dict(
+        out_shape=[
+            jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, lq, 1), jnp.float32),
+        ],
+        name=tiling.name,  # branch and tile, in every trace of the call
+        interpret=jax.default_backend() == "cpu",
+    )
+    if resident:
+        kv_spec = pl.BlockSpec((group, lk, d), lambda g, iq: (g, 0, 0))
+        of, lse = pl.pallas_call(
+            functools.partial(_flash_resident_kernel, scale=scale,
+                              causal=causal, window=window, block_k=block_k),
+            grid=(b * h // group, n_q),
+            in_specs=[
+                pl.BlockSpec((group, block_q, d), lambda g, iq: (g, iq, 0)),
+                kv_spec, kv_spec,
+                pl.BlockSpec((1, 1, 1, lk),
+                             lambda g, iq: (g * group // h, 0, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((group, block_q, d), lambda g, iq: (g, iq, 0)),
+                pl.BlockSpec((group, block_q, 1), lambda g, iq: (g, iq, 0)),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
+            **common,
+        )(qf, kf, vf, bias)
+    else:
+        def kv_tile(iq, ik):
+            # a step with nothing to do names the nearest tile that has, so
+            # the pipeline sees no new block and starts no copy
+            if not causal:
+                return ik
+            lo, _, _, hi = _kv_tile_bounds(
+                iq * block_q, block_q, block_k, n_kv, window)
+            return jnp.clip(ik, lo, hi - 1)
+
+        kv_spec = pl.BlockSpec(
+            (1, block_k, d), lambda bh, iq, ik: (bh, kv_tile(iq, ik), 0))
+        of, lse = pl.pallas_call(
+            functools.partial(_flash_kvgrid_kernel, scale=scale,
+                              causal=causal, window=window),
+            grid=(b * h, n_q, n_kv),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
+                kv_spec, kv_spec,
+                pl.BlockSpec(
+                    (1, 1, 1, block_k),
+                    lambda bh, iq, ik: (bh // h, 0, 0, kv_tile(iq, ik))),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda bh, iq, ik: (bh, iq, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, d), jnp.float32),
+            ],
+            # the KV axis is a sequential accumulation (scratch carries
+            # m/l/acc across ik); heads and query tiles are independent
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            **common,
+        )(qf, kf, vf, bias)
+    return of.reshape(b, h, lq, d).transpose(0, 2, 1, 3), lse
+
+
+def _flash_forward(q, k, v, bias, block_q: int, block_k: int,
+                   causal: bool = False, want_lse: bool = False,
+                   window: int = 0):
+    """`block_q`/`block_k` are the backward's tile and the fallback's; of
+    the forward they decide only whether the lengths tile at all (the
+    backward consumes `lse` at that tile) and the granule of its own tile,
+    which `flash_forward_tiling` chooses."""
+    lq, lk, h, d = q.shape[1], k.shape[1], q.shape[2], q.shape[3]
+    if lq % min(block_q, lq) or lk % min(block_k, lk):
+        out = blockwise_attention(q, k, v, bias, causal=causal,
+                                  window=window)
+        return (out, None) if want_lse else out
+    tiling = flash_forward_tiling(lq, lk, d, q.dtype, causal, window,
+                                  block_q=block_q, block_k=block_k, heads=h)
+    out, lse = _flash_forward_tiled(q, k, v, bias, tiling, causal, window)
+    return (out, lse) if want_lse else out
+
+
+# ------------------------------------------------------------------ pallas bwd
+
 
 def _block_live(iq, ik, block_q, block_k, causal, window):
     """Whether a (q_block, kv_block) pair can contribute: at-or-below the
@@ -577,148 +921,6 @@ def _block_live(iq, ik, block_q, block_k, causal, window):
             ik * block_k + (block_k - 1) >= iq * block_q - (window - 1),
         )
     return live
-
-
-def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
-                  m_scr, l_scr, acc_scr,
-                  *, scale: float, n_kv: int, causal: bool,
-                  block_q: int, block_k: int, window: int = 0):
-    """Flash-attention forward tile: one (batch*head, q_block) position,
-    sequential grid over KV blocks with VMEM online-softmax accumulators.
-    window > 0 (with causal) masks keys older than window-1 positions and
-    skips KV blocks wholly outside every query's window."""
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
-    def _():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    def _compute():
-        q = q_ref[0]  # (bq, d)
-        k = k_ref[0]  # (bk, d)
-        v = v_ref[0]  # (bk, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (bq, bk)
-        s = s + bias_ref[0, 0, 0, :].astype(jnp.float32)[None, :]
-        if causal:
-            rows = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            cols = ik * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            masked = cols > rows
-            if window:
-                masked = masked | (rows - cols >= window)
-            s = s + jnp.where(masked, NEG_INF, 0.0)
-        m_prev = m_scr[:]  # (bq, 1)
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_scr[:] = l_scr[:] * corr + p.sum(-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = m_new
-
-    if causal:
-        # KV blocks strictly above the diagonal — or wholly outside the
-        # sliding window — contribute nothing: skip their matmuls entirely
-        # (halves long-context causal FLOPs; window makes it O(L·W))
-        pl.when(_block_live(iq, ik, block_q, block_k, causal, window))(
-            _compute)
-    else:
-        _compute()
-
-    @pl.when(ik == n_kv - 1)
-    def _():
-        o_ref[0] = (acc_scr[:] / l_scr[:]).astype(o_ref.dtype)
-        # logsumexp residual for the fused backward kernels
-        lse_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
-
-
-def _flash_forward(q, k, v, bias, block_q: int, block_k: int,
-                   causal: bool = False, want_lse: bool = False,
-                   window: int = 0, dimsem: bool | None = None):
-    b, lq, h, d = q.shape
-    lk = k.shape[1]
-    scale = 1.0 / (d**0.5)
-    if dimsem is None:
-        dimsem = FLASH_DIMSEM
-    # KFT_FLASH_BLOCK_Q/K adopt a probe-timed FORWARD tile only — the
-    # backward keeps the caller's geometry, which is what the backward
-    # verdicts validated (the fwd-only sweep must not retile the
-    # NaN-history backward kernels). lse is per-row, so fwd/bwd tiles
-    # are independent.
-    env_tiled = FLASH_BLOCK_Q or FLASH_BLOCK_K
-    if env_tiled:
-        block_q = FLASH_BLOCK_Q or block_q
-        block_k = FLASH_BLOCK_K or block_k
-    block_q = min(block_q, lq)
-    block_k = min(block_k, lk)
-    if lq % block_q or lk % block_k:
-        if env_tiled:
-            import warnings
-
-            warnings.warn(
-                f"KFT_FLASH_BLOCK_Q/K=({FLASH_BLOCK_Q},{FLASH_BLOCK_K}) "
-                f"does not tile (lq={lq}, lk={lk}); flash fell back to "
-                "blockwise — the capture is NOT measuring the adopted "
-                "kernel geometry", stacklevel=2)
-        out = blockwise_attention(q, k, v, bias, causal=causal,
-                                  window=window)
-        return (out, None) if want_lse else out
-    # fold heads into batch: (B*H, L, D)
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
-    n_q, n_kv = lq // block_q, lk // block_k
-
-    kernel = functools.partial(
-        _flash_kernel, scale=scale, n_kv=n_kv, causal=causal,
-        block_q=block_q, block_k=block_k, window=window,
-    )
-    of, lse = pl.pallas_call(
-        kernel,
-        grid=(b * h, n_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, iq, ik: (bh, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, iq, ik: (bh, ik, 0)),
-            pl.BlockSpec(
-                (1, 1, 1, block_k), lambda bh, iq, ik, h=h: (bh // h, 0, 0, ik)
-            ),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, iq, ik: (bh, iq, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, lq, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
-        interpret=jax.default_backend() == "cpu",
-        # the KV dim is a sequential accumulation (scratch carries m/l/acc
-        # across ik); bh and iq cells are independent
-        **({"compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))}
-           if dimsem else {}),
-    )(qf, kf, vf, bias)
-    out = of.reshape(b, h, lq, d).transpose(0, 2, 1, 3)
-    return (out, lse) if want_lse else out
-
-
-# ------------------------------------------------------------------ pallas bwd
 
 
 def _flash_bwd_scores(q, k, bias_row, lse, scale, causal, iq, ik,
@@ -867,9 +1069,11 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dd_ref,
 #               reproducer for a backend bug).
 # All variants are numerically identical in interpret/CPU mode
 # (test_ring_attention pins it).
-# KFT_FLASH_BWD_IMPL overrides the default (ROADMAP S4 gives each
+# KFT_FLASH_BWD_IMPL overrides the default (ROADMAP S1(b) gives each
 # candidate its verdict against a float32 reference on the chip; D3 then
-# keeps one).
+# keeps one). Every variant tiles by flash_attention's `block`; the forward
+# tiles by its own rule (flash_forward_tiling), and `lse`, a per-row
+# statistic of shape (B*H, Lq, 1), is all that passes between the two.
 import os as _os  # noqa: E402
 
 _FLASH_BWD_IMPLS = ("xla", "loop2", "ddpre", "loop", "scratch")
@@ -879,17 +1083,6 @@ if FLASH_BWD_IMPL not in _FLASH_BWD_IMPLS:
         f"KFT_FLASH_BWD_IMPL={FLASH_BWD_IMPL!r} is not one of "
         f"{_FLASH_BWD_IMPLS} — refusing to fall through to an arbitrary "
         "backward (the scratch kernels NaN on Mosaic)")
-
-# Capture-campaign tuning knobs, import-time like KFT_FLASH_BWD_IMPL:
-#   KFT_FLASH_BLOCK_Q / KFT_FLASH_BLOCK_K  override flash_attention's
-#     square `block` with an asymmetric tile (probe_flash_r5b section F
-#     times the candidates; the only timed geometry so far was square).
-#   KFT_FLASH_DIMSEM=1  annotates the forward grid (parallel, parallel,
-#     arbitrary) via Mosaic CompilerParams — numerics re-verified by the
-#     probe before any bench adopts it.
-FLASH_BLOCK_Q = int(_os.environ.get("KFT_FLASH_BLOCK_Q", "0"))
-FLASH_BLOCK_K = int(_os.environ.get("KFT_FLASH_BLOCK_K", "0"))
-FLASH_DIMSEM = _os.environ.get("KFT_FLASH_DIMSEM", "") == "1"
 
 
 def _flash_backward_xla(qf, kf, vf, bias, gf, lse, dd, *, b, h, lq, lk, d,
@@ -1452,14 +1645,15 @@ def flash_attention(q, k, v, bias, dropout_rng=None, dropout_rate=0.0,
     forward AND backward; attention dropout unsupported. window > 0
     (requires causal) is the Mistral sliding window — whole KV blocks
     outside the window are skipped in forward and backward, making the
-    attention cost O(L·window) instead of O(L²/2)."""
+    attention cost O(L·window) instead of O(L²/2). `block` is the
+    backward's tile and the blockwise fallback's (lengths it does not tile
+    take the fallback); the forward kernel chooses its own tile from the
+    shapes (flash_forward_tiling)."""
     if dropout_rate:
         raise NotImplementedError("attention dropout unsupported in flash path")
     if window and not causal:
         raise ValueError("attention window requires causal=True")
 
-    # KFT_FLASH_BLOCK_Q/K apply inside _flash_forward (forward tile only;
-    # the backward keeps this block — its validated geometry)
     def per_device(q, k, v, bias):
         return _flash(q, k, v, bias, block, block, causal, window)
 

@@ -1,0 +1,60 @@
+"""The forward flash kernel as Mosaic compiles it for a v5e that is described, not
+attached: what interpret mode cannot see (a slice off the tiling, more VMEM than a kernel
+may use) is refused here, at no chip time. Nothing runs, so nothing here is a result or a
+time; `test_flash_forward_on_the_chip_matches_float32_attention` is the verdict on both.
+
+The topology is described inside a fixture, never at import: one process a machine may
+load the TPU's library, and every xdist worker imports this file."""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from kubeflow_tpu.parallel import ring_attention as ra
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps the library from loading here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+CALLS = [
+    # (b, lq, h, d), dtype, causal, window -> the kernel the rule names
+    ((8, 1024, 16, 64), jnp.bfloat16, True, 0, "flash_fwd_resident_q256_k512"),  # gpt2m-train-1k
+    ((8, 512, 12, 64), jnp.bfloat16, False, 0, "flash_fwd_resident_q512_k512_g4"),  # BERT-base
+    ((8, 256, 12, 64), jnp.bfloat16, False, 0, "flash_fwd_resident_q256_k256_g4"),  # ViT-B/16
+    ((1, 4096, 32, 128), jnp.bfloat16, True, 1024, "flash_fwd_resident_q256_k512"),  # Mistral's window
+    ((1, 8192, 16, 64), jnp.bfloat16, True, 0, "flash_fwd_resident_q256_k512"),
+    ((1, 32768, 8, 128), jnp.bfloat16, True, 0, "flash_fwd_kvgrid_q512_k1024"),
+    ((1, 32768, 4, 128), jnp.bfloat16, True, 4096, "flash_fwd_kvgrid_q512_k1024"),
+    ((2, 1024, 16, 64), jnp.float32, True, 0, "flash_fwd_resident_q256_k512"),
+]
+
+
+@pytest.mark.parametrize("shape,dtype,causal,window,name", CALLS)
+def test_forward_kernel_compiles_for_the_v5e(one_chip, monkeypatch, shape, dtype, causal,
+                                             window, name):
+    b, length, _, _ = shape
+    qkv = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((b, 1, 1, length), jnp.float32, sharding=one_chip)
+    # `_flash_forward_tiled` asks the backend whether to interpret; here the CPU answers
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(lambda q, k, v, bias: ra._flash_forward(
+        q, k, v, bias, 128, 128, causal, want_lse=True, window=window)
+    ).lower(qkv, qkv, qkv, bias).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the branch and the tile, where a trace and the ledger's `device_ops` show them
+    assert f"/{name}/pallas_call" in text

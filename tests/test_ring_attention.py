@@ -487,6 +487,290 @@ class TestSlidingWindowFlash:
                                    rtol=2e-5, atol=2e-5)
 
 
+def _attention_f32(q, k, v, bias, causal=False, window=0):
+    """Plain float32 attention at `highest` -> (out, lse (B*H, Lq, 1)): the
+    reference for the forward kernel's two outputs."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    b, lq, h, d = q.shape
+    lk = k.shape[1]
+    s = jnp.einsum("blhd,bmhd->bhlm", q, k, precision="highest") / d**0.5
+    s = s + bias.astype(jnp.float32)
+    if causal:
+        rows, cols = jnp.arange(lq)[:, None], jnp.arange(lk)[None, :]
+        masked = cols > rows
+        if window:
+            masked = masked | (rows - cols >= window)
+        s = s + jnp.where(masked, -1e9, 0.0)
+    out = jnp.einsum("bhlm,bmhd->blhd", jax.nn.softmax(s, -1), v,
+                     precision="highest")
+    return out, jax.nn.logsumexp(s, -1).reshape(b * h, lq, 1)
+
+
+def _qkvb(lq, lk, pad=0, b=2, h=4, d=16, seed=5, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (b, lq, h, d), jnp.float32).astype(dtype)
+    k = jax.random.normal(ks[1], (b, lk, h, d), jnp.float32).astype(dtype)
+    v = jax.random.normal(ks[2], (b, lk, h, d), jnp.float32).astype(dtype)
+    bias = jax.random.normal(ks[3], (b, 1, 1, lk), jnp.float32) * 0.3
+    if pad:
+        bias = bias.at[:, :, :, lk - pad:].set(-1e9)
+    return q, k, v, bias
+
+
+class TestFlashForwardTiling:
+    """The forward kernel's two branches (K/V resident in VMEM with the KV
+    loop inside the kernel; KV axis on the grid) against float32 attention,
+    `out` and `lse` both, at tiles that put the diagonal, the window's edge
+    and the padding inside, across and between tiles; and the rule that
+    chooses the tile from the call's shapes."""
+
+    # (lq, lk, causal, window, pad, block_q, block_k, heads a step)
+    GEOMETRIES = {
+        "causal-q-wider": (64, 64, True, 0, 0, 16, 8, 1),
+        "causal-k-wider": (64, 64, True, 0, 0, 8, 16, 1),
+        "causal-square-padded": (64, 64, True, 0, 9, 16, 16, 1),
+        "full-padding-bias": (64, 64, False, 0, 12, 32, 16, 1),
+        "window-crosses-tiles": (64, 64, True, 12, 0, 16, 8, 1),
+        "window-inside-a-tile": (64, 64, True, 5, 0, 8, 16, 1),
+        "window-wider-than-tiles": (64, 64, True, 40, 0, 16, 16, 1),
+        "window-self-only": (64, 64, True, 1, 0, 16, 16, 1),
+        "lq-under-lk": (32, 64, True, 0, 0, 16, 8, 1),
+        "lq-over-lk": (64, 32, True, 0, 0, 16, 8, 1),
+        "full-lq-under-lk": (32, 64, False, 0, 7, 16, 32, 1),
+        "one-tile": (64, 64, True, 0, 0, 64, 64, 1),
+    }
+
+    @pytest.mark.parametrize("resident", [True, False],
+                             ids=["resident", "kvgrid"])
+    @pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+    def test_out_and_lse_match_float32_attention(self, geometry, resident):
+        from kubeflow_tpu.parallel.ring_attention import (
+            FlashTiling,
+            _flash_forward_tiled,
+        )
+
+        lq, lk, causal, window, pad, bq, bk, group = self.GEOMETRIES[geometry]
+        q, k, v, bias = _qkvb(lq, lk, pad)
+        out, lse = _flash_forward_tiled(
+            q, k, v, bias, FlashTiling(resident, bq, bk, group), causal,
+            window)
+        want_out, want_lse = _attention_f32(q, k, v, bias, causal, window)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
+                                   rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("group", [2, 4])
+    def test_head_group_shares_its_batch_rows_bias(self, group):
+        from kubeflow_tpu.parallel.ring_attention import (
+            FlashTiling,
+            _flash_forward_tiled,
+        )
+
+        q, k, v, bias = _qkvb(64, 64)  # a random bias row a batch row
+        out, lse = _flash_forward_tiled(
+            q, k, v, bias, FlashTiling(True, 16, 8, group), True, 7)
+        want_out, want_lse = _attention_f32(q, k, v, bias, True, 7)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
+                                   rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("causal,window,vmem_budget,resident", [
+        (True, 0, None, True),       # the cell's lengths: resident K/V,
+        (False, 0, None, True),      # several query tiles and KV slices
+        (True, 300, None, True),
+        (True, 0, 600_000, False),   # K/V past the budget: KV on the grid
+    ])
+    def test_the_rules_choice_runs_each_branch(self, causal, window,
+                                               vmem_budget, resident):
+        from kubeflow_tpu.parallel import ring_attention as ra
+
+        q, k, v, bias = _qkvb(1024, 1024, pad=0 if causal else 100,
+                              b=1, h=2, d=16)
+        budget = ({} if vmem_budget is None
+                  else {"vmem_budget": vmem_budget})
+        tiling = ra.flash_forward_tiling(
+            1024, 1024, 16, q.dtype, causal, window, heads=2, **budget)
+        assert tiling.resident is resident
+        assert 1024 // tiling.block_q > 1 and 1024 // tiling.block_k > 1
+        assert tiling.name.startswith(
+            "flash_fwd_resident_q" if resident else "flash_fwd_kvgrid_q")
+        out, lse = ra._flash_forward_tiled(q, k, v, bias, tiling, causal,
+                                           window)
+        want_out, want_lse = _attention_f32(q, k, v, bias, causal, window)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
+                                   rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                                   rtol=2e-5, atol=2e-5)
+        if vmem_budget is None:
+            # what `_flash_forward` itself runs at these lengths
+            got, got_lse = ra._flash_forward(q, k, v, bias, 128, 128, causal,
+                                             want_lse=True, window=window)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(out))
+            np.testing.assert_array_equal(np.asarray(got_lse),
+                                          np.asarray(lse))
+
+    def test_a_length_that_does_not_tile_falls_back(self):
+        from kubeflow_tpu.parallel.ring_attention import _flash_forward
+
+        q, k, v, bias = _qkvb(200, 200, b=1, h=2)
+        out, lse = _flash_forward(q, k, v, bias, 128, 128, True,
+                                  want_lse=True)
+        assert lse is None
+        want, _ = _attention_f32(q, k, v, bias, True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+
+    def test_backward_consumes_the_new_forwards_lse(self):
+        """The forward tiles by the rule (two query tiles, two KV slices
+        here), the unchanged backward by the caller's block: `lse` is a
+        per-row statistic, so the two geometries are independent."""
+        from kubeflow_tpu.models.gpt import causal_dense_attention
+        from kubeflow_tpu.parallel.ring_attention import (
+            _flash,
+            flash_forward_tiling,
+        )
+
+        q, k, v, bias = _qkvb(1024, 1024, b=1, h=2, d=16)
+        tiling = flash_forward_tiling(1024, 1024, 16, q.dtype, True, heads=2)
+        assert (tiling.block_q, tiling.block_k) != (128, 128)
+        g = jax.random.normal(jax.random.PRNGKey(9), q.shape, jnp.float32)
+
+        def loss(attn, q, k, v, bias):
+            return (attn(q, k, v, bias) * g).sum()
+
+        got = jax.jit(jax.grad(
+            functools.partial(loss, lambda *a: _flash(*a, 128, 128, True, 0)),
+            argnums=(0, 1, 2, 3)))(q, k, v, bias)
+        want = jax.grad(functools.partial(loss, causal_dense_attention),
+                        argnums=(0, 1, 2, 3))(q, k, v, bias)
+        for name, a, b in zip(("dq", "dk", "dv", "dbias"), got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4, err_msg=name)
+
+    # (lq, lk, d, dtype, causal, window, block, heads) -> the call's name
+    RULE_TABLE = [
+        # gpt2m-train-1k's call
+        (1024, 1024, 64, jnp.bfloat16, True, 0, 128, 16,
+         "flash_fwd_resident_q256_k512"),
+        (1024, 1024, 64, jnp.float32, True, 0, 128, 16,
+         "flash_fwd_resident_q256_k512"),
+        # BERT at 512 and ViT at 256 patches: a head is one tile, four a step
+        (512, 512, 64, jnp.bfloat16, False, 0, 128, 12,
+         "flash_fwd_resident_q512_k512_g4"),
+        (256, 256, 64, jnp.bfloat16, False, 0, 128, 12,
+         "flash_fwd_resident_q256_k256_g4"),
+        (256, 256, 64, jnp.bfloat16, False, 0, 128, 6,
+         "flash_fwd_resident_q256_k256_g2"),
+        (4096, 4096, 128, jnp.bfloat16, True, 1024, 128, 32,
+         "flash_fwd_resident_q256_k512"),
+        (8192, 8192, 64, jnp.bfloat16, True, 0, 128, 16,
+         "flash_fwd_resident_q256_k512"),
+        # K and V of a head past the budget: 16k positions and up
+        (16384, 16384, 64, jnp.bfloat16, True, 0, 128, 16,
+         "flash_fwd_kvgrid_q512_k1024"),
+        (32768, 32768, 128, jnp.bfloat16, True, 0, 128, 32,
+         "flash_fwd_kvgrid_q512_k1024"),
+        (32768, 32768, 128, jnp.float32, False, 0, 128, 8,
+         "flash_fwd_kvgrid_q512_k1024"),
+        # 9 x 128: the only tiles are 128, 384 and 1152
+        (1152, 1152, 64, jnp.bfloat16, True, 0, 128, 16,
+         "flash_fwd_resident_q128_k384"),
+        (512, 2048, 64, jnp.bfloat16, False, 0, 128, 8,
+         "flash_fwd_resident_q512_k512"),
+        # the tests' own shapes: the caller's block is the granule
+        (64, 64, 16, jnp.float32, True, 5, 8, 4,
+         "flash_fwd_resident_q64_k64_g4"),
+        (32, 64, 16, jnp.float32, False, 0, 16, 3,
+         "flash_fwd_resident_q32_k64"),
+        (48, 48, 16, jnp.float32, True, 0, 16, 4,
+         "flash_fwd_resident_q48_k48_g4"),
+    ]
+
+    @pytest.mark.parametrize(
+        "lq,lk,d,dtype,causal,window,block,heads,name", RULE_TABLE)
+    def test_rule_divides_the_lengths_and_fits_the_budget(
+            self, lq, lk, d, dtype, causal, window, block, heads, name):
+        from kubeflow_tpu.parallel import ring_attention as ra
+
+        t = ra.flash_forward_tiling(lq, lk, d, dtype, causal, window,
+                                    block_q=block, block_k=block, heads=heads)
+        assert t.name == name
+        assert lq % t.block_q == 0 and lk % t.block_k == 0
+        # a multiple of the caller's granule: aligned wherever `block` is
+        assert t.block_q % min(block, lq) == 0
+        assert t.block_k % min(block, lk) == 0
+        assert heads % t.heads == 0
+        assert (ra._flash_fwd_vmem_bytes(t, lk, d, dtype)
+                <= ra.FLASH_FWD_VMEM_BUDGET)
+
+    def test_rule_shrinks_tiles_to_a_smaller_budget(self):
+        from kubeflow_tpu.parallel import ring_attention as ra
+
+        args = (4096, 4096, 128, jnp.bfloat16, True)
+        roomy = ra.flash_forward_tiling(*args)
+        assert roomy.resident
+        for budget in (3 * 2**20, 2**20, 300_000):
+            t = ra.flash_forward_tiling(*args, vmem_budget=budget)
+            assert not t.resident
+            assert 4096 % t.block_q == 0 and 4096 % t.block_k == 0
+            assert t.block_q % 128 == 0 and t.block_k % 128 == 0
+            assert (t.block_q, t.block_k) == (128, 128) or (
+                ra._flash_fwd_vmem_bytes(t, 4096, 128, jnp.bfloat16)
+                <= budget)
+
+    def test_no_environment_knob_chooses_the_tile(self):
+        """The tile is a function of the call's shapes: the three
+        capture-campaign variables are gone from the module."""
+        import inspect
+
+        from kubeflow_tpu.parallel import ring_attention as ra
+
+        assert "KFT_FLASH_BLOCK" not in inspect.getsource(ra)
+        assert "KFT_FLASH_DIMSEM" not in inspect.getsource(ra)
+        for name in ("FLASH_BLOCK_Q", "FLASH_BLOCK_K", "FLASH_DIMSEM"):
+            assert not hasattr(ra, name)
+
+
+def test_flash_forward_on_the_chip_matches_float32_attention():
+    """Chip only (`pytest --noconftest` through the chip tool; `conftest.py`
+    pins the CPU): the kernel as Mosaic compiles it at `gpt2m-train-1k`'s
+    shape against float32 attention at `highest`. Interpret mode has passed
+    kernels of this file that NaN on the chip, so this verdict is the one
+    that counts. The limits are the parent kernel's errors at this shape and
+    these inputs plus a rounding step (`CHIP_*_ERR_LIMIT` below)."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs the chip: Mosaic, not the interpreter, is judged")
+    from kubeflow_tpu.parallel.ring_attention import (
+        _flash_forward,
+        flash_forward_tiling,
+    )
+
+    q, k, v, bias = _qkvb(1024, 1024, b=8, h=16, d=64, seed=26,
+                          dtype=jnp.bfloat16)
+    bias = jnp.zeros_like(bias)
+    tiling = flash_forward_tiling(1024, 1024, 64, q.dtype, True, heads=16)
+    assert tiling.resident
+    out, lse = jax.jit(lambda *a: _flash_forward(
+        *a, 128, 128, True, want_lse=True))(q, k, v, bias)
+    want_out, want_lse = jax.jit(
+        lambda *a: _attention_f32(*a, causal=True))(q, k, v, bias)
+    out_err = float(jnp.abs(out.astype(jnp.float32) - want_out).max())
+    lse_err = float(jnp.abs(lse - want_lse).max())
+    print(f"{tiling.name}: out_err {out_err:.3e} lse_err {lse_err:.3e}")
+    assert bool(jnp.isfinite(lse).all())
+    assert out_err <= CHIP_OUT_ERR_LIMIT and lse_err <= CHIP_LSE_ERR_LIMIT
+
+
+#: the parent kernel's errors on the v5e at that shape and those inputs were
+#: 8.188e-3 (`out`, largest magnitude 3.78) and 7.63e-6 (`lse`, largest 7.87)
+#: (my chip run, PR 26, call 2). The limits add bf16's rounding of an output
+#: under 4 (half a step of 2**-6) and eight float32 steps of an lse under 8.
+CHIP_OUT_ERR_LIMIT = 8.188e-3 + 2.0**-7
+CHIP_LSE_ERR_LIMIT = 7.63e-6 + 8 * 2.0**-21
+
+
 class TestBlockwiseCustomVJP:
     """The FA2-style custom VJP (r5 default — recompute p from saved lse,
     O(L) residuals, no reverse-AD through the online max/exp chain) must be
