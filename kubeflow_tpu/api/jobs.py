@@ -222,7 +222,7 @@ def apply_elastic_scale(job: TrainJob, replicas: int) -> None:
             sp.min_available = min(sp.min_available, job.total_replicas())
 
 
-TRAIN_FAMILIES = ("mnist", "resnet", "bert", "bert_pretrain", "gpt")
+TRAIN_FAMILIES = ("mnist", "resnet", "bert", "bert_pretrain", "gpt", "afmoe")
 
 
 def build_example_train_job(

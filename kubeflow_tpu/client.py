@@ -388,7 +388,7 @@ class TrainingClient:
         around `python -m examples.<family>`, submit it, wait, and return
         the final metrics parsed from worker-0's log.
 
-        family: mnist | resnet | bert | bert_pretrain | gpt
+        family: mnist | resnet | bert | bert_pretrain | gpt | afmoe
         args:   extra example flags (e.g. ["--steps=200", "--bf16"])
         elastic: (min_replicas, max_replicas) to attach an ElasticPolicy
         """

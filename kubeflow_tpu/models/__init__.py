@@ -11,6 +11,7 @@ from kubeflow_tpu.models.bert import (
     BertForMaskedLM,
     BertForSequenceClassification,
 )
+from kubeflow_tpu.models.afmoe import AfmoeConfig, AfmoeLM
 from kubeflow_tpu.models.bert_pp import BertPipelineClassifier
 from kubeflow_tpu.models.gpt_pp import GPTPipelineLM
 from kubeflow_tpu.models.gpt import (
@@ -33,6 +34,8 @@ from kubeflow_tpu.models.resnet import (
 )
 
 __all__ = [
+    "AfmoeConfig",
+    "AfmoeLM",
     "BertConfig",
     "BertEncoder",
     "BertForMaskedLM",

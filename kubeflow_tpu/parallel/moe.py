@@ -290,3 +290,240 @@ class MoeMlp(nn.Module):
         self.sow("losses", "moe_aux", aux,
                  reduce_fn=lambda a, b: a + b, init_fn=lambda: 0.0)
         return y
+
+
+# ---------------------------------------------------------------------------
+# The held-experts layer: this chip's share of an expert-parallel layer.
+#
+# The layer is told which experts it holds (`experts_held`, a range of the
+# router's outputs), routes every token over ALL of them, and computes its
+# own experts' part of the result for the tokens routed to them. What the
+# absent experts would add is left out: on one chip there is no exchange,
+# and no code stands in for it. No token is ever dropped: the rows a share
+# can be asked for are bounded by tokens x top_k, which is the static
+# shape, and the products run over the rows in use only.
+
+#: the flax collection of the router's state that is no parameter: the
+#: selection bias and the counts it is moved by. The Trainer carries it in
+#: TrainState.extra and reads the step's counters from it.
+ROUTER_STATE = "router_state"
+#: the grouped product's (rows, contraction, columns) tile. Of the tiles timed
+#: at published widths on the v5e the row tile moved nothing (128, 256, 512:
+#: within 2 %); 512 x 2048 x 1024 and 1024 x 1024 x 1024 were refused for VMEM
+GMM_TILING = (512, 1024, 1024)
+
+
+def _gmm_kwargs(m: int, k: int, n: int) -> dict:
+    return {"tiling": tuple(min(t, d) for t, d in zip(GMM_TILING, (m, k, n))),
+            "interpret": jax.default_backend() == "cpu"}
+
+
+def _gmm(lhs, rhs, group_sizes, transpose_rhs=False, out_dtype=None):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    return gmm(lhs, rhs, group_sizes, out_dtype or lhs.dtype,
+               transpose_rhs=transpose_rhs, **_gmm_kwargs(*lhs.shape, n))
+
+
+def _tgmm(lhs, grad, group_sizes, out_dtype):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+    return tgmm(lhs.swapaxes(0, 1), grad, group_sizes, out_dtype,
+                **_gmm_kwargs(*lhs.shape, grad.shape[1]))
+
+
+@jax.custom_vjp
+def grouped_matmul(rows, weights, group_sizes):
+    """rows (M, K) sorted by group, weights (G, K, N), group_sizes (G,)
+    int32 -> (M, N): rows of group g times weights[g]. The pallas grouped
+    product (megablox): its grid is the row tiles IN USE, so the work is in
+    proportion to sum(group_sizes), not to M. Rows past sum(group_sizes)
+    are NOT computed, here or in the gradient of `rows`: they hold whatever
+    the buffer held, and the caller masks them (`HeldExpertsMlp` does where
+    it gathers the rows and where it combines them)."""
+    return _gmm(rows, weights, group_sizes)
+
+
+def _grouped_matmul_fwd(rows, weights, group_sizes):
+    return _gmm(rows, weights, group_sizes), (rows, weights, group_sizes)
+
+
+def _grouped_matmul_bwd(res, g):
+    rows, weights, group_sizes = res
+    d_rows = _gmm(g, weights, group_sizes, transpose_rhs=True, out_dtype=rows.dtype)
+    d_weights = _tgmm(rows, g, group_sizes, weights.dtype)
+    return d_rows, d_weights, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+# Dispatch and combine are gathers in BOTH directions. XLA's transpose of a
+# gather is a scatter, which a TPU runs row by row (one layer's forward was
+# 10.6 ms with a scatter-add combine and 6.7 ms with gathers, forward and
+# backward 27.5 and 20.3 ms, at 8,192 tokens of published widths: PERF.md,
+# PR 28), so the two gathers have their gradients written out.
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch(xt, order, inverse, held, top_k):
+    """xt (T, H) -> (T*K, H): row i is the token of the i-th (token, choice)
+    pair in sorted order, `order[i] // top_k`. `inverse` is the inverse
+    permutation of `order`; `held` (T*K,) says, pair by pair in token order,
+    whether its expert is held here. The gradient brings the pairs'
+    cotangents back to token order, the absent experts' masked (the grouped
+    product never wrote them), and sums over a token's choices."""
+    return xt[order // top_k]
+
+
+def _dispatch_fwd(xt, order, inverse, held, top_k):
+    return xt[order // top_k], (inverse, held)
+
+
+def _dispatch_bwd(top_k, res, g):
+    inverse, held = res
+    back = jnp.where(held[:, None], g[inverse], jnp.zeros((), g.dtype))
+    d_xt = back.reshape(-1, top_k, g.shape[-1]).astype(jnp.float32).sum(1)
+    return d_xt.astype(g.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _permute(x, perm, inverse):
+    """x[perm] for a permutation `perm` of the rows whose inverse is
+    `inverse`; the gradient is the cotangent permuted back, a gather too."""
+    return x[perm]
+
+
+_permute.defvjp(lambda x, perm, inverse: (x[perm], (perm, inverse)),
+                lambda res, g: (g[res[1]], None, None))
+
+
+def route_sigmoid(x, kernel, bias, top_k: int, scale: float):
+    """The sigmoid router: x (T, H), kernel (H, E), bias (E,) -> (idx (T, K)
+    int32, weights (T, K) f32, scores (T, E) f32). Scores are sigmoids of
+    the router's product in float32; the bias takes part in the choice of
+    the K experts and not in their weights, which are the chosen scores
+    normalised over the K and scaled."""
+    logits = jnp.dot(x.astype(jnp.float32), kernel.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    picked = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
+    return idx, weights, scores
+
+
+def router_counters(router_state) -> dict[str, jax.Array]:
+    """The step's routing counters out of the ROUTER_STATE collection (every
+    expert layer's `counts`, `rows_here` and `bias`), for the step's metrics:
+    `moe_rows_here` (rows the held experts computed, summed over layers),
+    `moe_load_max_over_mean` (the fullest of all the router's experts over
+    the mean, worst layer), `moe_bias_abs_max`."""
+    from flax.traverse_util import flatten_dict
+
+    flat = flatten_dict(router_state)
+    of = lambda name: [v for path, v in flat.items() if path[-1] == name]  # noqa: E731
+    return {
+        "moe_rows_here": sum(r.astype(jnp.float32) for r in of("rows_here")),
+        "moe_load_max_over_mean": jnp.stack(
+            [c.max() / jnp.maximum(c.mean(), 1e-9) for c in of("counts")]).max(),
+        "moe_bias_abs_max": jnp.stack([jnp.abs(b).max() for b in of("bias")]).max(),
+    }
+
+
+class HeldExpertsMlp(nn.Module):
+    """Sigmoid-routed SwiGLU experts beside a shared expert; this share holds
+    the routed experts `experts_held` = [lo, hi) of the router's
+    `num_experts`. x (B, L, H) -> (B, L, H):
+
+        shared(x) + sum over e in S(x), lo <= e < hi, of w_e(x) expert_e(x)
+
+    S and w come from `route_sigmoid` over all `num_experts`. Tokens x top_k
+    (token, choice) pairs are sorted by expert, the pairs of absent experts
+    last; the held experts' rows are a prefix of the sorted order and the
+    grouped products run over that prefix only. With `train=True` and a
+    mutable ROUTER_STATE the selection bias moves by `bias_update_rate`
+    against the step's load (no gradient, no optimizer state)."""
+
+    hidden_size: int
+    expert_dim: int
+    num_experts: int
+    top_k: int
+    experts_held: tuple[int, int] | None = None   # None: all of them
+    num_shared_experts: int = 1
+    route_scale: float = 1.0
+    bias_update_rate: float = 0.001
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
+        h, m, e, k = self.hidden_size, self.expert_dim, self.num_experts, self.top_k
+        lo, hi = self.experts_held or (0, e)
+        if not 0 <= lo < hi <= e:
+            raise ValueError(f"experts_held {self.experts_held} is no range of {e} experts")
+        held = hi - lo
+        router = self.param("router", nn.initializers.normal(stddev=0.02),
+                            (h, e), jnp.float32)
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=0)
+        w_gate = self.param("w_gate", init, (held, h, m))
+        w_up = self.param("w_up", init, (held, h, m))
+        w_down = self.param("w_down", init, (held, m, h))
+        zeros_e = lambda: jnp.zeros((e,), jnp.float32)  # noqa: E731
+        bias = self.variable(ROUTER_STATE, "bias", zeros_e)
+        counts = self.variable(ROUTER_STATE, "counts", zeros_e)
+        rows_here = self.variable(ROUTER_STATE, "rows_here",
+                                  lambda: jnp.zeros((), jnp.int32))
+
+        b, l, _ = x.shape
+        xt = x.reshape(b * l, h)
+        with jax.named_scope("moe.route"):
+            idx, weights, _ = route_sigmoid(xt, router, bias.value, k,
+                                            self.route_scale)
+            flat = idx.reshape(-1)                               # (T*K,)
+            load = (flat[:, None] == jnp.arange(e)).sum(0, dtype=jnp.int32)
+        with jax.named_scope("moe.dispatch"):
+            # sort the (token, choice) pairs by local expert, the absent
+            # experts' last: the held experts' rows are a prefix of the order
+            held_pair = (flat >= lo) & (flat < hi)
+            local = jnp.where(held_pair, flat - lo, held)
+            pairs = jnp.arange(b * l * k, dtype=jnp.int32)
+            _, order = jax.lax.sort((local, pairs), num_keys=1, is_stable=True)
+            _, inverse = jax.lax.sort((order, pairs), num_keys=1)
+            group_sizes = load[lo:hi]
+            rows = _dispatch(xt.astype(self.dtype), order, inverse, held_pair, k)
+        with jax.named_scope("moe.experts"):
+            cast = lambda w: w.astype(self.dtype)  # noqa: E731
+            hidden = (nn.silu(grouped_matmul(rows, cast(w_gate), group_sizes))
+                      * grouped_matmul(rows, cast(w_up), group_sizes))
+            y = grouped_matmul(hidden, cast(w_down), group_sizes)
+        with jax.named_scope("moe.combine"):
+            # back to token order; a pair whose expert is absent reads a row
+            # the grouped product never wrote, and is masked
+            held_tk = held_pair.reshape(b * l, k)
+            y = _permute(y, inverse, order).reshape(b * l, k, h)
+            y = jnp.where(held_tk[..., None], y, jnp.zeros((), y.dtype))
+            out = (y.astype(jnp.float32)
+                   * jnp.where(held_tk, weights, 0.0)[..., None]).sum(1)
+        with jax.named_scope("moe.shared"):
+            width = self.num_shared_experts * m
+            dense = lambda n, name: nn.Dense(  # noqa: E731
+                n, use_bias=False, dtype=self.dtype, name=name)
+            shared = dense(h, "shared_down")(
+                nn.silu(dense(width, "shared_gate")(xt)) * dense(width, "shared_up")(xt))
+
+        if train and self.is_mutable_collection(ROUTER_STATE) and not self.is_initializing():
+            load = load.astype(jnp.float32)
+            counts.value = load
+            rows_here.value = group_sizes.sum()
+            bias.value = bias.value + self.bias_update_rate * jnp.sign(load.mean() - load)
+        return (out + shared.astype(jnp.float32)).astype(self.dtype).reshape(b, l, h)
+
+
+HELD_EXPERTS_PARTITION_RULES: list[tuple[str, P]] = [
+    *MOE_PARTITION_RULES,
+    (r"moe/shared_(gate|up)/kernel$", P(AXIS_FSDP, AXIS_MODEL)),
+    (r"moe/shared_down/kernel$", P(AXIS_MODEL, AXIS_FSDP)),
+]

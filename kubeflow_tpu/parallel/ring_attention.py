@@ -1505,9 +1505,9 @@ def _flash_backward(q, k, v, bias, o, lse, g, block_q, block_k, causal,
     if (impl or FLASH_BWD_IMPL) == "xla":
         dqf, dkf, dvf, dbias = _flash_backward_xla(
             qf, kf, vf, bias, gf, lse, _dd(), b=b, h=h, lq=lq, lk=lk, d=d,
-            scale=scale, block_k=block_k, causal=causal,
-            out_dtypes=(q.dtype, k.dtype, v.dtype), bias_dtype=bias.dtype,
-            window=window,
+            scale=scale, block_k=flash_backward_xla_block(lk, block_k),
+            causal=causal, out_dtypes=(q.dtype, k.dtype, v.dtype),
+            bias_dtype=bias.dtype, window=window,
         )
         unfold = lambda t, L: t.reshape(b, h, L, d).transpose(0, 2, 1, 3)  # noqa: E731
         return unfold(dqf, lq), unfold(dkf, lk), unfold(dvf, lk), dbias
@@ -1670,3 +1670,26 @@ def flash_attention(q, k, v, bias, dropout_rng=None, dropout_rate=0.0,
         out_specs=FLASH_SPEC,
         check_vma=False,
     )(q, k, v, bias)
+
+
+#: the XLA backward's `while` walks at most this many KV blocks where the
+#: key length allows, and no block wider than the widest timed. Every
+#: iteration reads q, dO and the row statistics of ALL `lq` queries again
+#: and adds into the float32 dq, whatever the block's width, so a long
+#: context pays by the iteration: at (1, 8192, 32, 128) blocks of 128 / 256 /
+#: 512 (64 / 32 / 16 iterations) gave steps of 757.4 / 687.6 / 648.7 ms over
+#: five layers, at the same peak memory (PERF.md, PR 28). 1,024 was not
+#: timed. Up to 2k keys the caller's block stands: that is what
+#: `gpt2m-train-1k` has been measured with, and nothing shorter was timed.
+_FLASH_BWD_XLA_BLOCKS = 16
+_FLASH_BWD_XLA_WIDEST = 512
+
+
+def flash_backward_xla_block(lk: int, block: int) -> int:
+    """The XLA backward's KV block, from what the call can see: a multiple
+    of the caller's `block` (it tiles `lk`) that divides `lk`. It sits at the
+    end of the file because the Mosaic payload of the forward kernel embeds
+    its callers' line numbers (ROADMAP D17): a line added above
+    `flash_attention` is another program for every model that calls it."""
+    target = min(lk // _FLASH_BWD_XLA_BLOCKS, _FLASH_BWD_XLA_WIDEST)
+    return _largest_tile(lk, max(block, target), block)

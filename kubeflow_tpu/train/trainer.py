@@ -634,7 +634,7 @@ class Trainer:
         # the compile cache, as they were
         with jax.named_scope("train.grad_norm"):
             grad_norm = optax.global_norm(grads)
-        return new_state, {"loss": loss, "accuracy": acc, "grad_norm": grad_norm}
+        return new_state, _step_metrics(self.model, loss, acc, grad_norm, new_extra)
 
     def _eval_step(self, state: TrainState, batch) -> dict:
         x, y, w = batch  # w: validity mask for padded tail batches
@@ -1342,3 +1342,19 @@ class Trainer:
             "loss": tot_loss / max(count, 1),
             "accuracy": correct / max(count, 1),
         }
+
+
+def _step_metrics(model, loss, accuracy, grad_norm, extra) -> dict:
+    """The step's metrics, and what the model counts for itself a step: a
+    model may define `step_counters(extra) -> dict` of scalars out of the
+    collections the Trainer carries in `TrainState.extra` (AfmoeLM: its
+    routers' counters). Whether it does is seen while tracing, so a model
+    without it lowers to the step it always had. (The function sits after
+    the class because a line added above would move the callers of the flash
+    kernel, whose line numbers its Mosaic payload carries into the compile
+    cache's key: ROADMAP D17.)"""
+    metrics = {"loss": loss, "accuracy": accuracy, "grad_norm": grad_norm}
+    counters = getattr(model, "step_counters", None)
+    if counters is not None:
+        metrics.update(counters(extra))
+    return metrics

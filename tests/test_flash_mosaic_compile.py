@@ -40,6 +40,9 @@ CALLS = [
     ((1, 32768, 8, 128), jnp.bfloat16, True, 0, "flash_fwd_kvgrid_q512_k1024"),
     ((1, 32768, 4, 128), jnp.bfloat16, True, 4096, "flash_fwd_kvgrid_q512_k1024"),
     ((2, 1024, 16, 64), jnp.float32, True, 0, "flash_fwd_resident_q256_k512"),
+    # trinitym-train-8k: a sliding and a full layer
+    ((1, 8192, 32, 128), jnp.bfloat16, True, 2048, "flash_fwd_resident_q256_k512"),
+    ((1, 8192, 32, 128), jnp.bfloat16, True, 0, "flash_fwd_resident_q256_k512"),
 ]
 
 
@@ -58,3 +61,23 @@ def test_forward_kernel_compiles_for_the_v5e(one_chip, monkeypatch, shape, dtype
     assert "tpu_custom_call" in text
     # the branch and the tile, where a trace and the ledger's `device_ops` show them
     assert f"/{name}/pallas_call" in text
+
+
+@pytest.mark.parametrize("width,weights", [(2048, (16, 2048, 2048)), (1024, (16, 1024, 2048))])
+def test_grouped_expert_product_compiles_for_the_v5e(one_chip, monkeypatch, width, weights):
+    """`parallel/moe.py`'s grouped product at `trinitym-train-8k`'s widths, forward and
+    backward: 65,536 rows (8,192 tokens x 8 choices) against the gate-and-up weights of 16
+    held experts, and against their down weights. A tile that asks more VMEM than the
+    kernel may use is refused here (512 x 2048 x 1024 was, on the chip)."""
+    from kubeflow_tpu.parallel import moe
+
+    rows = jax.ShapeDtypeStruct((65536, width), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct(weights, jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss(rows, w, sizes):
+        return moe.grouped_matmul(rows, w, sizes).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(rows, w, sizes).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3  # gmm forward, gmm for the rows' gradient, tgmm

@@ -423,6 +423,43 @@ class TestSlidingWindowFlash:
         with pytest.raises(ValueError, match="causal"):
             blockwise_attention(q, k, v, bias, causal=False, window=4)
 
+    @pytest.mark.parametrize("lk,block,want", [
+        (1024, 128, 128),   # gpt2m-train-1k: the caller's block stands
+        (2048, 128, 128),
+        (4096, 128, 256),   # a sixteenth of the keys
+        (8192, 128, 512),   # trinitym-train-8k
+        (16384, 128, 512),  # no wider than the widest timed
+        (8192, 256, 512),
+        (6144, 128, 384),   # a multiple of the caller's block that divides the keys
+        (1536, 128, 128),
+        (64, 8, 8), (256, 8, 16), (32, 32, 32),
+    ])
+    def test_the_xla_backwards_block_follows_the_key_length(self, lk, block, want):
+        from kubeflow_tpu.parallel.ring_attention import flash_backward_xla_block
+
+        got = flash_backward_xla_block(lk, block)
+        assert got == want and lk % got == 0 and got % block == 0
+
+    @pytest.mark.parametrize("window", [0, 40])
+    def test_xla_backward_at_a_block_the_rule_widened_matches_dense_grads(self, window):
+        """256 keys at the caller's block of 8: the rule takes 16."""
+        from kubeflow_tpu.parallel.ring_attention import flash_attention
+
+        q, k, v, bias, g = self._qkvbg(l=256)
+
+        def loss_flash(q, k, v, bias):
+            return (flash_attention(q, k, v, bias, block=8, causal=True,
+                                    window=window) * g).sum()
+
+        def loss_dense(q, k, v, bias):
+            return (self._dense_ref(q, k, v, bias, window) * g).sum()
+
+        got = jax.grad(loss_flash, argnums=(0, 1, 2, 3))(q, k, v, bias)
+        want = jax.grad(loss_dense, argnums=(0, 1, 2, 3))(q, k, v, bias)
+        for name, a, b in zip(("dq", "dk", "dv", "dbias"), got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-4, err_msg=name)
+
     @pytest.mark.parametrize("attn", [ring_attention, ulysses_attention])
     @pytest.mark.parametrize("window", [5, 20, 40])
     def test_context_parallel_window_matches_dense(self, attn, window):
