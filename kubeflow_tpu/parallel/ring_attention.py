@@ -1071,9 +1071,10 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dd_ref,
 # (test_ring_attention pins it).
 # KFT_FLASH_BWD_IMPL overrides the default (ROADMAP S1(b) gives each
 # candidate its verdict against a float32 reference on the chip; D3 then
-# keeps one). Every variant tiles by flash_attention's `block`; the forward
-# tiles by its own rule (flash_forward_tiling), and `lse`, a per-row
-# statistic of shape (B*H, Lq, 1), is all that passes between the two.
+# keeps one). The pallas variants tile by flash_attention's `block`, the XLA
+# one by flash_backward_xla_blocks and the forward by flash_forward_tiling,
+# each from the shapes; `lse`, a per-row statistic of shape (B*H, Lq, 1), is
+# all that passes between forward and backward.
 import os as _os  # noqa: E402
 
 _FLASH_BWD_IMPLS = ("xla", "loop2", "ddpre", "loop", "scratch")
@@ -1086,64 +1087,105 @@ if FLASH_BWD_IMPL not in _FLASH_BWD_IMPLS:
 
 
 def _flash_backward_xla(qf, kf, vf, bias, gf, lse, dd, *, b, h, lq, lk, d,
-                        scale, block_k, causal, out_dtypes, bias_dtype,
-                        window: int = 0):
-    """Flash backward as XLA einsums over KV blocks, from saved residuals.
+                        scale, block_q, block_k, causal, out_dtypes,
+                        bias_dtype, window: int = 0):
+    """Flash backward as XLA einsums over the live (query block, KV block)
+    pairs, from saved residuals.
 
     Cheaper than jax.vjp(blockwise_attention) — which must REPLAY the
     whole online-softmax forward to rebuild residuals — by one full
     forward pass: p tiles come from exp(s − lse) with the lse the pallas
-    forward already saved. Memory stays bounded by materializing only a
-    (BH, Lq, block_k) score tile per scan step; XLA keeps the five
-    einsums per block on the MXU. Takes the same prefolded residuals as
-    the pallas variants (one shared prep in _flash_backward).
+    forward already saved. The time follows the score elements it touches
+    (float32 (BH, block_q, block_k) tiles of s, p, dp, ds), so it walks
+    `flash_backward_live_pairs` only: a `scan` over the KV blocks, and
+    inside it a loop over the query blocks that KV block's keys are visible
+    to. A pair the causal or window mask hides whole (probability and
+    gradient exactly 0) is never computed; the masks still apply inside a
+    live pair. Takes the same prefolded residuals as the pallas variants
+    (one shared prep in _flash_backward).
     """
     dq_dtype, dk_dtype, dv_dtype = out_dtypes
-    n_kv = lk // block_k
+    n_q, n_kv = lq // block_q, lk // block_k
+    pairs = flash_backward_live_pairs(lq, lk, block_q, block_k, causal,
+                                      window)
+    # a KV block's live query blocks are a range [first, first + count)
+    live = [[iq for iq, ik in pairs if ik == j] for j in range(n_kv)]
+    first = jnp.asarray([min(qs, default=0) for qs in live], jnp.int32)
+    count = jnp.asarray([len(qs) for qs in live], jnp.int32)
     # bias row per folded batch*head: (B,1,1,Lk) -> (BH, Lk)
     bias_bh = jnp.repeat(
         bias.reshape(b, lk).astype(jnp.float32), h, axis=0)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (lq, block_k), 0)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    cut = lambda t, j: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+        t, j * block_k, block_k, 1)
+    # the query side is indexed by block, through a (BH, n_q, block_q, ..)
+    # view: a dynamic index there is aligned by construction, where a dynamic
+    # offset along Lq is not, and the update of dq then rewrites far more
+    # than its block. One query block, every KV block live to it (every call
+    # that is not causal), needs neither the view nor the loop, and is the
+    # program it always was.
+    one = n_q == 1 and len(pairs) == n_kv
+    by_block = (lambda t: t) if one else (  # noqa: E731
+        lambda t: t.reshape(b * h, n_q, block_q, *t.shape[2:]))
+    block = (lambda t, i: t) if one else (  # noqa: E731
+        lambda t, i: jax.lax.dynamic_index_in_dim(t, i, 1, keepdims=False))
+    put = (lambda acc, t, i: t) if one else (  # noqa: E731
+        lambda acc, t, i: jax.lax.dynamic_update_index_in_dim(acc, t, i, 1))
+    qb, gb, lseb, ddb = by_block(qf), by_block(gf), by_block(lse), by_block(dd)
 
-    def step(dq_acc, j):
-        kj = jax.lax.dynamic_slice_in_dim(kf, j * block_k, block_k, 1)
-        vj = jax.lax.dynamic_slice_in_dim(vf, j * block_k, block_k, 1)
-        bj = jax.lax.dynamic_slice_in_dim(bias_bh, j * block_k, block_k, 1)
-        s = jnp.einsum("bqd,bkd->bqk", qf, kj,
-                       preferred_element_type=jnp.float32) * scale
-        s = s + bj[:, None, :]
-        if causal:
-            cols = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (lq, block_k), 1)
-            masked = cols > rows
-            if window:
-                masked = masked | (rows - cols >= window)
-            s = s + jnp.where(masked, NEG_INF, 0.0)
-        p = jnp.exp(s - lse)                                 # (BH, Lq, bk)
-        dp = jnp.einsum("bqd,bkd->bqk", gf, vj,
-                        preferred_element_type=jnp.float32)
-        ds32 = p * (dp - dd)
-        ds = ds32.astype(qf.dtype)  # bf16 onto the MXU, like the kernels
-        p16 = p.astype(qf.dtype)
-        dq_acc = dq_acc + jnp.einsum(
-            "bqk,bkd->bqd", ds, kj, preferred_element_type=jnp.float32)
-        dkj = jnp.einsum("bqk,bqd->bkd", ds, qf,
-                         preferred_element_type=jnp.float32) * scale
-        dvj = jnp.einsum("bqk,bqd->bkd", p16, gf,
-                         preferred_element_type=jnp.float32)
-        # bias is (B, 1, 1, Lk): reduce rows AND heads, in f32 (the
-        # pallas paths sum the f32 ds — a bf16 pre-cast would round
-        # every element before a Lq*h-long reduction)
-        dbj = ds32.sum(1).reshape(b, h, block_k).sum(1)
-        return dq_acc, (dkj, dvj, dbj)
+    def kv_step(dq_acc, xs):
+        j, first_j, count_j = xs
+        kj, vj, bj = cut(kf, j), cut(vf, j), cut(bias_bh, j)
 
-    dq_acc, (dks, dvs, dbs) = jax.lax.scan(
-        step, jnp.zeros((b * h, lq, d), jnp.float32), jnp.arange(n_kv))
-    dqf = (dq_acc * scale).astype(dq_dtype)
-    # scan stacks (n_kv, BH, bk, d): move the block axis back into Lk
-    dkf = jnp.moveaxis(dks, 0, 1).reshape(b * h, lk, d).astype(dk_dtype)
-    dvf = jnp.moveaxis(dvs, 0, 1).reshape(b * h, lk, d).astype(dv_dtype)
-    dbias = jnp.moveaxis(dbs, 0, 1).reshape(b, lk)[:, None, None, :]
+        def q_step(i, acc):
+            dq_acc, dkj, dvj, dbj = acc
+            qi, gi = block(qb, i), block(gb, i)
+            s = jnp.einsum("bqd,bkd->bqk", qi, kj,
+                           preferred_element_type=jnp.float32) * scale
+            s = s + bj[:, None, :]
+            if causal:
+                r, c = i * block_q + rows, j * block_k + cols
+                masked = c > r
+                if window:
+                    masked = masked | (r - c >= window)
+                s = s + jnp.where(masked, NEG_INF, 0.0)
+            p = jnp.exp(s - block(lseb, i))                  # (BH, bq, bk)
+            dp = jnp.einsum("bqd,bkd->bqk", gi, vj,
+                            preferred_element_type=jnp.float32)
+            ds32 = p * (dp - block(ddb, i))
+            ds = ds32.astype(qf.dtype)  # bf16 onto the MXU, like the kernels
+            p16 = p.astype(qf.dtype)
+            dq_acc = put(dq_acc, block(dq_acc, i) + jnp.einsum(
+                "bqk,bkd->bqd", ds, kj,
+                preferred_element_type=jnp.float32), i)
+            dkj = dkj + jnp.einsum("bqk,bqd->bkd", ds, qi,
+                                   preferred_element_type=jnp.float32)
+            dvj = dvj + jnp.einsum("bqk,bqd->bkd", p16, gi,
+                                   preferred_element_type=jnp.float32)
+            # bias is (B, 1, 1, Lk): reduce rows AND heads, in f32 (the
+            # pallas paths sum the f32 ds — a bf16 pre-cast would round
+            # every element before a Lq*h-long reduction)
+            dbj = dbj + ds32.sum(1).reshape(b, h, block_k).sum(1)
+            return dq_acc, dkj, dvj, dbj
+
+        zeros = jnp.zeros((b * h, block_k, d), jnp.float32)
+        acc = (dq_acc, zeros, zeros, jnp.zeros((b, block_k), jnp.float32))
+        dq_acc, dkj, dvj, dbj = q_step(0, acc) if one else jax.lax.fori_loop(
+            first_j, first_j + count_j, q_step, acc)
+        return dq_acc, (dkj * scale, dvj, dbj)
+
+    # the scope names what ran in any trace, as the forward's kernel name does
+    with jax.named_scope(f"flash_bwd_xla_q{block_q}_k{block_k}"
+                         f"_live{len(pairs)}of{n_q * n_kv}"):
+        dq_acc, (dks, dvs, dbs) = jax.lax.scan(
+            kv_step, by_block(jnp.zeros((b * h, lq, d), jnp.float32)),
+            (jnp.arange(n_kv), first, count))
+        dqf = (dq_acc.reshape(b * h, lq, d) * scale).astype(dq_dtype)
+        # scan stacks (n_kv, BH, bk, d): move the block axis back into Lk
+        dkf = jnp.moveaxis(dks, 0, 1).reshape(b * h, lk, d).astype(dk_dtype)
+        dvf = jnp.moveaxis(dvs, 0, 1).reshape(b * h, lk, d).astype(dv_dtype)
+        dbias = jnp.moveaxis(dbs, 0, 1).reshape(b, lk)[:, None, None, :]
     return dqf, dkf, dvf, dbias.astype(bias_dtype)
 
 
@@ -1503,11 +1545,13 @@ def _flash_backward(q, k, v, bias, o, lse, g, block_q, block_k, causal,
             -1, keepdims=True)
 
     if (impl or FLASH_BWD_IMPL) == "xla":
+        xla_q, xla_k = flash_backward_xla_blocks(lq, lk, block_q, block_k,
+                                                 causal)
         dqf, dkf, dvf, dbias = _flash_backward_xla(
             qf, kf, vf, bias, gf, lse, _dd(), b=b, h=h, lq=lq, lk=lk, d=d,
-            scale=scale, block_k=flash_backward_xla_block(lk, block_k),
-            causal=causal, out_dtypes=(q.dtype, k.dtype, v.dtype),
-            bias_dtype=bias.dtype, window=window,
+            scale=scale, block_q=xla_q, block_k=xla_k, causal=causal,
+            out_dtypes=(q.dtype, k.dtype, v.dtype), bias_dtype=bias.dtype,
+            window=window,
         )
         unfold = lambda t, L: t.reshape(b, h, L, d).transpose(0, 2, 1, 3)  # noqa: E731
         return unfold(dqf, lq), unfold(dkf, lk), unfold(dvf, lk), dbias
@@ -1641,14 +1685,16 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q, k, v, bias, dropout_rng=None, dropout_rate=0.0,
                     block: int = 128, causal: bool = False,
                     window: int = 0):
-    """Pallas flash attention (single device / per-shard). Fused pallas
-    forward AND backward; attention dropout unsupported. window > 0
-    (requires causal) is the Mistral sliding window — whole KV blocks
-    outside the window are skipped in forward and backward, making the
-    attention cost O(L·window) instead of O(L²/2). `block` is the
-    backward's tile and the blockwise fallback's (lengths it does not tile
-    take the fallback); the forward kernel chooses its own tile from the
-    shapes (flash_forward_tiling)."""
+    """Pallas flash attention (single device / per-shard): a pallas
+    forward and a backward from its saved (out, lse), by default XLA's
+    (FLASH_BWD_IMPL); attention dropout unsupported. window > 0 (requires
+    causal) is the Mistral sliding window — block pairs the causal or window
+    mask hides whole are skipped in forward and backward
+    (flash_backward_live_pairs), making the attention cost O(L·window)
+    instead of O(L²/2). `block` is the granule of the backward's blocks
+    (flash_backward_xla_blocks widens them from the shapes) and the blockwise
+    fallback's (lengths it does not tile take the fallback); the forward
+    kernel chooses its own tile from the shapes (flash_forward_tiling)."""
     if dropout_rate:
         raise NotImplementedError("attention dropout unsupported in flash path")
     if window and not causal:
@@ -1672,24 +1718,55 @@ def flash_attention(q, k, v, bias, dropout_rng=None, dropout_rate=0.0,
     )(q, k, v, bias)
 
 
-#: the XLA backward's `while` walks at most this many KV blocks where the
-#: key length allows, and no block wider than the widest timed. Every
-#: iteration reads q, dO and the row statistics of ALL `lq` queries again
-#: and adds into the float32 dq, whatever the block's width, so a long
-#: context pays by the iteration: at (1, 8192, 32, 128) blocks of 128 / 256 /
-#: 512 (64 / 32 / 16 iterations) gave steps of 757.4 / 687.6 / 648.7 ms over
-#: five layers, at the same peak memory (PERF.md, PR 28). 1,024 was not
-#: timed. Up to 2k keys the caller's block stands: that is what
-#: `gpt2m-train-1k` has been measured with, and nothing shorter was timed.
-_FLASH_BWD_XLA_BLOCKS = 16
+#: the XLA backward's blocks, timed on the v5e at nine shapes (PERF.md, PR 29;
+#: ms a call). Under a causal mask narrow blocks hug the diagonal and wide
+#: ones pay fewer steps: no block wider than 512 (at (1,8192,32,128) with
+#: window 2,048: 512x512 8.49, 256x1024 9.25, 256x512 9.47, 512x256 11.30,
+#: 1024x512 12.34, the parent's whole square 67.6), eight query blocks to a
+#: length (at (8,1024,16,64): 128x256 1.50, 256x256 1.71, 64x256 1.89, the
+#: parent 2.98) and four KV blocks, whose step also pays the K/V slices and
+#: the write of dk and dv (128x128 1.60, 128x512 1.56). Not causal, every pair
+#: is live and nothing is gained: one query block, which needs no loop, and
+#: the KV block the parent walked with (a sixteenth of the keys up to 512, the
+#: caller's block up to 2k keys): the parent's program, and its time
+#: (BERT-like (32,512,12,64): 3.00 both; (1,8192,32,128): 67.6 both).
 _FLASH_BWD_XLA_WIDEST = 512
+_FLASH_BWD_XLA_Q_BLOCKS, _FLASH_BWD_XLA_KV_BLOCKS = 8, 4
+_FLASH_BWD_XLA_FULL_KV_BLOCKS = 16
 
 
-def flash_backward_xla_block(lk: int, block: int) -> int:
-    """The XLA backward's KV block, from what the call can see: a multiple
-    of the caller's `block` (it tiles `lk`) that divides `lk`. It sits at the
-    end of the file because the Mosaic payload of the forward kernel embeds
-    its callers' line numbers (ROADMAP D17): a line added above
-    `flash_attention` is another program for every model that calls it."""
-    target = min(lk // _FLASH_BWD_XLA_BLOCKS, _FLASH_BWD_XLA_WIDEST)
-    return _largest_tile(lk, max(block, target), block)
+def flash_backward_xla_blocks(lq: int, lk: int, block_q: int, block_k: int,
+                              causal: bool) -> tuple[int, int]:
+    """The XLA backward's (query block, KV block), from what the call can
+    see: multiples of the caller's blocks (they tile the lengths) that
+    divide the lengths, never under the caller's. It sits at the end of the
+    file because the Mosaic payload of the forward kernel embeds its callers'
+    line numbers (ROADMAP D17): a line added above `flash_attention` is
+    another program for every model that calls it."""
+    widest = _FLASH_BWD_XLA_WIDEST
+    if not causal:
+        kv = min(lk // _FLASH_BWD_XLA_FULL_KV_BLOCKS, widest)
+        return lq, _largest_tile(lk, max(block_k, kv), block_k)
+    want_q = min(lq // _FLASH_BWD_XLA_Q_BLOCKS, widest)
+    want_k = min(lk // _FLASH_BWD_XLA_KV_BLOCKS, widest)
+    return (_largest_tile(lq, max(block_q, want_q), block_q),
+            _largest_tile(lk, max(block_k, want_k), block_k))
+
+
+def flash_backward_live_pairs(lq: int, lk: int, block_q: int, block_k: int,
+                              causal: bool, window: int = 0
+                              ) -> list[tuple[int, int]]:
+    """The (query block, KV block) pairs with at least one visible element
+    under the mask as `_flash_backward_xla` applies it: query row `i` sees
+    key column `c` iff `c <= i` and, with a window, `i - c < window` (both
+    counted from 0). KV block major, the query blocks of one KV block in a
+    row; not causal: every pair. A KV block that no query sees (`lq < lk`)
+    has no pair, and its `dk`, `dv`, `dbias` stay zero."""
+    def live(iq, ik):
+        # i - c over the tile spans [lo, hi], every whole number between
+        lo = iq * block_q - (ik * block_k + block_k - 1)
+        hi = iq * block_q + block_q - 1 - ik * block_k
+        return not causal or (hi >= 0 and (not window or lo < window))
+
+    return [(iq, ik) for ik in range(lk // block_k)
+            for iq in range(lq // block_q) if live(iq, ik)]

@@ -1,5 +1,5 @@
-"""The forward flash kernel as Mosaic compiles it for a v5e that is described, not
-attached: what interpret mode cannot see (a slice off the tiling, more VMEM than a kernel
+"""The forward flash kernel as Mosaic compiles it, and the XLA backward that ships beside
+it, for a v5e that is described, not attached: what interpret mode cannot see (a slice off the tiling, more VMEM than a kernel
 may use) is refused here, at no chip time. Nothing runs, so nothing here is a result or a
 time; `test_flash_forward_on_the_chip_matches_float32_attention` is the verdict on both.
 
@@ -81,3 +81,27 @@ def test_grouped_expert_product_compiles_for_the_v5e(one_chip, monkeypatch, widt
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(rows, w, sizes).compile().as_text()
     assert text.count("tpu_custom_call") >= 3  # gmm forward, gmm for the rows' gradient, tgmm
+
+
+@pytest.mark.parametrize("shape,causal,window,name", [
+    ((8, 1024, 16, 64), True, 0, "flash_bwd_xla_q128_k256_live20of32"),  # gpt2m-train-1k
+    # trinitym-train-8k: a sliding and a full layer
+    ((1, 8192, 32, 128), True, 2048, "flash_bwd_xla_q512_k512_live70of256"),
+    ((1, 8192, 32, 128), True, 0, "flash_bwd_xla_q512_k512_live136of256"),
+    ((8, 512, 12, 64), False, 0, "flash_bwd_xla_q512_k128_live4of4"),  # BERT-base: nothing to skip
+])
+def test_xla_backward_compiles_for_the_v5e_under_its_scope(one_chip, shape, causal, window, name):
+    """The shipped backward (XLA's, not a kernel) at the rule's blocks: the scope a trace
+    shows, and temporaries of a few score tiles, not of the square."""
+    b, length, h, d = shape
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((b, 1, 1, length), jnp.float32, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((b * h, length, 1), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v, bias, o, lse, g: ra._flash_backward(
+        q, k, v, bias, o, lse, g, 128, 128, causal, impl="xla", window=window)
+    ).lower(qkv, qkv, qkv, bias, qkv, lse, qkv).compile()
+    assert f"/{name}/" in compiled.as_text()
+    block_q, block_k = ra.flash_backward_xla_blocks(length, length, 128, 128, causal)
+    tile = b * h * block_q * block_k * 4
+    operands = 7 * b * h * length * d * 4  # q, k, v, dO, and dq, dk, dv in float32
+    assert compiled.memory_analysis().temp_size_in_bytes <= operands + 6 * tile
