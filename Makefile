@@ -43,69 +43,60 @@ test-trace:
 test-health:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_health_drills.py -q -m health
 
-# profiling layer: trace analytics + golden trace-shape drill + the
-# CPU-proxy perf gate against tests/golden/prof_budgets.json
-# (docs/profiling.md; KFTPU_UPDATE_PROF_BUDGETS=1 regenerates budgets)
+# profiling layer: trace analytics + golden trace-shape drill
+# (docs/profiling.md)
 test-prof:
-	JAX_PLATFORMS=cpu python -m pytest tests/test_profiling.py tests/test_prof_gate.py -q -m prof
+	JAX_PLATFORMS=cpu python -m pytest tests/test_profiling.py -q -m prof
 
 # control-plane scale-out suite: sharded/filtered watch drills, keyed-pool
-# per-key ordering, status-write group commit, and the 10k-pod storm gate
+# per-key ordering, status-write group commit
 # (docs/architecture.md "Control-plane scaling")
 test-cplane:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_cplane.py -q -m cplane
-	JAX_PLATFORMS=cpu python -m pytest tests/test_prof_gate.py -q -m prof
 
 # serving-fleet suite: paged-KV prefix reuse, chunked-prefill equivalence,
-# router admission/shed + the seeded replica-kill drill, and the
-# serve_fleet cpu-proxy gate (docs/serving.md)
+# router admission/shed + the seeded replica-kill drill
+# (docs/serving.md)
 test-fleet:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_fleet.py -q -m fleet
-	JAX_PLATFORMS=cpu python -m pytest tests/test_prof_gate.py -q -m prof
 
 # training hot-path suite: restart-warm compile cache (warm incarnation
 # = zero backend compiles), AsyncLoader edge drills under the lock-order
-# detector, analytics splits, and the train_restart_warm cpu-proxy gate
-# (docs/perf.md "MFU hunt")
+# detector, analytics splits (docs/perf.md "MFU hunt")
 test-hotpath:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_hotpath.py -q -m hotpath
-	JAX_PLATFORMS=cpu python -m pytest tests/test_prof_gate.py -q -m prof
 
 # kftpu-partition suite: logical-axis rule derivation, legacy round-trip,
 # hybrid-mesh guard, bf16-by-default numerics gate, buffer-donation
-# accounting, and the grad_overlap cpu-proxy gate (docs/partitioner.md)
+# accounting (docs/partitioner.md)
 test-partition:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_partitioner.py -q -m partition
-	JAX_PLATFORMS=cpu python -m pytest tests/test_prof_gate.py -q -m prof
 
 # kftpu-reqtrace suite: serving request tracing (golden kill→requeue
 # trace shape), the bounded TSDB, SLO burn-rate evaluation, /debug/slo
-# surface agreement, and the decode-tick burn teeth in the prof gate
-# (docs/slo.md)
+# surface agreement (docs/slo.md)
 test-slo:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_slo.py -q -m slo
-	JAX_PLATFORMS=cpu python -m pytest tests/test_prof_gate.py -q -m prof
 
 # kftpu-decode suite: decode rows growing paged block chains
 # (allocate-on-boundary, COW-safe sharing), block-budgeted admission,
 # chain adoption by digest, speculative x chunked composition pinned
 # token-identical, the disaggregated prefill/decode tier, and the
-# resume-from-KV requeue drill + serve_disagg cpu-proxy gate
+# resume-from-KV requeue drill
 # (docs/serving.md "Disaggregated prefill/decode")
 test-decode:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_decode.py -q -m decode
-	JAX_PLATFORMS=cpu python -m pytest tests/test_prof_gate.py -q -m prof
 
 # kftpu-storm suite: the closed autoscaling loop (scale-up cooldown,
 # graceful-drain scale-down, loss-free drain-kill resume, scale-to-zero
 # + wake-on-arrival, hang detection, frozen-scaler chaos mode), the
 # golden scaler decision trace, activator cold-start Retry-After
-# calibration, SLO monitoring across scaler activity, and the seeded
-# production-day soak + its prod_day cpu-proxy gate
-# (docs/autoscaling.md)
+# calibration, SLO monitoring across scaler activity, the seeded
+# production day (healthy and with the scaler frozen) and the
+# chip-constrained diurnal storm (healthy and with the ledger frozen)
+# (docs/autoscaling.md, docs/scheduler.md)
 test-soak:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_soak.py -q -m soak
-	JAX_PLATFORMS=cpu python -m pytest tests/test_prof_gate.py -q -m prof
 
 # kftpu-pods suite: cross-process pod-backed replicas — real subprocess
 # workers behind the length-prefixed wire protocol over BOTH transports
@@ -114,23 +105,21 @@ test-soak:
 # heartbeat-age hang indictment + scaler replacement, torn-frame retry
 # idempotency, end-to-end deadline propagation, the network failure
 # family (severed-connection replay, stale-epoch 410 fencing, the
-# partition-heal split-brain drill), and the serve_pods/serve_pods_tcp
-# cpu-proxy gates with their wire-fault and net-fault teeth
+# partition-heal split-brain drill), and the kill drill under the
+# seeded wire- and net-fault plan
 # (docs/serving.md "Pod-backed replicas")
 test-pods:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_pods.py -q -m pods
-	JAX_PLATFORMS=cpu python -m pytest tests/test_prof_gate.py -q -m prof
 
 # kftpu-chipsched suite: the shared chip ledger both workload classes
 # claim through — slice-aware placement, priority preemption through
 # the gang-restart path (sched.preempt→job.gang_restart span link +
 # restart-warm resume), DRF tenant quotas with borrow/reclaim, the
-# deny/Retry-After contract, /debug/sched surface agreement, and the
-# diurnal_storm cpu-proxy gate with its sched_freeze teeth
+# deny/Retry-After contract, /debug/sched surface agreement (the
+# diurnal storm that drives it end to end is in tests/test_soak.py)
 # (docs/scheduler.md)
 test-sched:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_chipsched.py -q -m sched
-	JAX_PLATFORMS=cpu python -m pytest tests/test_prof_gate.py -q -m prof
 
 # kftpu-protocheck suite: exploration-kernel unit tests, HEAD-clean pins,
 # the per-mutation counterexample pins, and recorded-trace conformance

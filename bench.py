@@ -11,10 +11,6 @@ non-zero if any row failed.
   python bench.py                 # resnet50 images/sec/chip
   python bench.py --suite         # every bench, one JSON line each
   python bench.py --only NEEDLE   # the benches whose metric matches
-  python bench.py --cpu-proxy     # fixed-seed CPU workloads with phase
-                                  # breakdowns (profiling/cpu_proxy.py) — the
-                                  # tier-1 perf gate's input, no TPU needed;
-                                  # --only NEEDLE filters workloads
 
 The bench functions themselves stay callable at tiny sizes on the CPU (the
 tests do); a number from such a run is not a device metric.
@@ -581,88 +577,6 @@ SUITE_BENCHES = [
 ]
 
 
-#: capture-file pattern for --cpu-proxy rounds (repo root, checked in):
-#: CPU phase breakdowns and counts — not device metrics
-_CPU_PROXY_CAPTURE_RE = re.compile(r"BENCH_cpu_proxy_r(\d+)\.json$")
-
-
-def write_cpu_proxy_capture(results: list[dict],
-                            base_dir: str | None = None) -> str:
-    """Write a timestamped `BENCH_cpu_proxy_rNN.json` capture (workload ->
-    anchor units / phase seconds / gated ratios). NN is one past the
-    highest existing round, so
-    successive full runs build a trajectory instead of overwriting it;
-    test_bench pins this schema."""
-    base = base_dir or os.path.dirname(os.path.abspath(__file__))
-    rounds = []
-    for f in os.listdir(base):
-        m = _CPU_PROXY_CAPTURE_RE.match(f)
-        if m:
-            rounds.append(int(m.group(1)))
-    nn = max(rounds, default=0) + 1
-    import jax
-
-    workloads = {}
-    for r in results:
-        if r.get("skipped"):
-            workloads[r["workload"]] = {"skipped": r["skipped"]}
-            continue
-        workloads[r["workload"]] = {
-            k: r[k] for k in ("anchor", "anchor_s", "phases_s", "rel")
-            if k in r
-        }
-    payload = {
-        "captured_at": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
-        "round": nn,
-        "jax_version": jax.__version__,
-        "backend": "cpu",
-        "workloads": workloads,
-    }
-    path = os.path.join(base, f"BENCH_cpu_proxy_r{nn:02d}.json")
-    with open(path, "w") as fh:
-        json.dump(payload, fh, indent=2, sort_keys=True)
-        fh.write("\n")
-    return path
-
-
-def run_cpu_proxy(argv: list[str]) -> int:
-    """`bench.py --cpu-proxy`: the tier-1 perf surface (docs/profiling.md).
-
-    Runs the fixed-seed CPU workloads (profiling/cpu_proxy.py: traced MLP
-    train steps, continuous-serve ticks, a 200-pod traced reconcile storm,
-    and the 10k-pod cplane_storm — jobs/sec-to-Running + reconcile passes
-    per gang restart through the sharded watch/pool/coalesced-write path)
-    and emits ONE JSON line per workload with its phase breakdown and
-    anchor-relative ratios — the numbers the perf-gate test
-    (tests/test_prof_gate.py) compares against tests/golden/
-    prof_budgets.json. This path is deterministic and CPU-only by
-    construction; the pods its workloads spawn take their platform from
-    this process's environment.
-    """
-    from kubeflow_tpu.profiling.cpu_proxy import run_all
-    from kubeflow_tpu.utils.device import select_device
-
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    select_device("cpu")
-
-    only = ""
-    if "--only" in argv:
-        only = argv[argv.index("--only") + 1]
-    results = []
-    for rec in run_all(only=only):
-        results.append(rec)
-        print(json.dumps(rec))
-        sys.stdout.flush()
-    if not only:
-        # full runs bank a BENCH_cpu_proxy_rNN.json round (the CPU-side
-        # perf trajectory); filtered runs are working probes and bank
-        # nothing — a partial round would read as a regression of the
-        # missing workloads
-        path = write_cpu_proxy_capture(results)
-        print(json.dumps({"cpu_proxy_capture": os.path.basename(path)}))
-    return 0
-
-
 def _benches(argv: list[str]) -> list:
     """The bench list an invocation owes, from its arguments."""
     if "--only" in argv:
@@ -694,8 +608,6 @@ def require_tpu() -> dict:
 
 def main(argv: list[str] | None = None) -> int:
     argv = sys.argv[1:] if argv is None else argv
-    if "--cpu-proxy" in argv:
-        return run_cpu_proxy(argv)
     dev = require_tpu()
     failed = 0
     for bench, metric, unit in _benches(argv):

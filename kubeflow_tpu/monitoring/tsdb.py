@@ -22,10 +22,9 @@ buffer:
 Samples arrive two ways: `sample_platform` scrapes the EXISTING
 `kftpu_*` exposition on a tick (one build path with /metrics — see
 sampler.py), and hot-path producers (the serving engine's decode-tick /
-TTFT hooks) record directly — a perf_counter read plus a deque append,
-cheap enough that the decode-tick perf gate cannot see it
-(tests/test_prof_gate.py keeps the budget with sampling live; per
-2011.03641 the monitoring plane must stay off the hot path).
+TTFT hooks) record directly — a perf_counter read plus a deque append
+(per 2011.03641 the monitoring plane must stay off the hot path; what
+it costs on the chip is not measured).
 """
 
 from __future__ import annotations

@@ -3,13 +3,11 @@
 The answer layer on top of tracing/ (docs/profiling.md): step-time
 breakdowns with an explicit stall remainder, goodput per job incarnation
 with restart overhead attributed along the causal chain, control-plane
-latency percentiles, golden-pinnable restart trace shapes, and the
-CPU-proxy perf workloads that gate `make test` on regressions.
+latency percentiles, and golden-pinnable restart trace shapes.
 
-Surfaces: `GET /debug/profile` (apiserver), the `profile` CLI subcommand,
-the `kftpu_prof_*` /metrics families (observability.py), and
-`bench.py --cpu-proxy` — all reading report.build_profile, so they agree
-by construction.
+Surfaces: `GET /debug/profile` (apiserver), the `profile` CLI subcommand
+and the `kftpu_prof_*` /metrics families (observability.py) — all
+reading report.build_profile, so they agree by construction.
 """
 
 from kubeflow_tpu.profiling.analytics import (

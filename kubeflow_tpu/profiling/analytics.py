@@ -35,7 +35,7 @@ DATA_NAMES = ("train.data_load",)
 CKPT_NAMES = ("checkpoint.save", "checkpoint.restore")
 #: gradient-communication spans accounted inside a cycle: host-visible
 #: time spent waiting on gradient collectives that did NOT overlap the
-#: backward pass (the grad_overlap cpu-proxy workload emits these; on
+#: backward pass (no producer in the package emits these today; on
 #: hardware a step with full comm/compute overlap shows ~zero here)
 COMM_NAMES = ("train.comm",)
 #: span names that only the PLATFORM process emits — used to tell a
@@ -92,8 +92,7 @@ def step_breakdown(spans: list[dict]) -> list[dict]:
     ``data_load + compute + checkpoint + comm + stall == wall`` (stall is
     the remainder, floored at 0 against float noise). ``comm`` counts
     `train.comm` spans — gradient-collective time left ON the critical
-    path; a fully overlapped step charges ~nothing here (ROADMAP item 5's
-    comm/compute-overlap front, gated by the grad_overlap workload).
+    path; a fully overlapped step charges ~nothing here.
 
     data_load itself splits sum-exactly into ``data_wait + data_assemble
     == data_load``: when the async host loader stamps a ``wait_s`` attr

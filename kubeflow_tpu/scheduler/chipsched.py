@@ -42,10 +42,9 @@ they all route through:
     the activator's Retry-After idiom, scheduler edition — plus a
     traced ``sched.deny`` event so a starved fleet's burn alert has a
     cause to point at.
-  - **chaos**: ``freeze()`` (KFTPU_PROF_CHAOS="sched_freeze:1" via the
-    diurnal-storm drill) stops all granting; the serving burn signal
-    keeps demanding, the SLO alert fires, and the prof gate fails —
-    tests/test_prof_gate.py pins both sides.
+  - **chaos**: ``freeze()`` (``run_diurnal_storm(cfg, frozen=True)``)
+    stops all granting; the serving burn signal keeps demanding and
+    the SLO alert fires — tests/test_soak.py pins both sides.
 
 Thread-safety: one ``make_lock``-named mutex guards the ledger
 (GuardedState-checked under KFTPU_LOCKCHECK=1). Evictor callbacks are

@@ -1156,10 +1156,7 @@ class ContinuousBatcher:
         if tsdb is not None:
             # the decode-tick SLO series (docs/slo.md): one sample per
             # dispatch, measured to the host-visible sync (np.asarray).
-            # Cost is one perf_counter read + a deque append — the
-            # decode_tick perf gate runs WITH this live and keeps its
-            # budget (tests/test_prof_gate.py), which is the off-the-
-            # hot-path claim in falsifiable form
+            # Cost is one perf_counter read + a deque append
             tsdb.record("serving.decode_tick_s",
                         time.perf_counter() - t_dec)
         for slot, req in enumerate(self._rows):

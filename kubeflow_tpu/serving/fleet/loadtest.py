@@ -19,10 +19,10 @@ Two drive modes share one report shape:
     (tests/test_fleet.py).
   - ``run_loadtest_sync`` (tick-driven): no threads, no sleeps — one
     round-robin tick across live replicas per step, arrivals and the
-    kill scheduled in TICK units. Everything the run does is engine
-    work, so TTFT expressed in anchor units is machine-speed invariant —
-    this is the cpu-proxy ``serve_fleet`` gate's mode
-    (profiling/cpu_proxy.py).
+    kill scheduled in TICK units, so what a seeded run counts
+    (drops, requeues, resumes, reused prefill tokens) is the same on
+    every machine — the mode of the seeded kill drills
+    (tests/test_fleet.py, tests/test_decode.py, tests/test_pods.py).
 
 Requests may carry a shared prefix (`shared_prefix` tokens prepended to
 every prompt) to exercise paged-KV prefix reuse under load.
@@ -228,11 +228,11 @@ def run_loadtest_sync(router: FleetRouter, prompts: list[np.ndarray],
                       on_tick=None) -> LoadReport:
     """Tick-driven run (no threads, no sleeps): arrivals land on seeded
     tick offsets, the kill fires at `kill_at_tick`, and every unit of
-    work is an engine tick — machine-speed cancels out of anchor-relative
-    ratios (the cpu-proxy serve_fleet mode). `on_tick(tick, router)`,
-    when given, runs after each round-robin pass — the monitoring
-    plane's sampling hook (the serve_fleet drill records the fleet's
-    counter families into its TSDB here)."""
+    work is an engine tick, so a seeded run's counts do not depend on
+    the machine. `on_tick(tick, router)`, when given, runs after each
+    round-robin pass — the monitoring plane's sampling hook (a drill
+    records the fleet's counter families into its TSDB here) and the
+    place a pods drill sends its SIGKILL from."""
     rng = random.Random(seed)
     arrivals: list[tuple[int, int]] = []  # (tick, prompt index)
     t = 0.0

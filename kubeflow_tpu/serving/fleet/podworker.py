@@ -151,18 +151,11 @@ class PodServer:
             tracer=(self.tracer
                     if getattr(self.tracer, "enabled", False) else None),
         )
-        repeats = int(spec.get("chaos_decode_repeats", 1))
-        if repeats > 1:
-            # the cpu-proxy gate's decode chaos, armed in-process from
-            # the spec (NOT the env: the controller decides per fleet)
-            from kubeflow_tpu.profiling.cpu_proxy import _arm_decode_chaos
-
-            _arm_decode_chaos([eng], repeats)
         return eng, pool
 
     def _warmup(self) -> None:
         """Compile every executable the serve phase dispatches BEFORE
-        the socket goes live — the gate measures serving, not XLA."""
+        the socket goes live — a pod that serves does not compile."""
         import numpy as np
 
         prompts = self.spec.get("warmup_prompts") or []

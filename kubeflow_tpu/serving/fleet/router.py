@@ -1036,7 +1036,7 @@ class FleetRouter:
             r.engine.stop()
 
     def run_until_idle(self) -> None:
-        """Synchronous drive (tests, the cpu-proxy scenario): round-robin
+        """Synchronous drive (tests, the seeded drills): round-robin
         one tick per live replica until every queue and row is empty."""
         while True:
             busy = False

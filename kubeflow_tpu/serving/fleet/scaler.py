@@ -141,10 +141,10 @@ class FleetScaler:
         self.monitor = monitor
         self.tracer = tracer if tracer is not None else router.tracer
         self.threaded = threaded
-        #: chaos hook (KFTPU_PROF_CHAOS="scaler_freeze:1" via the soak):
+        #: chaos hook (`run_prod_day(cfg, frozen=True)` via the soak):
         #: a frozen scaler keeps evaluating — and counting — but acts on
         #: nothing, which is exactly the outage the SLO burn alert must
-        #: catch (tests/test_prof_gate.py pins it)
+        #: catch (tests/test_soak.py pins it)
         self.frozen = False
         self._mu = make_lock("fleet.FleetScaler._mu")
         self._evals = 0

@@ -7,11 +7,11 @@ cold-start path), training-job churn on the control plane, and injected
 faults — replica kills, a pod hang, a torn checkpoint — with ONE report
 (`monitoring.build_slo_report` + `SLOMonitor.evaluate()` over the
 calibrated `default_slos()` set) gating goodput ratio, the restart-
-overhead budget, p99 TTFT, and zero dropped requests. Lands in tier-1
-as the `prod_day` cpu-proxy workload (profiling/cpu_proxy.py), with
-`KFTPU_PROF_CHAOS="scaler_freeze:1"` as the falsifiable teeth: a scaler
+overhead budget, p99 TTFT, and zero dropped requests. tests/test_soak.py
+runs the day once and holds each of its counts as a case;
+`run_prod_day(cfg, frozen=True)` is the falsifiable teeth: a scaler
 that stops reacting while the waves continue must fire the SLO
-burn-rate alert and fail the gate. docs/autoscaling.md is the guide.
+burn-rate alert. docs/autoscaling.md is the guide.
 
 kftpu-chipsched adds the diurnal storm (`run_diurnal_storm`): the same
 day re-run on a chip-CONSTRAINED cluster where peak serving demand
@@ -19,8 +19,8 @@ cannot fit without preempting batch training through the shared
 ChipScheduler ledger — real JAXJob gangs evicted via the gang-restart
 path, resumed when the trough frees chips, gated on preemption-to-
 resume latency, zero serving SLO violations, and a batch goodput
-floor. `KFTPU_PROF_CHAOS="sched_freeze:1"` (the ledger stops granting)
-is its teeth. docs/scheduler.md is the guide.
+floor. `run_diurnal_storm(cfg, frozen=True)` (the ledger stops
+granting) is its teeth. docs/scheduler.md is the guide.
 
 kftpu-net re-composes the day on REAL pods (`run_prod_day_pods`): a
 spawn_pod TCP fleet where the kills are SIGKILLs discovered through the

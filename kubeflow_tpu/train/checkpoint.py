@@ -62,6 +62,11 @@ class Checkpointer:
         self._async = async_save
         self._manifest_mu = make_lock("checkpoint.Checkpointer._manifest_mu")
         self._mgr = self._open()
+        #: the steps the manager kept at the last save (None before the
+        #: first): every other step on disk is being deleted by orbax's
+        #: finalize thread, and a manifest written into it fails that
+        #: thread's rmtree (ENOTEMPTY) and with it the next save
+        self._kept: set[int] | None = None
 
     def _open(self):
         return ocp.CheckpointManager(
@@ -87,10 +92,15 @@ class Checkpointer:
                 f"keep_best_metric {self.keep_best_metric!r} not in metrics "
                 f"{sorted(metrics)} — fix TrainerConfig.keep_best_metric"
             )
-        self._mgr.save(
-            step, args=ocp.args.StandardSave(state),
-            **({"metrics": metrics} if metrics is not None else {}),
-        )
+        # under the manifest lock: orbax picks the steps to delete inside
+        # save() and starts deleting them behind it, so no writer may be
+        # half-way through one of them, and the next must know which went
+        with self._manifest_mu:
+            self._mgr.save(
+                step, args=ocp.args.StandardSave(state),
+                **({"metrics": metrics} if metrics is not None else {}),
+            )
+            self._kept = set(self._mgr.all_steps())
         if self.verify:
             # sync mode: the step is committed, manifest inline. Async mode
             # hashes on a helper thread that first WAITS for this step's
@@ -243,8 +253,11 @@ class Checkpointer:
             return []
 
     def _write_manifests(self) -> None:
-        """Checksum-manifest every committed step that lacks one."""
+        """Checksum-manifest every committed step that lacks one and that
+        the manager keeps."""
         for step in self._committed_steps():
+            if self._kept is not None and step not in self._kept:
+                continue
             root = self._step_dir(step)
             manifest = os.path.join(root, CKPT_MANIFEST_NAME)
             if os.path.exists(manifest):

@@ -9,8 +9,8 @@ and training gang-restart (train/trainer.py warm_start):
      A re-traced program whose HLO matches a cached entry skips the XLA
      compiler entirely; the `/jax/compilation_cache/cache_misses`
      monitoring counter (install_compile_listener / compile_counts) is the
-     proof both the serving AOT tests and the `train_restart_warm`
-     cpu-proxy gate assert on.
+     proof both the serving AOT tests and the restart-warm test
+     (tests/test_hotpath.py) assert on.
   2. **Serialized executables**: `save_executable` / `load_executable`
      persist a jitted program's COMPILED form (jax.experimental.
      serialize_executable) keyed by `executable_key(...)` — reloading

@@ -70,12 +70,6 @@ ENV_LOCKCHECK = "KFTPU_LOCKCHECK"
 ENV_UPDATE_LINT_BASELINE = "KFTPU_UPDATE_LINT_BASELINE"
 #: "1" regenerates golden files (metrics exposition) instead of diffing
 ENV_UPDATE_GOLDEN = "KFTPU_UPDATE_GOLDEN"
-#: "1" regenerates the CPU-proxy perf budgets (tests/golden/
-#: prof_budgets.json) instead of gating against them (docs/profiling.md)
-ENV_UPDATE_PROF_BUDGETS = "KFTPU_UPDATE_PROF_BUDGETS"
-#: test-only chaos hook for the CPU-proxy perf gate: "phase:N[,phase:N]"
-#: repeats a phase's deterministic work N times (profiling/cpu_proxy.py)
-ENV_PROF_CHAOS = "KFTPU_PROF_CHAOS"
 #: exhaustive-BFS depth bound for the protocol model checker
 #: (analysis/protocheck — docs/analysis.md "Protocol model checking")
 ENV_MODELCHECK_DEPTH = "KFTPU_MODELCHECK_DEPTH"

@@ -1,4 +1,5 @@
-"""Custom predictor/transformer classes for serving tests.
+"""Custom predictor/transformer classes for serving tests, and the
+zero-drop watch the seeded kill drills share.
 
 Lives in an importable module (not the test file) because the custom-runtime
 contract loads 'module:Class' inside the server subprocess.
@@ -78,3 +79,30 @@ class TwoOutModel(Model):
     def predict(self, inputs):
         x = np.asarray(inputs)
         return {"doubled": x * 2.0, "plus1": x + 1.0}
+
+
+class ZeroDropWatch:
+    """The monitoring plane's half of a seeded kill drill: `on_tick`
+    (the load harness's hook) samples the fleet's failure counter into
+    a TSDB every tick; `verdict()` evaluates the zero-drop objective
+    over it and returns its state plus the names of the alerts fired."""
+
+    METRIC = "fleet.requests_failed_total"
+
+    def __init__(self):
+        from kubeflow_tpu.monitoring import TimeSeriesStore
+
+        self.tsdb = TimeSeriesStore()
+
+    def on_tick(self, _tick, router):
+        self.tsdb.record(self.METRIC, router.metrics["requests_failed_total"])
+
+    def verdict(self) -> dict:
+        from kubeflow_tpu.monitoring import SLOConfig, SLOMonitor
+
+        monitor = SLOMonitor(self.tsdb, (SLOConfig(
+            "serving_zero_drop", metric=self.METRIC, kind="increase",
+            budget=0.0, windows=((3600.0, 1.0),)),))
+        alerts = [a.slo for a in monitor.evaluate()]
+        (state,) = monitor.describe()
+        return {**state, "alerts": alerts}

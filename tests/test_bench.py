@@ -234,31 +234,3 @@ def test_resnet_probe_flag_adoption(tmp_path):
     with art.open("a") as fh:
         fh.write("RESULT resnet50_xla_7x7_fwdbwd_b128_ms=10.000 tflops=80.00\n")
     assert bench._resnet_probe_flags(128, str(art)) == ("7x7", "xla")
-
-
-def test_cpu_proxy_capture_schema(tmp_path):
-    """BENCH_cpu_proxy_rNN.json: the --cpu-proxy capture. Pins the
-    schema (workload -> anchor/phases/rel), the rNN numbering past the
-    highest existing round, and skipped-workload records."""
-    import bench
-
-    results = [
-        {"workload": "mlp_train", "anchor": "raw_fetch/compute",
-         "anchor_s": 0.002, "phases_s": {"data_load": 0.0025},
-         "rel": {"data_load": 1.2, "data_load_async": 0.02}},
-        {"workload": "serve_ticks", "skipped": "no jax feature"},
-    ]
-    p1 = bench.write_cpu_proxy_capture(results, base_dir=str(tmp_path))
-    assert p1.endswith("BENCH_cpu_proxy_r01.json")
-    cap = json.loads(open(p1).read())
-    assert cap["round"] == 1 and cap["backend"] == "cpu"
-    assert cap["captured_at"].endswith("Z") and "T" in cap["captured_at"]
-    assert cap["jax_version"]
-    w = cap["workloads"]["mlp_train"]
-    assert w["anchor"] == "raw_fetch/compute"
-    assert w["rel"]["data_load_async"] == 0.02
-    assert cap["workloads"]["serve_ticks"] == {"skipped": "no jax feature"}
-    # next round numbers past the highest existing capture
-    p2 = bench.write_cpu_proxy_capture(results, base_dir=str(tmp_path))
-    assert p2.endswith("BENCH_cpu_proxy_r02.json")
-    assert json.loads(open(p2).read())["round"] == 2

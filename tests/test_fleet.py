@@ -434,28 +434,59 @@ class TestFleetRouter:
             np.testing.assert_array_equal(h.result(timeout=1),
                                           _want(lm, p, 10))
 
-    def test_seeded_sync_drill_matches_cpu_proxy_shape(self, lm):
-        """The cpu-proxy scenario's exact drive mode, asserted on
-        counts: seeded arrivals, kill mid-run, zero drops, all complete,
-        prefix reuse measurably engaged (the serve_fleet gate then pins
-        the same run's timing machine-invariantly)."""
+    @pytest.fixture(scope="class")
+    def kill_drill(self, lm):
+        """The seeded tick-driven drill, run once: seeded arrivals on 3
+        replicas sharing one pool, replica 1 killed at tick 5, with the
+        monitoring plane attached (the engines' decode-tick samples and
+        the fleet's failure counter flow into one TSDB, the zero-drop
+        objective evaluated over it afterwards)."""
+        from tests.serving_fixtures import ZeroDropWatch
+
         model, variables = lm
+        watch = ZeroDropWatch()
         pool = PagedKVPool(block_size=4, capacity_blocks=256)
         router = FleetRouter(
             [ContinuousBatcher(model, variables, max_rows=2,
-                               paged_kv=pool, prefill_chunk=4)
+                               paged_kv=pool, prefill_chunk=4,
+                               tsdb=watch.tsdb)
              for _ in range(3)])
         prompts = make_prompts(12, seed=7, vocab=512, prompt_len=4,
                                shared_prefix=8)
-        report = run_loadtest_sync(router, prompts, seed=7,
-                                   mean_gap_ticks=0.7, new_tokens=6,
-                                   kill_at_tick=5, kill_replica=1)
-        assert report.dropped == 0
-        assert report.completed == 12
-        assert report.requeued >= 1
-        assert router.metrics["replica_kills_total"] == 1
-        assert report.prefill_tokens_reused > 0
-        assert len(report.ttft_s) == 12
+        report = run_loadtest_sync(
+            router, prompts, seed=7, mean_gap_ticks=0.7, new_tokens=6,
+            kill_at_tick=5, kill_replica=1, on_tick=watch.on_tick)
+        return {"report": report, "router": router, "tsdb": watch.tsdb,
+                "zero_drop": watch.verdict()}
+
+    KILL_DRILL = {
+        "zero_drops": lambda d: d["report"].dropped == 0,
+        "every_admission_completed": lambda d: d["report"].completed == 12,
+        "the_kill_requeued_work": lambda d: d["report"].requeued >= 1,
+        "one_replica_killed":
+            lambda d: d["router"].metrics["replica_kills_total"] == 1,
+        "prefix_reuse_engaged":
+            lambda d: d["report"].prefill_tokens_reused > 0,
+        "a_first_token_for_every_request":
+            lambda d: len(d["report"].ttft_s) == 12,
+        # the monitored half: the failure counter was sampled on every
+        # tick, never moved, and the zero-drop objective stayed quiet
+        # through the kill; the engines fed the decode-tick series
+        "failure_counter_sampled": lambda d: d["zero_drop"]["samples"] > 0,
+        "zero_drop_objective_quiet":
+            lambda d: d["zero_drop"]["fired"] is False
+            and d["zero_drop"]["alerts"] == [],
+        "decode_ticks_sampled": lambda d: len(
+            d["tsdb"].window("serving.decode_tick_s", 3600.0)) > 0,
+    }
+
+    @pytest.mark.parametrize("contract", list(KILL_DRILL))
+    def test_seeded_sync_kill_drill_holds(self, kill_drill, contract):
+        """Asserted on counts: seeded arrivals, kill mid-run, zero
+        drops, all complete, prefix reuse measurably engaged."""
+        d = kill_drill
+        assert self.KILL_DRILL[contract](d), (
+            d["report"].summary(), dict(d["router"].metrics), d["zero_drop"])
 
     def test_activator_pick_is_queue_depth_aware(self, lm):
         """The satellite: with a fleet load view wired, the activator's

@@ -408,6 +408,9 @@ class TestTrainerWarmStart:
             s1 = t1.init_state(x[:16])
             info1 = t1.warm_start(x[:16], y[:16])
             assert info1["enabled"] and "train_step" in info1["compiled"]
+            # the cold incarnation counted misses: the counter and the
+            # cache are live, so the warm zero below means something
+            assert info1["backend_misses"] > 0
             s1, m1 = t1.train_step(s1, (x[:16], y[:16]))
 
             jax.clear_caches()  # the simulated gang restart

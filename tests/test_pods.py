@@ -319,57 +319,160 @@ class TestPodLifecycle:
             time.sleep(0.1)
 
 
-class TestRouterIntegration:
-    def test_sigkill_mid_decode_zero_drop_chain_resume(self, state_dir,
-                                                       protolog):
-        """The acceptance drill in miniature (the full gated version is
-        the serve_pods cpu-proxy workload): prefill pod + two decode
-        pods behind the router, one decode pod SIGKILLed by PID
-        mid-run. Zero drops; at least one requeue rescued by resuming
-        the home-pool chain instead of re-decoding from scratch."""
-        home = PagedKVPool(block_size=4, capacity_blocks=512)
-        spec = _spec()
-        roles = (("pf-0", "prefill"), ("dc-0", "decode"),
-                 ("dc-1", "decode"))
-        clients = [spawn_pod(n, spec, state_dir, home_pool=home,
-                             connect=False) for n, _r in roles]
+def _kill_drill(state_dir, tmp_dir, tag, transport="unix",
+                chaos_profile=None, seed=31):
+    """The acceptance drill in miniature: a prefill pod and two decode
+    pods behind the router, the seeded mix, decode pod 0 SIGKILLed by
+    PID at tick 3. With `chaos_profile` the seeded fault plan of that
+    name is armed on the decode pods' client sockets after the
+    handshakes (so start-up never spends the fault budget). The
+    protocol event log is armed for the run and replayed through the
+    model acceptors. Returns the counts."""
+    from kubeflow_tpu.utils.envvars import ENV_PROTOLOG
+
+    home = PagedKVPool(block_size=4, capacity_blocks=512)
+    spec = _spec()
+    roles = ((f"{tag}-pf-0", "prefill"), (f"{tag}-dc-0", "decode"),
+             (f"{tag}-dc-1", "decode"))
+    log = tmp_dir / f"{tag}-protocol-events.jsonl"
+    clients = []
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv(ENV_PROTOLOG, str(log))
         try:
+            for n, _r in roles:
+                clients.append(spawn_pod(n, spec, state_dir, home_pool=home,
+                                         connect=False, transport=transport))
             for c in clients:
                 c.connect()
+            if chaos_profile is not None:
+                from kubeflow_tpu.chaos import ChaosEngine, FaultPlan
+
+                chaos = ChaosEngine(FaultPlan.from_seed(
+                    seed, profile=chaos_profile))
+                for c in clients[1:]:
+                    c.chaos = chaos
             router = FleetRouter([(c.name, c, role)
                                   for c, (_n, role) in zip(clients, roles)])
             wire_pod_deaths(router)
             victim = clients[1]
-            prompts = make_prompts(6, seed=31, vocab=VOCAB,
+            prompts = make_prompts(6, seed=seed, vocab=VOCAB,
                                    prompt_len=PROMPT, shared_prefix=PREFIX)
             killed = {"done": False}
 
             def on_tick(tick, _rtr):
                 if not killed["done"] and tick >= 3:
                     killed["done"] = True
-                    os.kill(victim.worker_pid, signal.SIGKILL)
+                    try:
+                        os.kill(victim.worker_pid, signal.SIGKILL)
+                    except ProcessLookupError:
+                        # the fault plan's partition already exhausted
+                        # the retries and the client reaped the worker
+                        pass
 
+            base = pod_metrics_snapshot()
             report = run_loadtest_sync(
-                router, prompts, seed=31, mean_gap_ticks=1.0,
+                router, prompts, seed=seed, mean_gap_ticks=1.0,
                 new_tokens=NEW, kill_replica=None, on_tick=on_tick)
-            rs = report.summary()
-            assert killed["done"]
-            assert rs["dropped"] == 0
-            assert rs["completed"] == len(prompts)
-            assert rs["requeued"] >= 1
-            assert rs["resumed"] >= 1  # chain rescue, not scratch
-            (vrep,) = [r for r in router.replicas
-                       if r.engine is victim]
-            assert not vrep.alive
-            assert router.metrics["replica_kills_total"] >= 1
-            assert router.metrics["prefill_handoffs_total"] == len(prompts)
+            now = pod_metrics_snapshot()
+            (vrep,) = [r for r in router.replicas if r.engine is victim]
+            rec = {
+                **report.summary(),
+                **{k: now[k] - base[k] for k in (
+                    "wire_retries_total", "net_reconnects_total",
+                    "net_duplicate_acks_refused_total", "kills_total",
+                    "handoff_bytes_total")},
+                "requests": len(prompts),
+                "kill_sent": killed["done"],
+                "victim_alive": vrep.alive,
+                "replica_kills": router.metrics["replica_kills_total"],
+                "handoffs": router.metrics["prefill_handoffs_total"],
+            }
         finally:
             for c in clients:
-                c.kill(timeout_s=2.0)
-        # the recorded trace is an ACCEPTED run of the protocol models —
-        # both protocols the drill exercises left real events behind
-        counts = protolog.counts()
-        assert counts["wire"] > 0 and counts["kv"] > 0
+                try:
+                    c.kill(timeout_s=2.0)
+                except (RuntimeError, OSError):  # teardown best-effort
+                    pass
+    # the recorded trace is an ACCEPTED run of the protocol models
+    # (check_trace raises TraceRejected otherwise) — both protocols the
+    # drill exercises left real events behind
+    from kubeflow_tpu.analysis.protocheck import check_trace, read_log
+
+    rec["trace_counts"] = check_trace(read_log(str(log)))
+    return rec
+
+
+#: what a SIGKILL mid-decode must leave, on any transport, faults or not
+KILL_DRILL = {
+    "kill_sent": lambda r: r["kill_sent"] is True,
+    "zero_drops": lambda r: r["dropped"] == 0,
+    "every_admission_completed": lambda r: r["completed"] == r["requests"],
+    "victim_marked_dead":
+        lambda r: r["victim_alive"] is False and r["replica_kills"] >= 1,
+    "a_pod_death_counted": lambda r: r["kills_total"] >= 1,
+    "trace_accepted_by_the_protocol_models":
+        lambda r: r["trace_counts"]["wire"] > 0
+        and r["trace_counts"]["kv"] > 0,
+}
+#: on a healthy wire: the rescue is a chain resume, every prompt is
+#: handed off by digest, and nothing is retried, redialed or refused
+HEALTHY_WIRE = {
+    **KILL_DRILL,
+    "the_kill_requeued_work": lambda r: r["requeued"] >= 1,
+    # rescued by resuming the home-pool chain, not a scratch re-decode
+    "resumed_from_the_home_pool_chain":
+        lambda r: r["resumed"] >= 1 and r["resumed_tokens"] >= 1,
+    "every_prompt_handed_off": lambda r: r["handoffs"] == r["requests"],
+    "chains_crossed_the_wire": lambda r: r["handoff_bytes_total"] > 0,
+    "no_wire_retry": lambda r: r["wire_retries_total"] == 0,
+    "no_redial": lambda r: r["net_reconnects_total"] == 0,
+    "no_duplicate_refused":
+        lambda r: r["net_duplicate_acks_refused_total"] == 0,
+}
+#: under the seeded `wire` plan (resets, torn frames, deadline delays,
+#: and the net family: black holes, half-open replies, duplicate
+#: deliveries, a partition): every fault is absorbed by a retry or a
+#: redial, never by a drop — and never silently. Whether the victim
+#: still carries rows when the SIGKILL lands is the plan's to decide
+#: (its partition may have fenced the pod first), so no requeue is asked
+FAULTY_WIRE = {
+    **KILL_DRILL,
+    "faults_left_fingerprints":
+        lambda r: r["wire_retries_total"] + r["net_reconnects_total"] >= 1,
+}
+
+
+class TestRouterIntegration:
+    @pytest.fixture(scope="class")
+    def unix_drill(self, state_dir, tmp_path_factory):
+        return _kill_drill(state_dir, tmp_path_factory.mktemp("unix-drill"),
+                           "ux")
+
+    @pytest.fixture(scope="class")
+    def tcp_drill(self, state_dir, tmp_path_factory):
+        return _kill_drill(state_dir, tmp_path_factory.mktemp("tcp-drill"),
+                           "tx", transport="tcp")
+
+    @pytest.fixture(scope="class")
+    def faulty_tcp_drill(self, state_dir, tmp_path_factory):
+        return _kill_drill(state_dir, tmp_path_factory.mktemp("fx-drill"),
+                           "fx", transport="tcp", chaos_profile="wire",
+                           seed=11)
+
+    @pytest.mark.parametrize("contract", list(HEALTHY_WIRE))
+    def test_sigkill_mid_decode_zero_drop_chain_resume(self, unix_drill,
+                                                       contract):
+        assert HEALTHY_WIRE[contract](unix_drill), unix_drill
+
+    @pytest.mark.parametrize("contract", list(HEALTHY_WIRE))
+    def test_sigkill_over_tcp_matches_the_unix_contract(self, tcp_drill,
+                                                        contract):
+        assert HEALTHY_WIRE[contract](tcp_drill), tcp_drill
+
+    @pytest.mark.parametrize("contract", list(FAULTY_WIRE))
+    def test_sigkill_under_seeded_wire_and_net_faults(self, faulty_tcp_drill,
+                                                      contract):
+        assert FAULTY_WIRE[contract](faulty_tcp_drill), faulty_tcp_drill
 
     def test_admission_window_kill_repicks(self, state_dir):
         """The regression ISSUE 16 names: a pod dying BETWEEN admission
