@@ -301,11 +301,19 @@ class MoeMlp(nn.Module):
 # absent experts would add is left out: on one chip there is no exchange,
 # and no code stands in for it. No token is ever dropped: the rows a share
 # can be asked for are bounded by tokens x top_k, which is the static
-# shape, and the products run over the rows in use only.
+# shape of every buffer of sorted rows, and the work follows the rows in
+# use: R = sum of the held experts' loads, a prefix of the sorted order.
+# The grouped products' grid is the row tiles in use; what else costs per
+# sorted row (the dispatch gather, the SwiGLU, the combine's gradient) walks
+# the chunks of ROW_CHUNK rows below R. Rows at and past R are written by no
+# one and read unmasked by no one. What costs per (token, choice) pair (the
+# two sums over a token's choices) covers all tokens x top_k pairs.
 
 #: the flax collection of the router's state that is no parameter: the
-#: selection bias and the counts it is moved by. The Trainer carries it in
-#: TrainState.extra and reads the step's counters from it.
+#: selection bias and the counts it is moved by (`bias`, `counts`), and
+#: `rows_here`, the rows in use R of the step. The Trainer carries it in
+#: TrainState.extra and reads the step's counters from it
+#: (`router_counters`: the rows walked are computed from `rows_here`).
 ROUTER_STATE = "router_state"
 #: the grouped product's (rows, contraction, columns) tile. Of the tiles timed
 #: at published widths on the v5e the row tile moved nothing (128, 256, 512:
@@ -339,9 +347,12 @@ def grouped_matmul(rows, weights, group_sizes):
     int32 -> (M, N): rows of group g times weights[g]. The pallas grouped
     product (megablox): its grid is the row tiles IN USE, so the work is in
     proportion to sum(group_sizes), not to M. Rows past sum(group_sizes)
-    are NOT computed, here or in the gradient of `rows`: they hold whatever
-    the buffer held, and the caller masks them (`HeldExpertsMlp` does where
-    it gathers the rows and where it combines them)."""
+    are NOT written, here or in the gradient of `rows`: they hold whatever
+    the buffer held (NaN, for all anyone knows), and they may hold anything
+    in `rows` and in the cotangent too: the kernels select by group, so
+    nothing of them reaches a written row or the gradient of `weights`.
+    The caller masks them where pairs go back to tokens (`_dispatch`'s
+    gradient and `_combine` select by `held`)."""
     return _gmm(rows, weights, group_sizes)
 
 
@@ -359,46 +370,188 @@ def _grouped_matmul_bwd(res, g):
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
-# Dispatch and combine are gathers in BOTH directions. XLA's transpose of a
-# gather is a scatter, which a TPU runs row by row (one layer's forward was
-# 10.6 ms with a scatter-add combine and 6.7 ms with gathers, forward and
-# backward 27.5 and 20.3 ms, at 8,192 tokens of published widths: PERF.md,
-# PR 28), so the two gathers have their gradients written out.
+# What costs per sorted row follows the rows in use. The (token, choice) pairs
+# are sorted with the held experts' first, so the rows in use are the prefix
+# [0, R), R = group_sizes.sum(), of a buffer whose static shape is the bound
+# tokens x top_k (rounded up to whole chunks). Every pass over such a buffer
+# is a loop over its chunks of ROW_CHUNK rows with the traced trip count
+# ceil(R / ROW_CHUNK): the chunk index is a dimension of its own, so the write
+# of a chunk is in place (an offset along the rows that the compiler cannot
+# know aligned is not: PERF.md, PR 29). Rows at and past R hold whatever the
+# buffer held, NaN included, and no one may read them unmasked: the grouped
+# products select by group, and the two sums over a token's choices below
+# select by `held`.
 
-@partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _dispatch(xt, order, inverse, held, top_k):
-    """xt (T, H) -> (T*K, H): row i is the token of the i-th (token, choice)
-    pair in sorted order, `order[i] // top_k`. `inverse` is the inverse
-    permutation of `order`; `held` (T*K,) says, pair by pair in token order,
-    whether its expert is held here. The gradient brings the pairs'
-    cotangents back to token order, the absent experts' masked (the grouped
-    product never wrote them), and sums over a token's choices."""
-    return xt[order // top_k]
+#: rows a trip of the per-row loops covers. One layer at published widths on
+#: the v5e, forward and backward at 12,288 rows in use: 17.18 ms at 1,024,
+#: 17.09 at 2,048, 17.05 at 4,096 (my chip run, PR 31, call 1): the trips cost
+#: little, and a larger chunk walks more rows past the rows in use (half a
+#: chunk a pass, on average).
+ROW_CHUNK = 2048
+#: a gather of 65,536 rows of 2,048 (bf16) out of a source of 96 MiB and less
+#: took 0.44 ms on the v5e whatever the indices, out of 128 MiB and more 2.2 to
+#: 2.3 ms, the identity included; masked and summed over a token's eight
+#: choices 0.97-1.07 against 2.77, and 3.4 out of a prefix of 128 to 192 MiB
+#: (my chip runs, PR 31, calls 2 and 3)
+SMALL_SOURCE_BYTES = 96 << 20
 
 
-def _dispatch_fwd(xt, order, inverse, held, top_k):
-    return xt[order // top_k], (inverse, held)
+def _unwritten(shape, dtype, after):
+    """A buffer no one has written (the loops below write the chunks in use),
+    allocated once `after` (any array) is computed and no earlier: a pallas
+    call that does nothing. `jax.lax.empty` has no operand, and the TPU's
+    scheduler then moves every layer's allocations to the start of the step,
+    where all are live at once (a step of `trinitym-train-8k` compiled to
+    11.1 GB of temporaries against 2.9: PERF.md, PR 31)."""
+    from jax.experimental import pallas as pl
+
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        lambda after_ref, out_ref: None, out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        in_specs=[anywhere], out_specs=anywhere, name="unwritten",
+        interpret=jax.default_backend() == "cpu")(after)
 
 
-def _dispatch_bwd(top_k, res, g):
-    inverse, held = res
-    back = jnp.where(held[:, None], g[inverse], jnp.zeros((), g.dtype))
-    d_xt = back.reshape(-1, top_k, g.shape[-1]).astype(jnp.float32).sum(1)
-    return d_xt.astype(g.dtype), None, None, None
+def _live_chunks(n_rows):
+    return (n_rows + ROW_CHUNK - 1) // ROW_CHUNK
+
+
+def _per_live_chunk(fn, n_rows, *operands, over: int = 0):
+    """fn row by row over the rows in use: `operands` are (M, ...) buffers of
+    sorted rows, fn takes a chunk of each, (ROW_CHUNK, ...), and returns a
+    tuple of chunks; the results are (M, ...) buffers written for the chunks
+    below `n_rows` (traced) and unwritten past that. The first `over` results
+    are written over the first `over` operands, chunk by chunk in place (same
+    shape and type; the operand is dead then)."""
+    by_chunk = tuple(a.reshape(-1, ROW_CHUNK, *a.shape[1:]) for a in operands)
+    shapes = jax.eval_shape(fn, *(a[0] for a in by_chunk))
+    fresh = tuple(_unwritten((by_chunk[0].shape[0], *s.shape), s.dtype, operands[0])
+                  for s in shapes[over:])
+    # where each result goes in the loop's carry (operands, then fresh buffers)
+    slots = (*range(over), *range(len(operands), len(operands) + len(fresh)))
+
+    def body(i, bufs):
+        bufs = list(bufs)
+        for slot, chunk in zip(slots, fn(*(a[i] for a in bufs[:len(operands)]))):
+            bufs[slot] = bufs[slot].at[i].set(chunk)
+        return tuple(bufs)
+
+    bufs = jax.lax.fori_loop(0, _live_chunks(n_rows), body, by_chunk + fresh)
+    return tuple(bufs[slot].reshape(-1, *bufs[slot].shape[2:]) for slot in slots)
+
+
+def _sum_over_choices(rows, inverse, held, scale, n_rows):
+    """rows (M, H) in sorted order -> (T, H) float32: token t's sum over its
+    choices k of scale[t, k] * rows[inverse[t, k]], the held pairs' only
+    (`inverse`, `held`, `scale` are (T, K); a pair whose expert is absent
+    reads a row no one wrote, and is masked). It costs per (token, choice)
+    pair, all T x K of them, but what a pair costs follows the STATIC size of
+    the gather's source (SMALL_SOURCE_BYTES), and the rows in use are the
+    prefix below `n_rows`: where they fit a source of that size, a branch on
+    `n_rows` gathers from the prefix alone."""
+
+    def over(src):
+        picked = jnp.where(held[..., None], src[jnp.minimum(inverse, src.shape[0] - 1)],
+                           jnp.zeros((), src.dtype)).astype(jnp.float32)
+        return (picked if scale is None
+                else picked * jnp.where(held, scale, 0.0)[..., None]).sum(1)
+
+    cap = SMALL_SOURCE_BYTES // (rows.shape[1] * rows.dtype.itemsize)
+    if not 0 < cap < rows.shape[0]:
+        return over(rows)
+    return jax.lax.cond(n_rows <= cap, lambda: over(rows[:cap]), lambda: over(rows))
+
+
+@jax.custom_vjp
+def _dispatch(xt, order, inverse, held, n_rows):
+    """xt (T, H) -> (M, H), M = T x K rounded up to whole chunks: row i <
+    n_rows is the token of the i-th (token, choice) pair in sorted order,
+    `order[i] // K`. Only the chunks below `n_rows` (traced, the rows in use)
+    are gathered; the rest is unwritten. `order` (M,) is the sorted order of
+    the pairs, padded; `inverse` (T, K) is its inverse permutation; `held`
+    (T, K) says whether a pair's expert is held here. The gradient brings the
+    pairs' cotangents back to token order, the absent experts' masked (their
+    rows are unwritten), and sums over a token's choices."""
+    return _dispatch_fwd(xt, order, inverse, held, n_rows)[0]
+
+
+def _dispatch_fwd(xt, order, inverse, held, n_rows):
+    rows, = _per_live_chunk(lambda tok: (xt[tok],), n_rows, order // held.shape[1])
+    return rows, (inverse, held, n_rows)
+
+
+def _dispatch_bwd(res, g):
+    inverse, held, n_rows = res
+    return _sum_over_choices(g, inverse, held, None, n_rows).astype(g.dtype), None, None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _permute(x, perm, inverse):
-    """x[perm] for a permutation `perm` of the rows whose inverse is
-    `inverse`; the gradient is the cotangent permuted back, a gather too."""
-    return x[perm]
+def _swiglu(gate_up, n_rows):
+    """gate_up (M, 2m), the gate's and the up projection's products side by
+    side -> silu(gate) * up, (M, m), over the chunks below `n_rows`; computed
+    in float32 and rounded once. The gradient covers the same chunks."""
+    return _swiglu_fwd(gate_up, n_rows)[0]
 
 
-_permute.defvjp(lambda x, perm, inverse: (x[perm], (perm, inverse)),
-                lambda res, g: (g[res[1]], None, None))
+def _swiglu_fwd(gate_up, n_rows):
+    def act(gu):
+        gate, up = jnp.split(gu.astype(jnp.float32), 2, axis=-1)
+        return ((gate * jax.nn.sigmoid(gate) * up).astype(gu.dtype),)
+
+    hidden, = _per_live_chunk(act, n_rows, gate_up)
+    return hidden, (gate_up, n_rows)
+
+
+def _swiglu_bwd(res, g):
+    gate_up, n_rows = res
+
+    def d_act(gu, g):
+        gate, up = jnp.split(gu.astype(jnp.float32), 2, axis=-1)
+        g, s = g.astype(jnp.float32), jax.nn.sigmoid(gate)
+        d_gate = g * up * s * (1.0 + gate * (1.0 - s))
+        return (jnp.concatenate([d_gate, g * gate * s], axis=-1).astype(gu.dtype),)
+
+    d_gate_up, = _per_live_chunk(d_act, n_rows, gate_up, g, over=1)
+    return d_gate_up, None
+
+
+_swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, weights, order, inverse, held, n_rows):
+    """y (M, H) in sorted order, weights (T, K) float32 -> (T, H) float32:
+    token t's sum over its choices k of weights[t, k] * y[inverse[t, k]], the
+    held experts' only (`order`, `inverse`, `held` as `_dispatch` takes them).
+    The gradient is written in row space, over the chunks below `n_rows`:
+    d_y[r] = w[r] * d_out[token(r)], a gather from the T rows of d_out,
+    written over y, and d_w[r] = <y[r], d_out[token(r)]> in float32, brought
+    back to (T, K) by a gather of scalars; no (T, K, H) cotangent exists."""
+    return _combine_fwd(y, weights, order, inverse, held, n_rows)[0]
+
+
+def _combine_fwd(y, weights, order, inverse, held, n_rows):
+    return (_sum_over_choices(y, inverse, held, weights, n_rows),
+            (y, weights, order, inverse, held, n_rows))
+
+
+def _combine_bwd(res, g):
+    y, weights, order, inverse, held, n_rows = res
+    flat = weights.reshape(-1)
+
+    def d_rows(y, pair):
+        d = g[pair // held.shape[1]]
+        return ((flat[pair][:, None] * d).astype(y.dtype),
+                (y.astype(jnp.float32) * d).sum(-1))
+
+    d_y, d_w = _per_live_chunk(d_rows, n_rows, y, order, over=1)
+    return d_y, jnp.where(held, d_w[inverse], 0.0), None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def route_sigmoid(x, kernel, bias, top_k: int, scale: float):
@@ -420,6 +573,8 @@ def router_counters(router_state) -> dict[str, jax.Array]:
     """The step's routing counters out of the ROUTER_STATE collection (every
     expert layer's `counts`, `rows_here` and `bias`), for the step's metrics:
     `moe_rows_here` (rows the held experts computed, summed over layers),
+    `moe_rows_walked` (rows the layers' per-row loops covered: each layer's
+    rows in use rounded up to whole chunks of ROW_CHUNK),
     `moe_load_max_over_mean` (the fullest of all the router's experts over
     the mean, worst layer), `moe_bias_abs_max`."""
     from flax.traverse_util import flatten_dict
@@ -428,6 +583,8 @@ def router_counters(router_state) -> dict[str, jax.Array]:
     of = lambda name: [v for path, v in flat.items() if path[-1] == name]  # noqa: E731
     return {
         "moe_rows_here": sum(r.astype(jnp.float32) for r in of("rows_here")),
+        "moe_rows_walked": sum((_live_chunks(r) * ROW_CHUNK).astype(jnp.float32)
+                               for r in of("rows_here")),
         "moe_load_max_over_mean": jnp.stack(
             [c.max() / jnp.maximum(c.mean(), 1e-9) for c in of("counts")]).max(),
         "moe_bias_abs_max": jnp.stack([jnp.abs(b).max() for b in of("bias")]).max(),
@@ -493,20 +650,19 @@ class HeldExpertsMlp(nn.Module):
             _, order = jax.lax.sort((local, pairs), num_keys=1, is_stable=True)
             _, inverse = jax.lax.sort((order, pairs), num_keys=1)
             group_sizes = load[lo:hi]
-            rows = _dispatch(xt.astype(self.dtype), order, inverse, held_pair, k)
+            n_rows = group_sizes.sum()
+            # whole chunks: the pairs past tokens x top_k are never in use
+            order = jnp.pad(order, (0, -order.size % ROW_CHUNK))
+            inverse, held_pair = inverse.reshape(b * l, k), held_pair.reshape(b * l, k)
+            rows = _dispatch(xt.astype(self.dtype), order, inverse, held_pair, n_rows)
         with jax.named_scope("moe.experts"):
             cast = lambda w: w.astype(self.dtype)  # noqa: E731
-            hidden = (nn.silu(grouped_matmul(rows, cast(w_gate), group_sizes))
-                      * grouped_matmul(rows, cast(w_up), group_sizes))
-            y = grouped_matmul(hidden, cast(w_down), group_sizes)
+            # gate and up side by side: one product of `rows`, one gradient
+            gate_up = grouped_matmul(
+                rows, jnp.concatenate([cast(w_gate), cast(w_up)], axis=-1), group_sizes)
+            y = grouped_matmul(_swiglu(gate_up, n_rows), cast(w_down), group_sizes)
         with jax.named_scope("moe.combine"):
-            # back to token order; a pair whose expert is absent reads a row
-            # the grouped product never wrote, and is masked
-            held_tk = held_pair.reshape(b * l, k)
-            y = _permute(y, inverse, order).reshape(b * l, k, h)
-            y = jnp.where(held_tk[..., None], y, jnp.zeros((), y.dtype))
-            out = (y.astype(jnp.float32)
-                   * jnp.where(held_tk, weights, 0.0)[..., None]).sum(1)
+            out = _combine(y, weights, order, inverse, held_pair, n_rows)
         with jax.named_scope("moe.shared"):
             width = self.num_shared_experts * m
             dense = lambda n, name: nn.Dense(  # noqa: E731
@@ -517,7 +673,7 @@ class HeldExpertsMlp(nn.Module):
         if train and self.is_mutable_collection(ROUTER_STATE) and not self.is_initializing():
             load = load.astype(jnp.float32)
             counts.value = load
-            rows_here.value = group_sizes.sum()
+            rows_here.value = n_rows
             bias.value = bias.value + self.bias_update_rate * jnp.sign(load.mean() - load)
         return (out + shared.astype(jnp.float32)).astype(self.dtype).reshape(b, l, h)
 

@@ -22,7 +22,7 @@ from benchmarks.families import afmoe as family
 from kubeflow_tpu.models.afmoe import (FULL, SLIDING, AfmoeAttention,
                                        AfmoeConfig, AfmoeLM)
 from kubeflow_tpu.models.gpt import causal_lm_eval_metrics, causal_lm_loss
-from kubeflow_tpu.parallel.moe import (ROUTER_STATE, HeldExpertsMlp,
+from kubeflow_tpu.parallel.moe import (ROUTER_STATE, ROW_CHUNK, HeldExpertsMlp,
                                        route_sigmoid, router_counters)
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -199,6 +199,8 @@ def test_the_bias_rule_after_one_step_through_the_trainer():
     assert float(metrics["moe_bias_abs_max"]) == pytest.approx(0.001)
     expert_layers = cfg.num_layers - cfg.num_dense_layers
     assert float(metrics["moe_rows_here"]) == tokens * cfg.top_k * expert_layers  # all held
+    # every pair in use: the per-row loops walk the whole bound, rounded up to a chunk
+    assert float(metrics["moe_rows_walked"]) == -(-tokens * cfg.top_k // ROW_CHUNK) * ROW_CHUNK * expert_layers
     assert float(metrics["moe_load_max_over_mean"]) >= 1.0
     state, metrics = trainer.train_step(state, (IDS, IDS))
     assert 0.001 <= float(metrics["moe_bias_abs_max"]) <= 0.002 + 1e-9
@@ -258,6 +260,20 @@ def test_the_step_names_put_the_gate_in_block_dense_and_flash_in_the_core():
     assert {"moe.route", "moe.dispatch", "moe.experts", "moe.combine", "moe.shared"} <= scopes
     assert {parts[n] for n in names if train_moe.scope_of(n)} == {"block_dense"}
     assert any("/moe.experts/jit(gmm)" in n for n in names)  # the grouped product keeps the program's names
+    # the grouped products, and every loop and branch of the layer, lie INSIDE one of the
+    # five scopes (the readers take the segment right after `/moe/`): forward, recomputed
+    # forward and backward
+    control = [n for n in names if train_moe.scope_of(n)
+               and re.search(r"/(jit\(t?gmm\)|while|cond)(/|$)", n)]
+    assert {train_moe.scope_of(n) for n in control} == {"moe.dispatch", "moe.experts", "moe.combine"}
+    products = [n for n in control if re.search(r"/jit\(t?gmm\)", n)]
+    assert products and {train_moe.scope_of(n) for n in products} == {"moe.experts"}
+    assert {"rematted_computation" in n for n in products} == {True, False}
+    assert any("/jit(tgmm)" in n and "transpose(" in n for n in products)
+    # what falls to no scope is what fell to none before the loops (the bias rule's
+    # arithmetic, the reshapes at the layer's edges, the sum with the shared expert)
+    other = {re.sub(r".*/layer_\d+/moe/", "", n) for n in names if train_moe.scope_of(n) == train_moe.OTHER}
+    assert other <= {"add", "convert_element_type", "div", "mul", "reduce_sum", "reshape", "sign", "sub"}
 
 
 def test_the_tiny_preset_trains_as_a_jaxjob_through_platform(tmp_path):
@@ -276,39 +292,60 @@ def test_the_tiny_preset_trains_as_a_jaxjob_through_platform(tmp_path):
         log = client.get_job_logs("afmoe-tiny")
     losses = [float(v) for v in re.findall(r"step=\d+ .*? loss=([0-9.]+)", log)]
     assert len(losses) >= 2 and losses[-1] < losses[0] and final["final_loss"] < losses[0]
-    assert "moe_rows_here=" in log and "moe_load_max_over_mean=" in log
+    assert "moe_rows_here=" in log and "moe_load_max_over_mean=" in log and "moe_rows_walked=" in log
 
 
-def test_one_expert_layer_on_the_chip_matches_the_float32_reference():
+@pytest.mark.parametrize("routing,held_bias,rows_range", [
+    ("balanced", 0.0, (7373, 9011)),        # k x 16/128 = 1 a token at the balanced load
+    ("crowded", 0.045, (11000, 16000)),     # a fifth of the pairs here, as the cell's runs are
+    ("every-pair", 10.0, (65536, 65536)),   # every token's eight choices on held experts
+])
+def test_one_expert_layer_on_the_chip_matches_the_float32_reference(routing, held_bias, rows_range):
     """Chip only (`pytest --noconftest` through the chip tool): one expert
     layer at published widths, 8,192 tokens, the bf16 program against the
     float32 reference given the program's own choice of experts (a bf16
     score can order two near-tied experts the other way; the arithmetic is
-    what is judged)."""
+    what is judged), output and the gradient of the input, at three fills of
+    the layer's rows: a selection bias on the held experts crowds them."""
     if jax.default_backend() != "tpu":
         pytest.skip("needs the chip: the grouped product as Mosaic compiles it is judged")
     h, m, e, k, held = 2048, 1024, 128, 8, (0, 16)
     layer = HeldExpertsMlp(hidden_size=h, expert_dim=m, num_experts=e, top_k=k,
                            experts_held=held, route_scale=2.826, dtype=jnp.bfloat16)
     x = jax.random.normal(jax.random.PRNGKey(28), (1, 8192, h), jnp.float32)
+    cot = jax.random.normal(jax.random.PRNGKey(30), (1, 8192, h), jnp.float32)
     variables = jax.jit(layer.init)(jax.random.PRNGKey(29), x)
     params = variables["params"]
-    out, new = jax.jit(lambda p, x: layer.apply(
-        {"params": p, ROUTER_STATE: variables[ROUTER_STATE]}, x, train=True,
-        mutable=[ROUTER_STATE]))(params, x.astype(jnp.bfloat16))
+    bias = jnp.zeros((e,)).at[held[0]:held[1]].set(held_bias)
+    state = dict(variables[ROUTER_STATE], bias=bias)
+
+    def program(p, x):
+        out, new = layer.apply({"params": p, ROUTER_STATE: state}, x, train=True, mutable=[ROUTER_STATE])
+        return (out.astype(jnp.float32) * cot).sum(), (out, new)
+
+    (_, (out, new)), dx = jax.jit(jax.value_and_grad(program, argnums=1, has_aux=True))(
+        params, x.astype(jnp.bfloat16))
     xt = x.astype(jnp.bfloat16).astype(jnp.float32).reshape(-1, h)
-    chosen, _, _ = jax.jit(lambda x: route_sigmoid(
-        x, params["router"], jnp.zeros((e,)), k, 2.826))(xt)
+    chosen, _, _ = jax.jit(lambda x: route_sigmoid(x, params["router"], bias, k, 2.826))(xt)
     spec = {"top_k": k, "route_scale": 2.826, "experts_held": held}
-    want = jax.jit(lambda p, x, c: reference_afmoe.expert_layer(x, p, spec, chosen=c))(
-        _reference_weights(params), xt, chosen)
+
+    def reference(x, p, c):
+        out = reference_afmoe.expert_layer(x, dict(p, bias=bias), spec, chosen=c)
+        return (out * cot.reshape(-1, h)).sum(), out
+
+    (_, want), want_dx = jax.jit(jax.value_and_grad(reference, has_aux=True))(
+        xt, _reference_weights(params), chosen)
     err = float(jnp.abs(out.astype(jnp.float32).reshape(-1, h) - want).max())
     scale = float(jnp.abs(want).max())
+    dx_err = float(jnp.abs(dx.astype(jnp.float32).reshape(-1, h) - want_dx).max())
+    dx_scale = float(jnp.abs(want_dx).max())
     rows = int(new[ROUTER_STATE]["rows_here"])
-    print(f"expert layer on the chip: max error {err:.3e} of outputs up to {scale:.3e}; rows here {rows}")
-    assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
-    assert abs(rows - 8192) < 819  # k x 16/128 = 1 a token at the balanced load
+    print(f"expert layer on the chip, {routing}: max error {err:.3e} of outputs up to {scale:.3e}; "
+          f"d_x {dx_err:.3e} of up to {dx_scale:.3e}; rows here {rows}")
+    assert bool(jnp.isfinite(out.astype(jnp.float32)).all()) and bool(jnp.isfinite(dx.astype(jnp.float32)).all())
+    assert rows_range[0] <= rows <= rows_range[1]
     assert err <= CHIP_LAYER_ERR_LIMIT * scale
+    assert dx_err <= CHIP_LAYER_DX_ERR_LIMIT * dx_scale
 
 
 #: as a share of the largest output. On the v5e the layer read a largest error
@@ -316,3 +353,8 @@ def test_one_expert_layer_on_the_chip_matches_the_float32_reference():
 #: chip run, PR 28, call 3): a bf16 step and a quarter of an output between 2
 #: and 4. The limit is two such steps at 4: 2**-5 of 4, 2**-7 of the scale.
 CHIP_LAYER_ERR_LIMIT = 2.0 ** -7
+#: the gradient of the input, as a share of its largest entry. On the v5e it
+#: read 3.563e-2 of up to 5.599 at 8,108 rows here, 3.746e-2 of 5.599 at 12,842
+#: and 5.742e-2 of 6.947 at all 65,536 (my chip run, PR 31, call 4): 6.4e-3 to
+#: 8.3e-3 of the scale, two products deep. The limit is twice the worst.
+CHIP_LAYER_DX_ERR_LIMIT = 2.0 ** -6
