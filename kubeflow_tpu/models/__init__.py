@@ -21,6 +21,12 @@ from kubeflow_tpu.models.gpt import (
     causal_lm_loss,
 )
 from kubeflow_tpu.models.mnist import MnistCNN, MnistMLP
+from kubeflow_tpu.models.sdar_moe import (
+    SdarMoeConfig,
+    SdarMoeLM,
+    sdar_eval_metrics,
+    sdar_loss,
+)
 from kubeflow_tpu.models.vit import ViTClassifier, ViTConfig
 from kubeflow_tpu.models.resnet import (
     ResNet,
@@ -50,6 +56,10 @@ __all__ = [
     "GPTPipelineLM",
     "ViTClassifier",
     "ViTConfig",
+    "SdarMoeConfig",
+    "SdarMoeLM",
+    "sdar_loss",
+    "sdar_eval_metrics",
     "ResNet",
     "ResNet18",
     "ResNet34",

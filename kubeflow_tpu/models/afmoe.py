@@ -215,11 +215,11 @@ class AfmoeLM(nn.Module):
         return logits.astype(jnp.float32)
 
     @staticmethod
-    def step_counters(extra) -> dict:
+    def step_counters(extra, y=None) -> dict:
         """What the Trainer adds to a step's metrics (train/trainer.py
         `_step_metrics`): the routers' counters, out of the collection the
         expert layers keep in `TrainState.extra`; nothing for a model whose
-        layers are all dense."""
+        layers are all dense. The labels `y` count nothing here."""
         return router_counters(extra[ROUTER_STATE]) if ROUTER_STATE in extra else {}
 
 

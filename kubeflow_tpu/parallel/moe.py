@@ -30,7 +30,7 @@ old behavior (global capacity pool, cross-shard cumsum) for comparison.
 from __future__ import annotations
 
 from functools import partial
-from typing import Any
+from typing import Any, Callable
 
 import flax.linen as nn
 import jax
@@ -554,19 +554,36 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def route_sigmoid(x, kernel, bias, top_k: int, scale: float):
-    """The sigmoid router: x (T, H), kernel (H, E), bias (E,) -> (idx (T, K)
-    int32, weights (T, K) f32, scores (T, E) f32). Scores are sigmoids of
-    the router's product in float32; the bias takes part in the choice of
-    the K experts and not in their weights, which are the chosen scores
-    normalised over the K and scaled."""
+def _route_top_k(score, x, kernel, bias, top_k: int, scale: float):
+    """x (T, H), kernel (H, E), bias (E,) -> (idx (T, K) int32, weights
+    (T, K) f32, scores (T, E) f32). Scores are `score` of the router's
+    product in float32; the bias takes part in the choice of the K experts
+    and not in their weights, which are the chosen scores normalised over
+    the K and scaled."""
     logits = jnp.dot(x.astype(jnp.float32), kernel.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    scores = score(logits)
     _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
     picked = jnp.take_along_axis(scores, idx, axis=-1)
     weights = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
     return idx, weights, scores
+
+
+def route_sigmoid(x, kernel, bias, top_k: int, scale: float):
+    """The sigmoid router: each expert's score is a sigmoid of its own logit
+    (`_route_top_k`)."""
+    return _route_top_k(jax.nn.sigmoid, x, kernel, bias, top_k, scale)
+
+
+def route_softmax(x, kernel, bias, top_k: int, scale: float):
+    """The softmax router: scores are the softmax over ALL the experts, then
+    the K largest, normalised over the K (`norm_topk_prob`; `_route_top_k`)."""
+    return _route_top_k(jax.nn.softmax, x, kernel, bias, top_k, scale)
+
+
+#: `HeldExpertsMlp.score_func` -> the router: (x, kernel, bias, top_k, scale)
+#: -> (idx, weights, scores)
+ROUTERS = {"sigmoid": route_sigmoid, "softmax": route_softmax}
 
 
 def router_counters(router_state) -> dict[str, jax.Array]:
@@ -592,18 +609,23 @@ def router_counters(router_state) -> dict[str, jax.Array]:
 
 
 class HeldExpertsMlp(nn.Module):
-    """Sigmoid-routed SwiGLU experts beside a shared expert; this share holds
-    the routed experts `experts_held` = [lo, hi) of the router's
-    `num_experts`. x (B, L, H) -> (B, L, H):
+    """Routed SwiGLU experts, beside `num_shared_experts` shared ones where
+    the model has any; this share holds the routed experts `experts_held` =
+    [lo, hi) of the router's `num_experts`. x (B, L, H) -> (B, L, H):
 
         shared(x) + sum over e in S(x), lo <= e < hi, of w_e(x) expert_e(x)
 
-    S and w come from `route_sigmoid` over all `num_experts`. Tokens x top_k
-    (token, choice) pairs are sorted by expert, the pairs of absent experts
-    last; the held experts' rows are a prefix of the sorted order and the
-    grouped products run over that prefix only. With `train=True` and a
-    mutable ROUTER_STATE the selection bias moves by `bias_update_rate`
-    against the step's load (no gradient, no optimizer state)."""
+    S and w come from the router `score_func` names (`ROUTERS`: sigmoids, or
+    a softmax over all `num_experts`, the K chosen normalised either way).
+    Tokens x top_k (token, choice) pairs are sorted by expert, the pairs of
+    absent experts last; the held experts' rows are a prefix of the sorted
+    order and the grouped products run over that prefix only. With
+    `train=True` and a mutable ROUTER_STATE the step's load is counted
+    (`router_counters`) and, where `bias_update_rate` is not 0, the selection
+    bias moves by it against the load (no gradient, no optimizer state).
+    Without shared experts there is no `moe.shared` scope and no `shared_*`
+    parameter; at rate 0 the bias stays the zeros it was made as.
+    `router_init` draws the router's (H, num_experts) weights."""
 
     hidden_size: int
     expert_dim: int
@@ -614,6 +636,8 @@ class HeldExpertsMlp(nn.Module):
     route_scale: float = 1.0
     bias_update_rate: float = 0.001
     dtype: Any = jnp.float32
+    score_func: str = "sigmoid"                   # a key of ROUTERS
+    router_init: Callable = nn.initializers.normal(stddev=0.02)
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
@@ -622,8 +646,7 @@ class HeldExpertsMlp(nn.Module):
         if not 0 <= lo < hi <= e:
             raise ValueError(f"experts_held {self.experts_held} is no range of {e} experts")
         held = hi - lo
-        router = self.param("router", nn.initializers.normal(stddev=0.02),
-                            (h, e), jnp.float32)
+        router = self.param("router", self.router_init, (h, e), jnp.float32)
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=0)
         w_gate = self.param("w_gate", init, (held, h, m))
         w_up = self.param("w_up", init, (held, h, m))
@@ -637,8 +660,8 @@ class HeldExpertsMlp(nn.Module):
         b, l, _ = x.shape
         xt = x.reshape(b * l, h)
         with jax.named_scope("moe.route"):
-            idx, weights, _ = route_sigmoid(xt, router, bias.value, k,
-                                            self.route_scale)
+            idx, weights, _ = ROUTERS[self.score_func](
+                xt, router, bias.value, k, self.route_scale)
             flat = idx.reshape(-1)                               # (T*K,)
             load = (flat[:, None] == jnp.arange(e)).sum(0, dtype=jnp.int32)
         with jax.named_scope("moe.dispatch"):
@@ -663,19 +686,24 @@ class HeldExpertsMlp(nn.Module):
             y = grouped_matmul(_swiglu(gate_up, n_rows), cast(w_down), group_sizes)
         with jax.named_scope("moe.combine"):
             out = _combine(y, weights, order, inverse, held_pair, n_rows)
-        with jax.named_scope("moe.shared"):
-            width = self.num_shared_experts * m
-            dense = lambda n, name: nn.Dense(  # noqa: E731
-                n, use_bias=False, dtype=self.dtype, name=name)
-            shared = dense(h, "shared_down")(
-                nn.silu(dense(width, "shared_gate")(xt)) * dense(width, "shared_up")(xt))
+        shared = None
+        if self.num_shared_experts:
+            with jax.named_scope("moe.shared"):
+                width = self.num_shared_experts * m
+                dense = lambda n, name: nn.Dense(  # noqa: E731
+                    n, use_bias=False, dtype=self.dtype, name=name)
+                shared = dense(h, "shared_down")(
+                    nn.silu(dense(width, "shared_gate")(xt)) * dense(width, "shared_up")(xt))
 
         if train and self.is_mutable_collection(ROUTER_STATE) and not self.is_initializing():
             load = load.astype(jnp.float32)
             counts.value = load
             rows_here.value = n_rows
-            bias.value = bias.value + self.bias_update_rate * jnp.sign(load.mean() - load)
-        return (out + shared.astype(jnp.float32)).astype(self.dtype).reshape(b, l, h)
+            if self.bias_update_rate:
+                bias.value = bias.value + self.bias_update_rate * jnp.sign(load.mean() - load)
+        if shared is not None:
+            out = out + shared.astype(jnp.float32)
+        return out.astype(self.dtype).reshape(b, l, h)
 
 
 HELD_EXPERTS_PARTITION_RULES: list[tuple[str, P]] = [

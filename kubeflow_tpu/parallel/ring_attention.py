@@ -51,6 +51,13 @@ from jax.sharding import PartitionSpec as P
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from kubeflow_tpu.parallel.attention_mask import (
+    causal_window,
+    kv_runs,
+    live_tile_pairs,
+    mask_of,
+    name_suffix,
+)
 from kubeflow_tpu.parallel.mesh import (
     AXIS_CONTEXT,
     AXIS_DATA,
@@ -638,24 +645,28 @@ def _largest_tile(n: int, target: int, granule: int) -> int:
                 if n % t == 0), default=granule)
 
 
-def flash_forward_tiling(lq: int, lk: int, d: int, dtype, causal: bool,
-                         window: int = 0, *, block_q: int = 128,
-                         block_k: int = 128, heads: int = 1,
+def flash_forward_tiling(lq: int, lk: int, d: int, dtype, mask=None, *,
+                         block_q: int = 128, block_k: int = 128,
+                         heads: int = 1,
                          vmem_budget: int = FLASH_FWD_VMEM_BUDGET
                          ) -> FlashTiling:
     """The forward's tile, from what the call can see. `block_q`/`block_k`
     are the caller's granules (they tile `lq`/`lk`; every tile is a multiple
     of them, so a tiny test shape stays legal); `heads` is the head count,
-    which a head group has to divide to share one bias row. `window` moves
-    no tile (timed at 4,096 positions, window 1,024): the loop's bounds skip
-    what it hides."""
-    gq, gk = min(block_q, lq), min(block_k, lk)
+    which a head group has to divide to share one bias row. `mask` is a
+    mask of `attention_mask.py` or None, whose `period` the tiles divide (a
+    block-diffusion tile lies in one half). A window moves no tile (timed
+    at 4,096 positions, window 1,024): the loop's bounds skip what it
+    hides."""
+    # what a tile has to divide: the length, or the mask's period of it
+    pq, pk = (lq, lk) if mask is None else (mask.period(lq), mask.period(lk))
+    gq, gk = min(block_q, pq), min(block_k, pk)
 
     def fits(tiling):
         return _flash_fwd_vmem_bytes(tiling, lk, d, dtype) <= vmem_budget
 
-    target_q, target_k = _FLASH_FWD_RESIDENT_TARGET[bool(causal)]
-    bq, bk = _largest_tile(lq, target_q, gq), _largest_tile(lk, target_k, gk)
+    target_q, target_k = _FLASH_FWD_RESIDENT_TARGET[mask is not None]
+    bq, bk = _largest_tile(pq, target_q, gq), _largest_tile(pk, target_k, gk)
     one_tile = (bq, bk) == (lq, lk)
     for g in _FLASH_FWD_HEAD_GROUPS if one_tile else (1,):
         if heads % g == 0 and fits(FlashTiling(True, bq, bk, g)):
@@ -663,49 +674,30 @@ def flash_forward_tiling(lq: int, lk: int, d: int, dtype, causal: bool,
     # one head's K and V do not fit beside the tiles: the KV axis goes back
     # on the grid, and the wider tile shrinks until a step fits
     target_q, target_k = _FLASH_FWD_KVGRID_TARGET
-    bq, bk = _largest_tile(lq, target_q, gq), _largest_tile(lk, target_k, gk)
+    bq, bk = _largest_tile(pq, target_q, gq), _largest_tile(pk, target_k, gk)
     while not fits(FlashTiling(False, bq, bk)) and (bq > gq or bk > gk):
         if bq > gq and (bq >= bk or bk == gk):
-            bq = _largest_tile(lq, bq - 1, gq)
+            bq = _largest_tile(pq, bq - 1, gq)
         else:
-            bk = _largest_tile(lk, bk - 1, gk)
+            bk = _largest_tile(pk, bk - 1, gk)
     return FlashTiling(False, bq, bk)
-
-
-def _kv_tile_bounds(row0, block_q: int, block_k: int, n_kv: int, window: int):
-    """Under `causal`, the KV tiles [lo, hi) that the query tile starting at
-    row `row0` can see, and within them the tiles [lo_full, hi_full) that
-    neither the diagonal nor the window's edge crosses: those need no mask."""
-    last = row0 + block_q - 1
-    hi = jnp.minimum(last // block_k + 1, n_kv)
-    hi_full = (row0 + 1) // block_k  # last column at or under the first row
-    if window:
-        lo = jnp.maximum(row0 - (window - 1), 0) // block_k
-        # first column inside the last row's window
-        lo_full = (jnp.maximum(last - window + 1, 0) + block_k - 1) // block_k
-    else:
-        lo = lo_full = 0
-    lo_full = jnp.clip(lo_full, lo, hi)
-    return lo, lo_full, jnp.clip(hi_full, lo_full, hi), hi
 
 
 def _flash_tile(q, k, v, bias_row, carry, *, scale: float, mask):
     """One online-softmax step of the forward: a (block_q, d) query tile
-    against a (block_k, d) KV slice. `mask` is None for a tile wholly under
-    the diagonal and inside the window, else (row0, col0, window)."""
+    against a (block_k, d) KV slice. `mask` is None for a tile no row of
+    which the mask hides anything of, else (row0, col0, hidden): the tile's
+    origin and the band's rule `hidden(rows, cols)`."""
     m, l, acc = carry
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # (bq, bk)
     s = s + bias_row.astype(jnp.float32)[None, :]
     if mask is not None:
-        row0, col0, window = mask
+        row0, col0, hidden = mask
         rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        masked = cols > rows
-        if window:
-            masked = masked | (rows - cols >= window)
-        s = s + jnp.where(masked, NEG_INF, 0.0)
+        s = s + jnp.where(hidden(rows, cols), NEG_INF, 0.0)
     m_new = jnp.maximum(m, s.max(-1, keepdims=True))
     corr = jnp.exp(m - m_new)
     p = jnp.exp(s - m_new)
@@ -724,42 +716,42 @@ def _flash_tile_init(block_q: int, d: int):
 
 
 def _flash_resident_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
-                           *, scale: float, causal: bool, window: int,
-                           block_k: int):
+                           *, scale: float, mask, block_k: int):
     """Forward for one (head group, query tile): the group's whole K and V
     are in VMEM (their block does not move with the query tile, so they are
     fetched once a group), the KV loop runs here over `block_k` slices with
-    the running maximum, sum and accumulator as its carry, and stops at the
-    diagonal; only the tiles the diagonal or the window's edge crosses build
-    a mask."""
+    the running maximum, sum and accumulator as its carry, over the runs of
+    KV tiles the mask's bands leave the query tile (`kv_runs`: under `Causal`
+    one run that stops at the diagonal); only the tiles a band's edge
+    crosses build a mask."""
     heads, block_q, d = q_ref.shape
     n_kv = k_ref.shape[1] // block_k
     row0 = pl.program_id(1) * block_q
     for g in range(heads):
         q = q_ref[g]
 
-        def tile(j, carry, masked, g=g, q=q):
+        def tile(j, carry, hidden, g=g, q=q):
             col0 = pl.multiple_of(j * block_k, block_k)
             return _flash_tile(
                 q, k_ref[g, pl.ds(col0, block_k), :],
                 v_ref[g, pl.ds(col0, block_k), :],
                 bias_ref[0, 0, 0, pl.ds(col0, block_k)], carry, scale=scale,
-                mask=(row0, col0, window) if masked else None)
+                mask=(row0, col0, hidden) if hidden else None)
 
-        def tiles(lo, hi, masked, carry, tile=tile):
+        def tiles(lo, hi, hidden, carry, tile=tile):
             return jax.lax.fori_loop(
-                lo, hi, functools.partial(tile, masked=masked), carry)
+                lo, hi, functools.partial(tile, hidden=hidden), carry)
 
         carry = _flash_tile_init(block_q, d)
-        if causal:
-            lo, lo_full, hi_full, hi = _kv_tile_bounds(
-                row0, block_q, block_k, n_kv, window)
-            if window:  # else lo == lo_full: no loop to set up
-                carry = tiles(lo, lo_full, True, carry)
-            carry = tiles(lo_full, hi_full, False, carry)
-            carry = tiles(hi_full, hi, True, carry)
+        if mask is not None:
+            for band, (lo, lo_full, hi_full, hi) in zip(
+                    mask.bands(jnp), kv_runs(mask, row0, block_q, block_k, n_kv, jnp)):
+                if band.start is not None:  # else lo == lo_full: no loop to set up
+                    carry = tiles(lo, lo_full, band.hidden, carry)
+                carry = tiles(lo_full, hi_full, None, carry)
+                carry = tiles(hi_full, hi, band.hidden, carry)
         else:
-            carry = tiles(0, n_kv, False, carry)
+            carry = tiles(0, n_kv, None, carry)
         m, l, acc = carry
         o_ref[g] = (acc / l).astype(o_ref.dtype)
         # logsumexp residual for the fused backward kernels
@@ -768,11 +760,11 @@ def _flash_resident_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
 
 def _flash_kvgrid_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                          m_scr, l_scr, acc_scr,
-                         *, scale: float, causal: bool, window: int):
+                         *, scale: float, mask):
     """Forward for one (head, query tile, KV tile): the KV axis is the
     grid's last, sequential, with the running maximum, sum and accumulator
-    in VMEM scratch across it. A step above the diagonal or outside the
-    window does nothing, and fetches nothing: the K/V index maps
+    in VMEM scratch across it. A step outside every run of the mask's
+    (`kv_runs`) does nothing, and fetches nothing: the K/V index maps
     (`_flash_forward_tiled`) hold it on a tile that is already there."""
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
     ik, n_kv = pl.program_id(2), pl.num_programs(2)
@@ -783,22 +775,23 @@ def _flash_kvgrid_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         m_scr[:], l_scr[:], acc_scr[:] = _flash_tile_init(
             block_q, q_ref.shape[2])
 
-    def step(masked):
+    def step(hidden):
         m_scr[:], l_scr[:], acc_scr[:] = _flash_tile(
             q_ref[0], k_ref[0], v_ref[0], bias_ref[0, 0, 0, :],
             (m_scr[:], l_scr[:], acc_scr[:]), scale=scale,
-            mask=(row0, ik * block_k, window) if masked else None)
+            mask=(row0, ik * block_k, hidden) if hidden else None)
 
-    if causal:
-        lo, lo_full, hi_full, hi = _kv_tile_bounds(
-            row0, block_q, block_k, n_kv, window)
-        full = jnp.logical_and(ik >= lo_full, ik < hi_full)
-        live = jnp.logical_and(ik >= lo, ik < hi)
-        pl.when(full)(functools.partial(step, False))
-        pl.when(jnp.logical_and(live, jnp.logical_not(full)))(
-            functools.partial(step, True))
+    if mask is not None:
+        # the bands' runs share no tile: at most one of these fires a step
+        for band, (lo, lo_full, hi_full, hi) in zip(
+                mask.bands(jnp), kv_runs(mask, row0, block_q, block_k, n_kv, jnp)):
+            full = jnp.logical_and(ik >= lo_full, ik < hi_full)
+            live = jnp.logical_and(ik >= lo, ik < hi)
+            pl.when(full)(functools.partial(step, None))
+            pl.when(jnp.logical_and(live, jnp.logical_not(full)))(
+                functools.partial(step, band.hidden))
     else:
-        step(False)
+        step(None)
 
     @pl.when(ik == n_kv - 1)
     def _():
@@ -806,14 +799,16 @@ def _flash_kvgrid_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         lse_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
 
 
-def _flash_forward_tiled(q, k, v, bias, tiling: FlashTiling, causal: bool,
-                         window: int = 0):
+def _flash_forward_tiled(q, k, v, bias, tiling: FlashTiling, mask=None):
     """The forward kernel at a given tiling -> (out (B,Lq,H,D), lse
-    (B*H,Lq,1) f32). The tiling has to divide the lengths, and its head
-    group the head count."""
+    (B*H,Lq,1) f32). The tiling has to divide the lengths (the mask's period
+    of them), and its head group the head count. `mask`: a mask of
+    `attention_mask.py`, or None."""
     b, lq, h, d = q.shape
     lk = k.shape[1]
     resident, block_q, block_k, group = tiling
+    if mask is not None and (mask.period(lq) % block_q or mask.period(lk) % block_k):
+        raise ValueError(f"tiles of {block_q} x {block_k} straddle the mask's periods")
     scale = 1.0 / (d**0.5)
     # fold heads into batch: (B*H, L, D)
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
@@ -825,14 +820,15 @@ def _flash_forward_tiled(q, k, v, bias, tiling: FlashTiling, causal: bool,
             jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, lq, 1), jnp.float32),
         ],
-        name=tiling.name,  # branch and tile, in every trace of the call
+        # branch, tile and the mask's tag, in every trace of the call
+        name=tiling.name + name_suffix(mask),
         interpret=jax.default_backend() == "cpu",
     )
     if resident:
         kv_spec = pl.BlockSpec((group, lk, d), lambda g, iq: (g, 0, 0))
         of, lse = pl.pallas_call(
             functools.partial(_flash_resident_kernel, scale=scale,
-                              causal=causal, window=window, block_k=block_k),
+                              mask=mask, block_k=block_k),
             grid=(b * h // group, n_q),
             in_specs=[
                 pl.BlockSpec((group, block_q, d), lambda g, iq: (g, iq, 0)),
@@ -852,17 +848,19 @@ def _flash_forward_tiled(q, k, v, bias, tiling: FlashTiling, causal: bool,
         def kv_tile(iq, ik):
             # a step with nothing to do names the nearest tile that has, so
             # the pipeline sees no new block and starts no copy
-            if not causal:
+            if mask is None:
                 return ik
-            lo, _, _, hi = _kv_tile_bounds(
-                iq * block_q, block_q, block_k, n_kv, window)
-            return jnp.clip(ik, lo, hi - 1)
+            runs = kv_runs(mask, iq * block_q, block_q, block_k, n_kv, jnp)
+            lo, _, _, hi = runs[-1]
+            tile = jnp.clip(ik, lo, jnp.maximum(hi - 1, lo))
+            for lo, _, _, hi in runs[-2::-1]:  # an earlier run, up to its end
+                tile = jnp.where(ik < hi, jnp.clip(ik, lo, hi - 1), tile)
+            return tile
 
         kv_spec = pl.BlockSpec(
             (1, block_k, d), lambda bh, iq, ik: (bh, kv_tile(iq, ik), 0))
         of, lse = pl.pallas_call(
-            functools.partial(_flash_kvgrid_kernel, scale=scale,
-                              causal=causal, window=window),
+            functools.partial(_flash_kvgrid_kernel, scale=scale, mask=mask),
             grid=(b * h, n_q, n_kv),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
@@ -890,20 +888,22 @@ def _flash_forward_tiled(q, k, v, bias, tiling: FlashTiling, causal: bool,
 
 
 def _flash_forward(q, k, v, bias, block_q: int, block_k: int,
-                   causal: bool = False, want_lse: bool = False,
-                   window: int = 0):
+                   mask=None, want_lse: bool = False):
     """`block_q`/`block_k` are the backward's tile and the fallback's; of
     the forward they decide only whether the lengths tile at all (the
     backward consumes `lse` at that tile) and the granule of its own tile,
-    which `flash_forward_tiling` chooses."""
+    which `flash_forward_tiling` chooses. `mask`: a mask of
+    `attention_mask.py`, or None."""
     lq, lk, h, d = q.shape[1], k.shape[1], q.shape[2], q.shape[3]
-    if lq % min(block_q, lq) or lk % min(block_k, lk):
+    pq, pk = (lq, lk) if mask is None else (mask.period(lq), mask.period(lk))
+    if pq % min(block_q, pq) or pk % min(block_k, pk):
+        causal, window = causal_window(mask)
         out = blockwise_attention(q, k, v, bias, causal=causal,
                                   window=window)
         return (out, None) if want_lse else out
-    tiling = flash_forward_tiling(lq, lk, d, q.dtype, causal, window,
+    tiling = flash_forward_tiling(lq, lk, d, q.dtype, mask,
                                   block_q=block_q, block_k=block_k, heads=h)
-    out, lse = _flash_forward_tiled(q, k, v, bias, tiling, causal, window)
+    out, lse = _flash_forward_tiled(q, k, v, bias, tiling, mask)
     return (out, lse) if want_lse else out
 
 
@@ -1087,8 +1087,8 @@ if FLASH_BWD_IMPL not in _FLASH_BWD_IMPLS:
 
 
 def _flash_backward_xla(qf, kf, vf, bias, gf, lse, dd, *, b, h, lq, lk, d,
-                        scale, block_q, block_k, causal, out_dtypes,
-                        bias_dtype, window: int = 0):
+                        scale, block_q, block_k, mask, out_dtypes,
+                        bias_dtype):
     """Flash backward as XLA einsums over the live (query block, KV block)
     pairs, from saved residuals.
 
@@ -1098,20 +1098,24 @@ def _flash_backward_xla(qf, kf, vf, bias, gf, lse, dd, *, b, h, lq, lk, d,
     forward already saved. The time follows the score elements it touches
     (float32 (BH, block_q, block_k) tiles of s, p, dp, ds), so it walks
     `flash_backward_live_pairs` only: a `scan` over the KV blocks, and
-    inside it a loop over the query blocks that KV block's keys are visible
-    to. A pair the causal or window mask hides whole (probability and
-    gradient exactly 0) is never computed; the masks still apply inside a
-    live pair. Takes the same prefolded residuals as the pallas variants
-    (one shared prep in _flash_backward).
+    inside it a loop over each run of query blocks that KV block's keys are
+    visible to (one run under `Causal`, two under `BlockDiffusion`: clean
+    and noisy queries). A pair the mask hides whole (probability and
+    gradient exactly 0) is never computed; the mask still applies inside a
+    live pair. `mask`: a mask of `attention_mask.py`, or None. Takes the
+    same prefolded residuals as the pallas variants (one shared prep in
+    _flash_backward).
     """
     dq_dtype, dk_dtype, dv_dtype = out_dtypes
     n_q, n_kv = lq // block_q, lk // block_k
-    pairs = flash_backward_live_pairs(lq, lk, block_q, block_k, causal,
-                                      window)
-    # a KV block's live query blocks are a range [first, first + count)
-    live = [[iq for iq, ik in pairs if ik == j] for j in range(n_kv)]
-    first = jnp.asarray([min(qs, default=0) for qs in live], jnp.int32)
-    count = jnp.asarray([len(qs) for qs in live], jnp.int32)
+    pairs = flash_backward_live_pairs(lq, lk, block_q, block_k, mask)
+    # a KV block's live query blocks are runs [first, first + count), as many
+    # runs a KV block as the fullest has (a run may be empty)
+    live = [_unbroken_runs([iq for iq, ik in pairs if ik == j]) for j in range(n_kv)]
+    n_runs = max(map(len, live))
+    live = [runs + [(0, 0)] * (n_runs - len(runs)) for runs in live]
+    first, count = (tuple(jnp.asarray([runs[r][part] for runs in live], jnp.int32)
+                          for r in range(n_runs)) for part in (0, 1))
     # bias row per folded batch*head: (B,1,1,Lk) -> (BH, Lk)
     bias_bh = jnp.repeat(
         bias.reshape(b, lk).astype(jnp.float32), h, axis=0)
@@ -1135,7 +1139,7 @@ def _flash_backward_xla(qf, kf, vf, bias, gf, lse, dd, *, b, h, lq, lk, d,
     qb, gb, lseb, ddb = by_block(qf), by_block(gf), by_block(lse), by_block(dd)
 
     def kv_step(dq_acc, xs):
-        j, first_j, count_j = xs
+        j, firsts_j, counts_j = xs
         kj, vj, bj = cut(kf, j), cut(vf, j), cut(bias_bh, j)
 
         def q_step(i, acc):
@@ -1144,12 +1148,9 @@ def _flash_backward_xla(qf, kf, vf, bias, gf, lse, dd, *, b, h, lq, lk, d,
             s = jnp.einsum("bqd,bkd->bqk", qi, kj,
                            preferred_element_type=jnp.float32) * scale
             s = s + bj[:, None, :]
-            if causal:
+            if mask is not None:
                 r, c = i * block_q + rows, j * block_k + cols
-                masked = c > r
-                if window:
-                    masked = masked | (r - c >= window)
-                s = s + jnp.where(masked, NEG_INF, 0.0)
+                s = s + jnp.where(mask.hidden(r, c), NEG_INF, 0.0)
             p = jnp.exp(s - block(lseb, i))                  # (BH, bq, bk)
             dp = jnp.einsum("bqd,bkd->bqk", gi, vj,
                             preferred_element_type=jnp.float32)
@@ -1171,13 +1172,17 @@ def _flash_backward_xla(qf, kf, vf, bias, gf, lse, dd, *, b, h, lq, lk, d,
 
         zeros = jnp.zeros((b * h, block_k, d), jnp.float32)
         acc = (dq_acc, zeros, zeros, jnp.zeros((b, block_k), jnp.float32))
-        dq_acc, dkj, dvj, dbj = q_step(0, acc) if one else jax.lax.fori_loop(
-            first_j, first_j + count_j, q_step, acc)
+        if one:
+            acc = q_step(0, acc)
+        else:
+            for first_j, count_j in zip(firsts_j, counts_j):
+                acc = jax.lax.fori_loop(first_j, first_j + count_j, q_step, acc)
+        dq_acc, dkj, dvj, dbj = acc
         return dq_acc, (dkj * scale, dvj, dbj)
 
     # the scope names what ran in any trace, as the forward's kernel name does
     with jax.named_scope(f"flash_bwd_xla_q{block_q}_k{block_k}"
-                         f"_live{len(pairs)}of{n_q * n_kv}"):
+                         f"_live{len(pairs)}of{n_q * n_kv}{name_suffix(mask)}"):
         dq_acc, (dks, dvs, dbs) = jax.lax.scan(
             kv_step, by_block(jnp.zeros((b * h, lq, d), jnp.float32)),
             (jnp.arange(n_kv), first, count))
@@ -1524,8 +1529,10 @@ def _dd_prekernel(gf, of, *, b, h, lq, d, block_q, n_q, interpret):
     )(gf, of)
 
 
-def _flash_backward(q, k, v, bias, o, lse, g, block_q, block_k, causal,
-                    impl: str | None = None, window: int = 0):
+def _flash_backward(q, k, v, bias, o, lse, g, block_q, block_k, mask,
+                    impl: str | None = None):
+    if (impl or FLASH_BWD_IMPL) != "xla":
+        causal, window = causal_window(mask)
     b, lq, h, d = q.shape
     lk = k.shape[1]
     scale = 1.0 / (d**0.5)
@@ -1546,12 +1553,11 @@ def _flash_backward(q, k, v, bias, o, lse, g, block_q, block_k, causal,
 
     if (impl or FLASH_BWD_IMPL) == "xla":
         xla_q, xla_k = flash_backward_xla_blocks(lq, lk, block_q, block_k,
-                                                 causal)
+                                                 mask)
         dqf, dkf, dvf, dbias = _flash_backward_xla(
             qf, kf, vf, bias, gf, lse, _dd(), b=b, h=h, lq=lq, lk=lk, d=d,
-            scale=scale, block_q=xla_q, block_k=xla_k, causal=causal,
+            scale=scale, block_q=xla_q, block_k=xla_k, mask=mask,
             out_dtypes=(q.dtype, k.dtype, v.dtype), bias_dtype=bias.dtype,
-            window=window,
         )
         unfold = lambda t, L: t.reshape(b, h, L, d).transpose(0, 2, 1, 3)  # noqa: E731
         return unfold(dqf, lq), unfold(dkf, lk), unfold(dvf, lk), dbias
@@ -1646,30 +1652,29 @@ def _flash_backward(q, k, v, bias, o, lse, g, block_q, block_k, causal,
     return unfold(dqf, lq), unfold(dkf, lk), unfold(dvf, lk), dbias
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, bias, block_q, block_k, causal, window):
-    return _flash_forward(q, k, v, bias, block_q, block_k, causal,
-                          window=window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash(q, k, v, bias, block_q, block_k, mask):
+    return _flash_forward(q, k, v, bias, block_q, block_k, mask)
 
 
-def _flash_fwd(q, k, v, bias, block_q, block_k, causal, window):
+def _flash_fwd(q, k, v, bias, block_q, block_k, mask):
     # one source of truth for the fused-vs-fallback decision: the forward
     # itself — lse is None exactly when it took the blockwise fallback
     out, lse = _flash_forward(
-        q, k, v, bias, block_q, block_k, causal, want_lse=True,
-        window=window,
+        q, k, v, bias, block_q, block_k, mask, want_lse=True,
     )
     return out, (q, k, v, bias, out if lse is not None else None, lse)
 
 
-def _flash_bwd(block_q, block_k, causal, window, residuals, g):
+def _flash_bwd(block_q, block_k, mask, residuals, g):
     q, k, v, bias, o, lse = residuals
     if lse is not None:
         # fused pallas backward: recompute probability tiles from the saved
         # logsumexp — no O(L²) residuals, no full forward replay
         return _flash_backward(q, k, v, bias, o, lse, g, block_q, block_k,
-                               causal, window=window)
+                               mask)
     # ragged shapes fell back to blockwise in the forward: mirror it here
+    causal, window = causal_window(mask)
     _, vjp = jax.vjp(
         lambda q, k, v, bias: blockwise_attention(
             q, k, v, bias, block_k, causal=causal, window=window
@@ -1684,24 +1689,32 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, bias, dropout_rng=None, dropout_rate=0.0,
                     block: int = 128, causal: bool = False,
-                    window: int = 0):
+                    window: int = 0, mask=None):
     """Pallas flash attention (single device / per-shard): a pallas
     forward and a backward from its saved (out, lse), by default XLA's
-    (FLASH_BWD_IMPL); attention dropout unsupported. window > 0 (requires
-    causal) is the Mistral sliding window — block pairs the causal or window
-    mask hides whole are skipped in forward and backward
-    (flash_backward_live_pairs), making the attention cost O(L·window)
-    instead of O(L²/2). `block` is the granule of the backward's blocks
-    (flash_backward_xla_blocks widens them from the shapes) and the blockwise
-    fallback's (lengths it does not tile take the fallback); the forward
-    kernel chooses its own tile from the shapes (flash_forward_tiling)."""
+    (FLASH_BWD_IMPL); attention dropout unsupported. `mask` is a rule over
+    (row, column) of `parallel/attention_mask.py`: `Causal(window)` (with a
+    window the Mistral sliding window, query i sees keys in (i - window, i]),
+    `BlockDiffusion(half, block)`, or None for every key; `causal` and
+    `window` spell the first two of these for the callers that always did,
+    and `bias` (B, 1, 1, Lk) is added over the keys whatever the mask. Tiles
+    the mask hides whole are skipped in forward and backward (`kv_runs`,
+    `flash_backward_live_pairs`), so the attention costs what is visible:
+    O(L·window) under a window, L² + L·B of 4 L² under block diffusion; no
+    (Lq, Lk) bias is ever built. `block` is the granule of the backward's
+    blocks (flash_backward_xla_blocks widens them from the shapes) and the
+    blockwise fallback's (lengths it does not tile take the fallback, which
+    knows the causal and window masks only); the forward kernel chooses its
+    own tile from the shapes (flash_forward_tiling)."""
     if dropout_rate:
         raise NotImplementedError("attention dropout unsupported in flash path")
-    if window and not causal:
-        raise ValueError("attention window requires causal=True")
+    if mask is None:
+        mask = mask_of(causal, window)
+    elif causal or window:
+        raise ValueError("give the mask, or causal and window, not both")
 
     def per_device(q, k, v, bias):
-        return _flash(q, k, v, bias, block, block, causal, window)
+        return _flash(q, k, v, bias, block, block, mask)
 
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1 or in_manual_region():
@@ -1736,37 +1749,47 @@ _FLASH_BWD_XLA_FULL_KV_BLOCKS = 16
 
 
 def flash_backward_xla_blocks(lq: int, lk: int, block_q: int, block_k: int,
-                              causal: bool) -> tuple[int, int]:
+                              mask) -> tuple[int, int]:
     """The XLA backward's (query block, KV block), from what the call can
     see: multiples of the caller's blocks (they tile the lengths) that
-    divide the lengths, never under the caller's. It sits at the end of the
-    file because the Mosaic payload of the forward kernel embeds its callers'
+    divide the lengths (a mask's period of them), never under the caller's.
+    It sits at the end of the file because the Mosaic payload of the forward kernel embeds its callers'
     line numbers (ROADMAP D17): a line added above `flash_attention` is
     another program for every model that calls it."""
     widest = _FLASH_BWD_XLA_WIDEST
-    if not causal:
+    if mask is None:
         kv = min(lk // _FLASH_BWD_XLA_FULL_KV_BLOCKS, widest)
         return lq, _largest_tile(lk, max(block_k, kv), block_k)
     want_q = min(lq // _FLASH_BWD_XLA_Q_BLOCKS, widest)
     want_k = min(lk // _FLASH_BWD_XLA_KV_BLOCKS, widest)
-    return (_largest_tile(lq, max(block_q, want_q), block_q),
-            _largest_tile(lk, max(block_k, want_k), block_k))
+    pq, pk = mask.period(lq), mask.period(lk)
+    block_q, block_k = min(block_q, pq), min(block_k, pk)
+    return (_largest_tile(pq, max(block_q, want_q), block_q),
+            _largest_tile(pk, max(block_k, want_k), block_k))
 
 
 def flash_backward_live_pairs(lq: int, lk: int, block_q: int, block_k: int,
-                              causal: bool, window: int = 0
-                              ) -> list[tuple[int, int]]:
+                              mask) -> list[tuple[int, int]]:
     """The (query block, KV block) pairs with at least one visible element
-    under the mask as `_flash_backward_xla` applies it: query row `i` sees
-    key column `c` iff `c <= i` and, with a window, `i - c < window` (both
+    under the mask as `_flash_backward_xla` applies it (a mask of
+    `attention_mask.py`, or None; under `Causal` query row `i` sees key
+    column `c` iff `c <= i` and, with a window, `i - c < window`, both
     counted from 0). KV block major, the query blocks of one KV block in a
-    row; not causal: every pair. A KV block that no query sees (`lq < lk`)
+    row; no mask: every pair. A KV block that no query sees (`lq < lk`)
     has no pair, and its `dk`, `dv`, `dbias` stay zero."""
-    def live(iq, ik):
-        # i - c over the tile spans [lo, hi], every whole number between
-        lo = iq * block_q - (ik * block_k + block_k - 1)
-        hi = iq * block_q + block_q - 1 - ik * block_k
-        return not causal or (hi >= 0 and (not window or lo < window))
+    n_q, n_kv = lq // block_q, lk // block_k
+    if mask is None:
+        return [(iq, ik) for ik in range(n_kv) for iq in range(n_q)]
+    live = live_tile_pairs(mask, lq, lk, block_q, block_k)
+    return [(iq, ik) for ik in range(n_kv) for iq in range(n_q) if live[iq, ik]]
 
-    return [(iq, ik) for ik in range(lk // block_k)
-            for iq in range(lq // block_q) if live(iq, ik)]
+
+def _unbroken_runs(blocks: list[int]) -> list[tuple[int, int]]:
+    """Ascending block indices as runs (first, count) of consecutive ones."""
+    runs: list[tuple[int, int]] = []
+    for i in blocks:
+        if runs and i == sum(runs[-1]):
+            runs[-1] = (runs[-1][0], runs[-1][1] + 1)
+        else:
+            runs.append((i, 1))
+    return runs

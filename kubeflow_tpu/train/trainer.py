@@ -561,7 +561,7 @@ class Trainer:
         with jax.named_scope("train.cast"):
             x = self._cast(x)
         n_acc = max(self.config.grad_accum_steps, 1)
-
+        x, y = _corrupted(self.model, step_rng, x, y)  # on this line for ROADMAP D17: see there
         if n_acc == 1:
             (loss, (logits, new_extra)), grads = jax.value_and_grad(
                 self._loss_of, has_aux=True
@@ -634,13 +634,13 @@ class Trainer:
         # the compile cache, as they were
         with jax.named_scope("train.grad_norm"):
             grad_norm = optax.global_norm(grads)
-        return new_state, _step_metrics(self.model, loss, acc, grad_norm, new_extra)
+        return new_state, _step_metrics(self.model, loss, acc, grad_norm, new_extra, y)
 
     def _eval_step(self, state: TrainState, batch) -> dict:
         x, y, w = batch  # w: validity mask for padded tail batches
-        logits, _ = self.apply_fn(
-            state.params, state.extra, self._cast(x), state.rng, False
-        )
+        # a model that corrupts its batch is evaluated under one fixed draw
+        x, y = _corrupted(self.model, state.rng, self._cast(x), y)
+        logits, _ = self.apply_fn(state.params, state.extra, x, state.rng, False)
         logits = logits.astype(jnp.float32)
         per_ex, acc = self.eval_metrics_fn(logits, y)
         return {
@@ -1344,17 +1344,35 @@ class Trainer:
         }
 
 
-def _step_metrics(model, loss, accuracy, grad_norm, extra) -> dict:
+def _step_metrics(model, loss, accuracy, grad_norm, extra, y) -> dict:
     """The step's metrics, and what the model counts for itself a step: a
-    model may define `step_counters(extra) -> dict` of scalars out of the
+    model may define `step_counters(extra, y) -> dict` of scalars out of the
     collections the Trainer carries in `TrainState.extra` (AfmoeLM: its
-    routers' counters). Whether it does is seen while tracing, so a model
-    without it lowers to the step it always had. (The function sits after
-    the class because a line added above would move the callers of the flash
-    kernel, whose line numbers its Mosaic payload carries into the compile
-    cache's key: ROADMAP D17.)"""
+    routers' counters) and out of the labels the loss saw (SdarMoeLM: the
+    share of positions its corruption masked). Whether it does is seen while
+    tracing, so a model without it lowers to the step it always had. (The
+    function sits after the class because a line added above would move the
+    callers of the flash kernel, whose line numbers its Mosaic payload
+    carries into the compile cache's key: ROADMAP D17.)"""
     metrics = {"loss": loss, "accuracy": accuracy, "grad_norm": grad_norm}
     counters = getattr(model, "step_counters", None)
     if counters is not None:
-        metrics.update(counters(extra))
+        metrics.update(counters(extra, y))
     return metrics
+
+
+def _corrupted(model, rng, x, y):
+    """The batch as the model's objective wants it. A model may publish, as
+    it publishes `PARTITION_RULES` and `step_counters`, a corruption
+    `corrupt(rng, x, y) -> (x', y')`: noise drawn from the step's rng
+    (`fold_in(state.rng, state.step)`: a resumed job draws the same noise at
+    the same step) before the forward pass, under the device scope
+    `train.corrupt`. `x'` goes to `apply_fn`; `y'`, any tree whose leaves lead
+    with the batch (labels, which positions were masked, their weights), to
+    `loss_fn`, `eval_metrics_fn` and `step_counters`. A model that publishes
+    none lowers to the step it always had."""
+    corrupt = getattr(model, "corrupt", None)
+    if corrupt is None:
+        return x, y
+    with jax.named_scope("train.corrupt"):
+        return corrupt(rng, x, y)

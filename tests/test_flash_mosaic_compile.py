@@ -16,6 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from kubeflow_tpu.parallel import ring_attention as ra
+from kubeflow_tpu.parallel.attention_mask import BlockDiffusion, Causal
 
 
 @pytest.fixture(scope="module")
@@ -31,31 +32,36 @@ def one_chip():
 
 
 CALLS = [
-    # (b, lq, h, d), dtype, causal, window -> the kernel the rule names
-    ((8, 1024, 16, 64), jnp.bfloat16, True, 0, "flash_fwd_resident_q256_k512"),  # gpt2m-train-1k
-    ((8, 512, 12, 64), jnp.bfloat16, False, 0, "flash_fwd_resident_q512_k512_g4"),  # BERT-base
-    ((8, 256, 12, 64), jnp.bfloat16, False, 0, "flash_fwd_resident_q256_k256_g4"),  # ViT-B/16
-    ((1, 4096, 32, 128), jnp.bfloat16, True, 1024, "flash_fwd_resident_q256_k512"),  # Mistral's window
-    ((1, 8192, 16, 64), jnp.bfloat16, True, 0, "flash_fwd_resident_q256_k512"),
-    ((1, 32768, 8, 128), jnp.bfloat16, True, 0, "flash_fwd_kvgrid_q512_k1024"),
-    ((1, 32768, 4, 128), jnp.bfloat16, True, 4096, "flash_fwd_kvgrid_q512_k1024"),
-    ((2, 1024, 16, 64), jnp.float32, True, 0, "flash_fwd_resident_q256_k512"),
+    # (b, lq, h, d), dtype, mask -> the kernel the rule names
+    ((8, 1024, 16, 64), jnp.bfloat16, Causal(), "flash_fwd_resident_q256_k512"),  # gpt2m-train-1k
+    ((8, 512, 12, 64), jnp.bfloat16, None, "flash_fwd_resident_q512_k512_g4"),  # BERT-base
+    ((8, 256, 12, 64), jnp.bfloat16, None, "flash_fwd_resident_q256_k256_g4"),  # ViT-B/16
+    ((1, 4096, 32, 128), jnp.bfloat16, Causal(1024), "flash_fwd_resident_q256_k512"),  # Mistral's window
+    ((1, 8192, 16, 64), jnp.bfloat16, Causal(), "flash_fwd_resident_q256_k512"),
+    ((1, 32768, 8, 128), jnp.bfloat16, Causal(), "flash_fwd_kvgrid_q512_k1024"),
+    ((1, 32768, 4, 128), jnp.bfloat16, Causal(4096), "flash_fwd_kvgrid_q512_k1024"),
+    ((2, 1024, 16, 64), jnp.float32, Causal(), "flash_fwd_resident_q256_k512"),
     # trinitym-train-8k: a sliding and a full layer
-    ((1, 8192, 32, 128), jnp.bfloat16, True, 2048, "flash_fwd_resident_q256_k512"),
-    ((1, 8192, 32, 128), jnp.bfloat16, True, 0, "flash_fwd_resident_q256_k512"),
+    ((1, 8192, 32, 128), jnp.bfloat16, Causal(2048), "flash_fwd_resident_q256_k512"),
+    ((1, 8192, 32, 128), jnp.bfloat16, Causal(), "flash_fwd_resident_q256_k512"),
+    # sdar30b-train-4k: 4,096 tokens, clean and noisy copies, blocks of 4;
+    # and K/V past the budget under the same mask
+    ((1, 8192, 32, 128), jnp.bfloat16, BlockDiffusion(4096, 4),
+     "flash_fwd_resident_q256_k512_blockdiff"),
+    ((1, 32768, 4, 128), jnp.bfloat16, BlockDiffusion(16384, 4),
+     "flash_fwd_kvgrid_q512_k1024_blockdiff"),
 ]
 
 
-@pytest.mark.parametrize("shape,dtype,causal,window,name", CALLS)
-def test_forward_kernel_compiles_for_the_v5e(one_chip, monkeypatch, shape, dtype, causal,
-                                             window, name):
+@pytest.mark.parametrize("shape,dtype,mask,name", CALLS)
+def test_forward_kernel_compiles_for_the_v5e(one_chip, monkeypatch, shape, dtype, mask, name):
     b, length, _, _ = shape
     qkv = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     bias = jax.ShapeDtypeStruct((b, 1, 1, length), jnp.float32, sharding=one_chip)
     # `_flash_forward_tiled` asks the backend whether to interpret; here the CPU answers
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = jax.jit(lambda q, k, v, bias: ra._flash_forward(
-        q, k, v, bias, 128, 128, causal, want_lse=True, window=window)
+        q, k, v, bias, 128, 128, mask, want_lse=True)
     ).lower(qkv, qkv, qkv, bias).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
@@ -83,14 +89,16 @@ def test_grouped_expert_product_compiles_for_the_v5e(one_chip, monkeypatch, widt
     assert text.count("tpu_custom_call") >= 3  # gmm forward, gmm for the rows' gradient, tgmm
 
 
-@pytest.mark.parametrize("shape,causal,window,name", [
-    ((8, 1024, 16, 64), True, 0, "flash_bwd_xla_q128_k256_live20of32"),  # gpt2m-train-1k
+@pytest.mark.parametrize("shape,mask,name", [
+    ((8, 1024, 16, 64), Causal(), "flash_bwd_xla_q128_k256_live20of32"),  # gpt2m-train-1k
     # trinitym-train-8k: a sliding and a full layer
-    ((1, 8192, 32, 128), True, 2048, "flash_bwd_xla_q512_k512_live70of256"),
-    ((1, 8192, 32, 128), True, 0, "flash_bwd_xla_q512_k512_live136of256"),
-    ((8, 512, 12, 64), False, 0, "flash_bwd_xla_q512_k128_live4of4"),  # BERT-base: nothing to skip
+    ((1, 8192, 32, 128), Causal(2048), "flash_bwd_xla_q512_k512_live70of256"),
+    ((1, 8192, 32, 128), Causal(), "flash_bwd_xla_q512_k512_live136of256"),
+    ((8, 512, 12, 64), None, "flash_bwd_xla_q512_k128_live4of4"),  # BERT-base: nothing to skip
+    # sdar30b-train-4k: 36 clean-clean + 36 noisy-clean + 8 noisy-noisy pairs
+    ((1, 8192, 32, 128), BlockDiffusion(4096, 4), "flash_bwd_xla_q512_k512_live80of256_blockdiff"),
 ])
-def test_xla_backward_compiles_for_the_v5e_under_its_scope(one_chip, shape, causal, window, name):
+def test_xla_backward_compiles_for_the_v5e_under_its_scope(one_chip, shape, mask, name):
     """The shipped backward (XLA's, not a kernel) at the rule's blocks: the scope a trace
     shows, and temporaries of a few score tiles, not of the square."""
     b, length, h, d = shape
@@ -98,10 +106,10 @@ def test_xla_backward_compiles_for_the_v5e_under_its_scope(one_chip, shape, caus
     bias = jax.ShapeDtypeStruct((b, 1, 1, length), jnp.float32, sharding=one_chip)
     lse = jax.ShapeDtypeStruct((b * h, length, 1), jnp.float32, sharding=one_chip)
     compiled = jax.jit(lambda q, k, v, bias, o, lse, g: ra._flash_backward(
-        q, k, v, bias, o, lse, g, 128, 128, causal, impl="xla", window=window)
+        q, k, v, bias, o, lse, g, 128, 128, mask, impl="xla")
     ).lower(qkv, qkv, qkv, bias, qkv, lse, qkv).compile()
     assert f"/{name}/" in compiled.as_text()
-    block_q, block_k = ra.flash_backward_xla_blocks(length, length, 128, 128, causal)
+    block_q, block_k = ra.flash_backward_xla_blocks(length, length, 128, 128, mask)
     tile = b * h * block_q * block_k * 4
     operands = 7 * b * h * length * d * 4  # q, k, v, dO, and dq, dk, dv in float32
     assert compiled.memory_analysis().temp_size_in_bytes <= operands + 6 * tile

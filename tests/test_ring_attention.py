@@ -11,6 +11,7 @@ import pytest
 
 from kubeflow_tpu.models.bert import dense_attention
 from kubeflow_tpu.parallel import MeshConfig, build_mesh
+from kubeflow_tpu.parallel.attention_mask import Causal, mask_of
 from kubeflow_tpu.parallel.ring_attention import (
     blockwise_attention,
     flash_attention,
@@ -260,10 +261,10 @@ class TestFlashFusedBackward:
         from kubeflow_tpu.parallel.ring_attention import _flash_fwd
 
         q, k, v, bias = self._qkvb()
-        _, res = _flash_fwd(q, k, v, bias, 8, 8, False, 0)
+        _, res = _flash_fwd(q, k, v, bias, 8, 8, None)
         assert res[5] is not None  # lse saved -> pallas bwd path
         # ragged shapes fall back to the recomputing path
-        _, res = _flash_fwd(q[:, :30], k, v, bias, 8, 8, False, 0)
+        _, res = _flash_fwd(q[:, :30], k, v, bias, 8, 8, None)
         assert res[5] is None
 
 
@@ -296,9 +297,10 @@ class TestFlashBackwardImpls:
         )
 
         q, k, v, bias, g = self._qkvb()
-        out, lse = _flash_forward(q, k, v, bias, 8, 8, causal, want_lse=True)
+        mask = mask_of(causal)
+        out, lse = _flash_forward(q, k, v, bias, 8, 8, mask, want_lse=True)
         grads = {
-            impl: _flash_backward(q, k, v, bias, out, lse, g, 8, 8, causal,
+            impl: _flash_backward(q, k, v, bias, out, lse, g, 8, 8, mask,
                                   impl=impl)
             for impl in ("scratch", "loop", "loop2", "ddpre", "xla")
         }
@@ -328,9 +330,9 @@ class TestFlashBackwardImpls:
         )
 
         q, k, v, bias, g = (x.astype(jnp.float32) for x in self._qkvb())
-        out, lse = _flash_forward(q, k, v, bias, 8, 8, False, want_lse=True)
+        out, lse = _flash_forward(q, k, v, bias, 8, 8, None, want_lse=True)
         with pytest.raises(ValueError, match="unknown flash backward"):
-            _flash_backward(q, k, v, bias, out, lse, g, 8, 8, False,
+            _flash_backward(q, k, v, bias, out, lse, g, 8, 8, None,
                             impl="Loop2")
         # the env override is validated at import
         proc = subprocess.run(
@@ -446,7 +448,7 @@ class TestSlidingWindowFlash:
     def test_the_xla_backwards_blocks_follow_the_shapes(self, lq, lk, block, causal, want):
         from kubeflow_tpu.parallel.ring_attention import flash_backward_xla_blocks
 
-        got = flash_backward_xla_blocks(lq, lk, block, block, causal)
+        got = flash_backward_xla_blocks(lq, lk, block, block, mask_of(causal))
         assert got == want
         assert lq % got[0] == 0 and lk % got[1] == 0
         assert got[0] % min(block, lq) == 0 and got[1] % block == 0
@@ -601,8 +603,8 @@ class TestFlashForwardTiling:
         lq, lk, causal, window, pad, bq, bk, group = self.GEOMETRIES[geometry]
         q, k, v, bias = _qkvb(lq, lk, pad)
         out, lse = _flash_forward_tiled(
-            q, k, v, bias, FlashTiling(resident, bq, bk, group), causal,
-            window)
+            q, k, v, bias, FlashTiling(resident, bq, bk, group),
+            mask_of(causal, window))
         want_out, want_lse = _attention_f32(q, k, v, bias, causal, window)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
                                    rtol=2e-5, atol=2e-5)
@@ -618,7 +620,7 @@ class TestFlashForwardTiling:
 
         q, k, v, bias = _qkvb(64, 64)  # a random bias row a batch row
         out, lse = _flash_forward_tiled(
-            q, k, v, bias, FlashTiling(True, 16, 8, group), True, 7)
+            q, k, v, bias, FlashTiling(True, 16, 8, group), Causal(7))
         want_out, want_lse = _attention_f32(q, k, v, bias, True, 7)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
                                    rtol=2e-5, atol=2e-5)
@@ -640,13 +642,14 @@ class TestFlashForwardTiling:
         budget = ({} if vmem_budget is None
                   else {"vmem_budget": vmem_budget})
         tiling = ra.flash_forward_tiling(
-            1024, 1024, 16, q.dtype, causal, window, heads=2, **budget)
+            1024, 1024, 16, q.dtype, mask_of(causal, window), heads=2,
+            **budget)
         assert tiling.resident is resident
         assert 1024 // tiling.block_q > 1 and 1024 // tiling.block_k > 1
         assert tiling.name.startswith(
             "flash_fwd_resident_q" if resident else "flash_fwd_kvgrid_q")
-        out, lse = ra._flash_forward_tiled(q, k, v, bias, tiling, causal,
-                                           window)
+        out, lse = ra._flash_forward_tiled(q, k, v, bias, tiling,
+                                           mask_of(causal, window))
         want_out, want_lse = _attention_f32(q, k, v, bias, causal, window)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
                                    rtol=2e-5, atol=2e-5)
@@ -654,8 +657,9 @@ class TestFlashForwardTiling:
                                    rtol=2e-5, atol=2e-5)
         if vmem_budget is None:
             # what `_flash_forward` itself runs at these lengths
-            got, got_lse = ra._flash_forward(q, k, v, bias, 128, 128, causal,
-                                             want_lse=True, window=window)
+            got, got_lse = ra._flash_forward(q, k, v, bias, 128, 128,
+                                             mask_of(causal, window),
+                                             want_lse=True)
             np.testing.assert_array_equal(np.asarray(got), np.asarray(out))
             np.testing.assert_array_equal(np.asarray(got_lse),
                                           np.asarray(lse))
@@ -664,7 +668,7 @@ class TestFlashForwardTiling:
         from kubeflow_tpu.parallel.ring_attention import _flash_forward
 
         q, k, v, bias = _qkvb(200, 200, b=1, h=2)
-        out, lse = _flash_forward(q, k, v, bias, 128, 128, True,
+        out, lse = _flash_forward(q, k, v, bias, 128, 128, Causal(),
                                   want_lse=True)
         assert lse is None
         want, _ = _attention_f32(q, k, v, bias, True)
@@ -682,7 +686,8 @@ class TestFlashForwardTiling:
         )
 
         q, k, v, bias = _qkvb(1024, 1024, b=1, h=2, d=16)
-        tiling = flash_forward_tiling(1024, 1024, 16, q.dtype, True, heads=2)
+        tiling = flash_forward_tiling(1024, 1024, 16, q.dtype, Causal(),
+                                      heads=2)
         assert (tiling.block_q, tiling.block_k) != (128, 128)
         g = jax.random.normal(jax.random.PRNGKey(9), q.shape, jnp.float32)
 
@@ -690,7 +695,7 @@ class TestFlashForwardTiling:
             return (attn(q, k, v, bias) * g).sum()
 
         got = jax.jit(jax.grad(
-            functools.partial(loss, lambda *a: _flash(*a, 128, 128, True, 0)),
+            functools.partial(loss, lambda *a: _flash(*a, 128, 128, Causal())),
             argnums=(0, 1, 2, 3)))(q, k, v, bias)
         want = jax.grad(functools.partial(loss, causal_dense_attention),
                         argnums=(0, 1, 2, 3))(q, k, v, bias)
@@ -743,7 +748,7 @@ class TestFlashForwardTiling:
             self, lq, lk, d, dtype, causal, window, block, heads, name):
         from kubeflow_tpu.parallel import ring_attention as ra
 
-        t = ra.flash_forward_tiling(lq, lk, d, dtype, causal, window,
+        t = ra.flash_forward_tiling(lq, lk, d, dtype, mask_of(causal, window),
                                     block_q=block, block_k=block, heads=heads)
         assert t.name == name
         assert lq % t.block_q == 0 and lk % t.block_k == 0
@@ -757,7 +762,7 @@ class TestFlashForwardTiling:
     def test_rule_shrinks_tiles_to_a_smaller_budget(self):
         from kubeflow_tpu.parallel import ring_attention as ra
 
-        args = (4096, 4096, 128, jnp.bfloat16, True)
+        args = (4096, 4096, 128, jnp.bfloat16, Causal())
         roomy = ra.flash_forward_tiling(*args)
         assert roomy.resident
         for budget in (3 * 2**20, 2**20, 300_000):
@@ -843,8 +848,8 @@ class TestFlashBackwardLivePairs:
             flash_backward_live_pairs,
         )
 
-        pairs = flash_backward_live_pairs(lq, lk, block_q, block_k, causal,
-                                          window)
+        pairs = flash_backward_live_pairs(lq, lk, block_q, block_k,
+                                          mask_of(causal, window))
         n_q, n_kv = lq // block_q, lk // block_k
         assert (len(pairs), n_q * n_kv) == (n_live, n_all)
         assert len(set(pairs)) == len(pairs)
@@ -914,16 +919,15 @@ class TestFlashBackwardLivePairs:
         from kubeflow_tpu.parallel import ring_attention as ra
 
         b, h, d = 1, 2, 16
-        pairs = ra.flash_backward_live_pairs(lq, lk, block_q, block_k, causal,
-                                             window)
+        mask = mask_of(causal, window)
+        pairs = ra.flash_backward_live_pairs(lq, lk, block_q, block_k, mask)
         rows = jnp.zeros((b * h, lq, d)), jnp.zeros((b * h, lq, 1))
         keys = jnp.zeros((b * h, lk, d))
         closed = jax.make_jaxpr(lambda q, k, v, bias, g, lse, dd: (
             ra._flash_backward_xla(
                 q, k, v, bias, g, lse, dd, b=b, h=h, lq=lq, lk=lk, d=d,
-                scale=0.25, block_q=block_q, block_k=block_k, causal=causal,
-                out_dtypes=(jnp.float32,) * 3, bias_dtype=jnp.float32,
-                window=window)))(
+                scale=0.25, block_q=block_q, block_k=block_k, mask=mask,
+                out_dtypes=(jnp.float32,) * 3, bias_dtype=jnp.float32)))(
             rows[0], keys, keys, jnp.zeros((b, 1, 1, lk)), rows[0], rows[1],
             rows[1])
         (scan,) = [e for e in closed.jaxpr.eqns if e.primitive.name == "scan"]
@@ -994,10 +998,10 @@ def test_flash_forward_on_the_chip_matches_float32_attention():
     q, k, v, bias = _qkvb(1024, 1024, b=8, h=16, d=64, seed=26,
                           dtype=jnp.bfloat16)
     bias = jnp.zeros_like(bias)
-    tiling = flash_forward_tiling(1024, 1024, 64, q.dtype, True, heads=16)
+    tiling = flash_forward_tiling(1024, 1024, 64, q.dtype, Causal(), heads=16)
     assert tiling.resident
     out, lse = jax.jit(lambda *a: _flash_forward(
-        *a, 128, 128, True, want_lse=True))(q, k, v, bias)
+        *a, 128, 128, Causal(), want_lse=True))(q, k, v, bias)
     want_out, want_lse = jax.jit(
         lambda *a: _attention_f32(*a, causal=True))(q, k, v, bias)
     out_err = float(jnp.abs(out.astype(jnp.float32) - want_out).max())
@@ -1047,9 +1051,9 @@ def test_flash_backward_on_the_chip_matches_float32_attention(shape, window):
     g = jax.random.normal(jax.random.PRNGKey(30), q.shape,
                           jnp.float32).astype(jnp.bfloat16)
     out, lse = jax.jit(lambda *a: _flash_forward(
-        *a, 128, 128, True, want_lse=True, window=window))(q, k, v, bias)
+        *a, 128, 128, Causal(window), want_lse=True))(q, k, v, bias)
     got = jax.jit(lambda *a: _flash_backward(
-        *a, 128, 128, True, window=window))(q, k, v, bias, out, lse, g)
+        *a, 128, 128, Causal(window)))(q, k, v, bias, out, lse, g)
     want = _grads_f32_by_heads(q, k, v, bias, g, True, window)
     for name, a, w in zip(("dq", "dk", "dv", "dbias"), got, want):
         a = a.astype(jnp.float32)

@@ -178,3 +178,62 @@ def test_the_part_classifier_reads_the_same_parts_with_and_without_the_scopes(to
     # the new scopes split class (iv); the parent's names cannot
     optimizer = {with_scopes[i].split("/")[1] for i in named if part_of(with_scopes[i]) == "optimizer"}
     assert {"train.grad_norm", "train.optimizer"} <= optimizer
+
+
+def test_a_model_that_publishes_no_corruption_lowers_to_the_step_it_had(toy, monkeypatch):
+    """`Trainer._train_step` asks the model for a `corrupt` (the seam a diffusion
+    objective draws its noise through): a model without one is traced as if the seam were
+    not there, instruction for instruction, so its compile cache still serves it."""
+    from kubeflow_tpu.train import trainer as trainer_module
+
+    trainer, x = toy
+    with_seam = lowered_step(trainer, x, x)
+    assert "train.corrupt" not in with_seam.as_text(debug_info=True)
+    monkeypatch.setattr(trainer_module, "_corrupted", lambda model, rng, x, y: (x, y))
+    without = lowered_step(trainer, x, x)
+    monkeypatch.undo()
+    assert with_seam.as_text() == without.as_text()
+
+
+@pytest.mark.parametrize("what", ["scope", "labels", "rng"])
+def test_a_models_corruption_runs_under_its_scope_with_the_steps_rng(toy, what):
+    """A GPT that drops a tenth of its input ids: the draw is outside the differentiated
+    function under `train.corrupt`, the loss and the accuracy see the `y'` it returns,
+    and the key is `fold_in(state.rng, state.step)`: another step, another draw."""
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.layer_metrics.train_parts import part_of
+    from kubeflow_tpu.models.gpt import GPTLM, causal_lm_eval_metrics, causal_lm_loss
+    from kubeflow_tpu.train import Trainer, TrainerConfig
+
+    class DroppingGPT(GPTLM):
+        @nn.nowrap
+        def corrupt(self, rng, x, y):
+            kept = jax.random.uniform(rng, x.shape) >= 0.1
+            return jnp.where(kept, x, 1), {"labels": y, "kept": kept}
+
+        @staticmethod
+        def step_counters(extra, y):
+            return {"kept_share": y["kept"].mean(dtype=jnp.float32)}
+
+    base, x = toy
+    trainer = Trainer(DroppingGPT(base.model.cfg), TrainerConfig(batch_size=8, learning_rate=1e-3, seed=1),
+                      loss_fn=lambda logits, y: causal_lm_loss(logits, y["labels"]),
+                      eval_metrics_fn=lambda logits, y: causal_lm_eval_metrics(logits, y["labels"]))
+    if what == "scope":
+        names = set(step_op_names(trainer, x).values())
+        corrupt = {n for n in names if "train.corrupt" in n}
+        assert corrupt and all(n.startswith("jit(_train_step)/train.corrupt/") for n in corrupt)
+        assert {part_of(n) for n in corrupt} == {"optimizer"}  # outside the differentiated function
+        return
+    state = trainer.init_state(x)
+    state, first = trainer.train_step(state, (x, x))
+    state, second = trainer.train_step(state, (x, x))
+    if what == "labels":
+        assert np.isfinite(float(first["loss"])) and 0.7 < float(first["kept_share"]) < 1.0
+    else:
+        assert float(first["kept_share"]) != float(second["kept_share"])
+        resumed = trainer.init_state(x).replace(step=jnp.ones((), jnp.int32))
+        assert float(trainer.train_step(resumed, (x, x))[1]["kept_share"]) == float(second["kept_share"])
