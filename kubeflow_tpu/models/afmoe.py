@@ -43,7 +43,8 @@ from kubeflow_tpu.parallel.mesh import AXIS_FSDP, AXIS_MODEL
 from kubeflow_tpu.parallel.moe import (HELD_EXPERTS_PARTITION_RULES,
                                        ROUTER_STATE, HeldExpertsMlp,
                                        router_counters)
-from kubeflow_tpu.parallel.ring_attention import flash_attention
+from kubeflow_tpu.parallel.ring_attention import (FLASH_REMAT_POLICY,
+                                                  flash_attention)
 from kubeflow_tpu.parallel.rope import apply_rope
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -86,6 +87,8 @@ class AfmoeConfig:
     scale_embedding: bool = True          # `mup_enabled`
     dtype: Any = jnp.float32
     attention: str = "dense"              # dense | flash
+    # recompute each block in the backward pass, but for the flash kernel's
+    # output and row statistic (ring_attention.FLASH_REMAT_POLICY keeps them)
     remat: bool = False
 
     def __post_init__(self):
@@ -206,7 +209,8 @@ class AfmoeLM(nn.Module):
         if c.scale_embedding:
             x = x * jnp.asarray(c.hidden_size ** 0.5, x.dtype)
         x = constrain(x, ACT_SPEC)
-        block_cls = nn.remat(AfmoeBlock, static_argnums=(2,)) if c.remat else AfmoeBlock
+        block_cls = nn.remat(AfmoeBlock, static_argnums=(2,),
+                             policy=FLASH_REMAT_POLICY) if c.remat else AfmoeBlock
         for i, kind in enumerate(c.layer_types):
             x = block_cls(c, kind, i < c.num_dense_layers, name=f"layer_{i}")(x, train)
         x = _norm(c, "ln_final")(x)

@@ -30,8 +30,8 @@ from kubeflow_tpu.models.bert import (
     constrain,
 )
 from kubeflow_tpu.parallel.mesh import AXIS_FSDP, AXIS_MODEL
-
 from kubeflow_tpu.parallel.moe import MOE_PARTITION_RULES, MoeMlp
+from kubeflow_tpu.parallel.ring_attention import FLASH_REMAT_POLICY
 
 PARTITION_RULES: list[tuple[str, P]] = [
     (r"(query|key|value)/kernel$", P(AXIS_FSDP, AXIS_MODEL)),
@@ -96,9 +96,9 @@ class GPTConfig:
     # norm epsilon (flax default 1e-6); HF checkpoints vary (Llama-2 uses
     # 1e-5) and the importer threads the checkpoint's value for parity
     norm_eps: float = 1e-6
-    # rematerialize each block on backward (jax.checkpoint): activation
-    # memory drops from O(layers x seq x hidden) to O(seq x hidden) at the
-    # cost of one extra forward — the standard long-context HBM lever
+    # recompute each block in the backward pass (jax.checkpoint), but for the
+    # flash kernel's output and row statistic, which FLASH_REMAT_POLICY keeps
+    # (ring_attention.py has what they cost): the long-context HBM lever
     remat: bool = False
     # MoE decoder (Mixtral shape): 0 = dense MLP; >0 replaces every block's
     # MLP with a MoeMlp of this many experts over the `expert` mesh axis
@@ -477,7 +477,7 @@ class GPTLM(nn.Module):
         # remat never wraps the decode path: generation is forward-only and
         # its cache writes must not re-execute
         block_cls = (
-            nn.remat(GPTBlock, static_argnums=(3, 4))
+            nn.remat(GPTBlock, static_argnums=(3, 4), policy=FLASH_REMAT_POLICY)
             if (c.remat and not decode) else GPTBlock
         )
         for i in range(c.num_layers):

@@ -59,7 +59,8 @@ from kubeflow_tpu.parallel.attention_mask import BlockDiffusion
 from kubeflow_tpu.parallel.moe import (HELD_EXPERTS_PARTITION_RULES,
                                        ROUTER_STATE, HeldExpertsMlp,
                                        router_counters)
-from kubeflow_tpu.parallel.ring_attention import NEG_INF, flash_attention
+from kubeflow_tpu.parallel.ring_attention import (FLASH_REMAT_POLICY, NEG_INF,
+                                                  flash_attention)
 from kubeflow_tpu.parallel.rope import apply_rope
 
 PARTITION_RULES: list[tuple[str, P]] = [
@@ -90,6 +91,8 @@ class SdarMoeConfig:
     mask_rate_min: float = 0.05           # a block's rate is uniform on [this, 1]
     dtype: Any = jnp.float32
     attention: str = "dense"              # dense | flash
+    # recompute each block in the backward pass, but for the flash kernel's
+    # output and row statistic (ring_attention.FLASH_REMAT_POLICY keeps them)
     remat: bool = False
 
     def __post_init__(self):
@@ -227,7 +230,8 @@ class SdarMoeLM(nn.Module):
         x = VocabEmbed(c.vocab_size, c.hidden_size, dtype=c.dtype,
                        embedding_init=token_rows, name="token_embed")(input_ids)
         x = constrain(x, ACT_SPEC)
-        block_cls = nn.remat(SdarMoeBlock, static_argnums=(2,)) if c.remat else SdarMoeBlock
+        block_cls = nn.remat(SdarMoeBlock, static_argnums=(2,),
+                             policy=FLASH_REMAT_POLICY) if c.remat else SdarMoeBlock
         for i in range(c.num_layers):
             x = block_cls(c, name=f"layer_{i}")(x, train)
         x = _norm(c, "ln_final")(x[:, x.shape[1] // 2:])

@@ -1663,7 +1663,7 @@ def _flash_fwd(q, k, v, bias, block_q, block_k, mask):
     out, lse = _flash_forward(
         q, k, v, bias, block_q, block_k, mask, want_lse=True,
     )
-    return out, (q, k, v, bias, out if lse is not None else None, lse)
+    return _flash_residuals(q, k, v, bias, out, lse)
 
 
 def _flash_bwd(block_q, block_k, mask, residuals, g):
@@ -1793,3 +1793,34 @@ def _unbroken_runs(blocks: list[int]) -> list[tuple[int, int]]:
         else:
             runs.append((i, 1))
     return runs
+
+
+from jax.ad_checkpoint import checkpoint_name  # noqa: E402
+
+#: The two arrays the fused forward hands its backward, by the names a remat
+#: policy can keep them under (`jax.ad_checkpoint.checkpoint_name`). The
+#: blockwise fallback names nothing, and without `remat` a name lowers to
+#: nothing.
+FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
+
+#: The remat policy of the decoder blocks that can run the flash kernel
+#: (`GPTLM`, `AfmoeLM`, `SdarMoeLM`): recompute the block in the backward pass
+#: but for the kernel's output and row statistic, so that pass does not launch
+#: the forward kernel again. A block whose attention is dense emits no name and
+#: is recomputed whole. What a job gives up between the passes, a layer a row of
+#: batch at 8,192 positions, 32 heads of 128: `out` 64 MiB in bf16 and `lse`
+#: 128 MiB (1 MiB of float32 values that the chip pads 128-fold, kept in the
+#: kernel's own layout: PERF.md, PR 33).
+FLASH_REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
+    *FLASH_RESIDUAL_NAMES)
+
+
+def _flash_residuals(q, k, v, bias, out, lse):
+    """`_flash_fwd`'s return: the output and the residuals of `_flash_bwd`,
+    `out` and `lse` under FLASH_RESIDUAL_NAMES on the fused path. The output is
+    the named array too: what consumes it downstream then needs no forward
+    kernel either. Down here for the reason `flash_backward_xla_blocks` is."""
+    if lse is None:
+        return out, (q, k, v, bias, None, None)
+    out, lse = map(checkpoint_name, (out, lse), FLASH_RESIDUAL_NAMES)
+    return out, (q, k, v, bias, out, lse)

@@ -249,9 +249,10 @@ def test_the_step_names_put_the_gate_in_block_dense_and_flash_in_the_core():
     gate = [n for n in names if "/attn_gate/" in n]
     assert gate and {parts[n] for n in gate} == {"block_dense"}
     flash = [n for n in names if "/flash_fwd_" in n]
-    assert {parts[n] for n in flash if "transpose(" not in n} == {"attn_core_fwd"}
-    # the recomputed forward runs in the backward pass and is timed with it
-    assert {parts[n] for n in flash if "transpose(" in n} == {"attn_core_bwd"}
+    assert {parts[n] for n in flash} == {"attn_core_fwd"}
+    # the block's remat policy keeps the kernel's output and statistic: the backward
+    # pass recomputes the block without it (the expert products below it does recompute)
+    assert not [n for n in flash if "transpose(" in n or "rematted_computation" in n]
     assert {parts[n] for n in names if re.search(r"/attention/(q_norm|k_norm)/", n)} \
         == {"attn_core_fwd", "attn_core_bwd"}
     assert {parts[n] for n in names if re.search(r"/attention/(query|key|value|attn_out)/", n)} \

@@ -1,14 +1,21 @@
 """Block rematerialization (jax.checkpoint) — the long-context HBM lever:
 numerics identical to the plain path, decode untouched, trains on a mesh."""
 
+import collections
+import importlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from kubeflow_tpu.models import BertConfig, BertForSequenceClassification
+from kubeflow_tpu.models.afmoe import AfmoeConfig, AfmoeLM
 from kubeflow_tpu.models.gpt import GPTConfig, GPTLM, generate
+from kubeflow_tpu.models.sdar_moe import SdarMoeConfig, SdarMoeLM
 from kubeflow_tpu.parallel import MeshConfig, build_mesh
+from kubeflow_tpu.parallel.ring_attention import FLASH_RESIDUAL_NAMES
 
 
 @pytest.fixture(scope="module")
@@ -94,3 +101,110 @@ def test_pipelined_models_already_remat(cpu_devices):
     v = a.init(jax.random.PRNGKey(0), ids)
     np.testing.assert_allclose(np.asarray(a.apply(v, ids)),
                                np.asarray(b.apply(v, ids)), atol=1e-6)
+
+
+# ---- the blocks' remat policy keeps the flash kernel's output and statistic ----
+
+#: the three decoders that can run the flash kernel under `remat`, at their
+#: `tiny` sizes (every layer has an attention): (model class, its config's
+#: preset, the preset's extra arguments)
+DECODERS = {
+    "gpt": (GPTLM, GPTConfig.tiny, dict(dropout_rate=0.0, max_len=64)),
+    "afmoe": (AfmoeLM, AfmoeConfig.tiny, {}),
+    "sdar_moe": (SdarMoeLM, SdarMoeConfig.tiny, {}),
+}
+FLASH_IDS = np.asarray(np.random.default_rng(7).integers(1, 500, size=(2, 48)), np.int32)
+
+
+def _loss_and_params(name, ids=FLASH_IDS, **cfg_kw):
+    """(loss over the parameters, the parameters) of one of DECODERS."""
+    cls, preset, kw = DECODERS[name]
+    model = cls(preset(**kw, **cfg_kw))
+    variables = model.init(jax.random.PRNGKey(0), ids)
+    params = variables.pop("params")
+
+    def loss(p):
+        out = model.apply({"params": p, **variables}, ids, mutable=list(variables))
+        return (out[0] ** 2).mean()
+    return loss, params
+
+
+def _pallas_calls(jaxpr, found=None) -> collections.Counter:
+    """The `pallas_call`s of a jaxpr and of every jaxpr inside it, by name."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[str(eqn.params["name"])] += 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _pallas_calls(inner, found)
+    return found
+
+
+def _flash_forwards(calls) -> int:
+    return sum(n for name, n in calls.items() if name.startswith("flash_fwd_"))
+
+
+def _without_the_policy(monkeypatch, name):
+    """The block's `nn.remat` with no policy, as it was before PR 33."""
+    module = importlib.import_module(DECODERS[name][0].__module__)
+    monkeypatch.setattr(module, "FLASH_REMAT_POLICY", None)
+
+
+@pytest.mark.parametrize("name", DECODERS)
+def test_a_rematted_flash_block_runs_the_forward_kernel_once(name, monkeypatch):
+    """Under `remat` the backward pass of a block keeps the flash kernel's
+    `out` and `lse` and recomputes the rest: one `flash_fwd_*` call an attention
+    layer in the gradient's jaxpr (two with no policy), every other kernel as
+    often as with no policy, and the same gradient to the last bit."""
+    _, preset, kw = DECODERS[name]
+    layers = preset(**kw).num_layers
+    loss, params = _loss_and_params(name, attention="flash", remat=True)
+    kept = _pallas_calls(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    grads = jax.jit(jax.grad(loss))(params)
+    plain_loss, _ = _loss_and_params(name, attention="flash", remat=False)
+    plain = jax.jit(jax.grad(plain_loss))(params)
+    with monkeypatch.context() as patch:
+        _without_the_policy(patch, name)
+        loss, _ = _loss_and_params(name, attention="flash", remat=True)
+        whole = _pallas_calls(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+        whole_grads = jax.jit(jax.grad(loss))(params)
+    assert (_flash_forwards(kept), _flash_forwards(whole)) == (layers, 2 * layers)
+    others = lambda calls: {k: n for k, n in calls.items()  # noqa: E731
+                            if not k.startswith("flash_fwd_")}
+    assert others(kept) == others(whole)  # the expert layer's products are still recomputed
+    for a, b, c in zip(*map(jax.tree.leaves, (grads, whole_grads, plain))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=1e-4)
+
+
+@pytest.mark.parametrize("name", DECODERS)
+def test_the_policy_changes_nothing_where_attention_is_dense(name, monkeypatch):
+    """A dense block emits no name, so it is recomputed whole, as before."""
+    loss, params = _loss_and_params(name, attention="dense", remat=True)
+    text = lambda f: re.sub(  # noqa: E731  (the `checkpoint` equation prints its policy)
+        r"policy=.*", "policy=", str(jax.make_jaxpr(jax.grad(f))(params)))
+    with_policy = text(loss)
+    assert "policy=" in with_policy
+    assert not any(n in with_policy for n in FLASH_RESIDUAL_NAMES)
+    _without_the_policy(monkeypatch, name)
+    loss, _ = _loss_and_params(name, attention="dense", remat=True)
+    assert text(loss) == with_policy
+
+
+def test_the_blockwise_fallback_names_nothing_and_still_differentiates():
+    """A length the blocks do not tile takes `blockwise_attention`: no kernel,
+    no name, the whole block recomputed, the plain path's gradient."""
+    ids = FLASH_IDS[:, :30]  # ragged against a block of 8
+    loss, params = _loss_and_params("gpt", ids, attention="flash", attention_block=8,
+                                    remat=True)
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
+    assert not _pallas_calls(jaxpr.jaxpr)
+    assert not any(n in str(jaxpr) for n in FLASH_RESIDUAL_NAMES)
+    plain_loss, _ = _loss_and_params("gpt", ids, attention="flash", attention_block=8,
+                                     remat=False)
+    for a, b in zip(*(jax.tree.leaves(jax.grad(f)(params)) for f in (loss, plain_loss))):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
