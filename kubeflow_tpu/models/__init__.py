@@ -13,6 +13,7 @@ from kubeflow_tpu.models.bert import (
 )
 from kubeflow_tpu.models.afmoe import AfmoeConfig, AfmoeLM
 from kubeflow_tpu.models.bert_pp import BertPipelineClassifier
+from kubeflow_tpu.models.deepseek_v2 import DeepseekV2Config, DeepseekV2LM
 from kubeflow_tpu.models.gpt_pp import GPTPipelineLM
 from kubeflow_tpu.models.gpt import (
     GPTConfig,
@@ -47,6 +48,8 @@ __all__ = [
     "BertForMaskedLM",
     "BertForSequenceClassification",
     "BertPipelineClassifier",
+    "DeepseekV2Config",
+    "DeepseekV2LM",
     "GPTConfig",
     "GPTLM",
     "causal_lm_loss",

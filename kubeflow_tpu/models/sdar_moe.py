@@ -33,7 +33,7 @@ and plainly drawn they do none of it: an embedding row is a hundredth of what
 a sublayer adds to it, a query averages every key it sees, every position of
 a layer ends as that same average, and the router sends them all to the same
 `top_k` experts. Three initialisers differ from the plain ones for that:
-`token_rows`, `QK_GAIN_INIT` and `share_centred_normal`.
+`token_rows`, `QK_GAIN_INIT` and `parallel/moe.py share_centred_normal`.
 
 Training and evaluation only: a serving step that yields a block of tokens
 is not here (ROADMAP, "Mechanisms the program lacks").
@@ -58,7 +58,7 @@ from kubeflow_tpu.models.gpt import (
 from kubeflow_tpu.parallel.attention_mask import BlockDiffusion
 from kubeflow_tpu.parallel.moe import (HELD_EXPERTS_PARTITION_RULES,
                                        ROUTER_STATE, HeldExpertsMlp,
-                                       router_counters)
+                                       router_counters, share_centred_normal)
 from kubeflow_tpu.parallel.ring_attention import (FLASH_REMAT_POLICY, NEG_INF,
                                                   flash_attention)
 from kubeflow_tpu.parallel.rope import apply_rope
@@ -137,22 +137,6 @@ def token_rows(key, shape, dtype=jnp.float32):
     one row a quarter of all positions shared would route them as one."""
     rows = jax.random.normal(key, shape, dtype)
     return rows.at[-1].multiply(MASK_ROW_SCALE)
-
-
-def share_centred_normal(share: int):
-    """The router's initialiser: normal draws at `HeldExpertsMlp`'s 0.02, and
-    in each run of `share` columns (the experts one chip holds) the columns
-    sum to zero. Whatever direction the
-    positions' representations have in common then favours no chip's experts over
-    another's, to first order: a share's load is the balanced one and steady from
-    seed to seed, as under a router trained to balance."""
-    draw = nn.initializers.normal(stddev=0.02)
-
-    def init(key, shape, dtype=jnp.float32):
-        w = draw(key, shape, dtype).reshape(shape[0], -1, share)
-        return (w - w.mean(-1, keepdims=True)).reshape(shape)
-
-    return init
 
 
 def _norm(c: SdarMoeConfig, name: str, gain: float = 1.0):

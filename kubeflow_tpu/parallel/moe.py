@@ -554,36 +554,71 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _route_top_k(score, x, kernel, bias, top_k: int, scale: float):
+def _route_top_k(score, x, kernel, bias, top_k: int, scale: float,
+                 renormalise: bool = True):
     """x (T, H), kernel (H, E), bias (E,) -> (idx (T, K) int32, weights
     (T, K) f32, scores (T, E) f32). Scores are `score` of the router's
     product in float32; the bias takes part in the choice of the K experts
-    and not in their weights, which are the chosen scores normalised over
-    the K and scaled."""
+    and not in their weights, which are the chosen scores scaled: normalised
+    over the K first (`renormalise`), or as the scores stand (a softmax's K
+    largest then sum to less than 1: `norm_topk_prob` false)."""
     logits = jnp.dot(x.astype(jnp.float32), kernel.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     scores = score(logits)
     _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
     picked = jnp.take_along_axis(scores, idx, axis=-1)
+    if not renormalise:
+        return idx, scale * picked, scores
     weights = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
     return idx, weights, scores
 
 
-def route_sigmoid(x, kernel, bias, top_k: int, scale: float):
+def route_sigmoid(x, kernel, bias, top_k: int, scale: float, renormalise: bool = True):
     """The sigmoid router: each expert's score is a sigmoid of its own logit
     (`_route_top_k`)."""
-    return _route_top_k(jax.nn.sigmoid, x, kernel, bias, top_k, scale)
+    return _route_top_k(jax.nn.sigmoid, x, kernel, bias, top_k, scale, renormalise)
 
 
-def route_softmax(x, kernel, bias, top_k: int, scale: float):
+def route_softmax(x, kernel, bias, top_k: int, scale: float, renormalise: bool = True):
     """The softmax router: scores are the softmax over ALL the experts, then
-    the K largest, normalised over the K (`norm_topk_prob`; `_route_top_k`)."""
-    return _route_top_k(jax.nn.softmax, x, kernel, bias, top_k, scale)
+    the K largest, normalised over the K (`norm_topk_prob`) or left as they
+    are (`_route_top_k`)."""
+    return _route_top_k(jax.nn.softmax, x, kernel, bias, top_k, scale, renormalise)
 
 
-#: `HeldExpertsMlp.score_func` -> the router: (x, kernel, bias, top_k, scale)
-#: -> (idx, weights, scores)
+#: `HeldExpertsMlp.score_func` -> the router: (x, kernel, bias, top_k, scale,
+#: renormalise) -> (idx, weights, scores)
 ROUTERS = {"sigmoid": route_sigmoid, "softmax": route_softmax}
+
+
+def sequence_balance_loss(scores, idx, num_experts: int):
+    """The sequence-wise balance loss of a softmax router before its
+    coefficient (DeepSeek-V2, arXiv:2405.04434, `seq_aux`): scores (B, L, E)
+    float32, idx (B, L, K) the chosen experts -> the mean over rows of
+    `sum_e f_e P_e`, where `f_e = count_e E / (K L)` is how often the row's
+    K L choices fell on expert e against the balanced share (no gradient) and
+    `P_e` the row's mean score of e. 1 at a uniform router, E / K where every
+    token takes the same K with certainty."""
+    b, length, k = idx.shape
+    count = (idx.reshape(b, length * k, 1) == jnp.arange(num_experts)).sum(1)
+    f = count.astype(jnp.float32) * (num_experts / (k * length))
+    return (f * scores.mean(1)).sum(-1).mean()
+
+
+def share_centred_normal(share: int):
+    """A router's initialiser (`HeldExpertsMlp.router_init`): normal draws at
+    the layer's own 0.02, and in each run of `share` columns (the experts one
+    chip holds) the columns sum to zero. Whatever direction the positions'
+    representations have in common then favours no chip's experts over
+    another's, to first order: a share's load is the balanced one and steady
+    from seed to seed, as under a router trained to balance."""
+    draw = nn.initializers.normal(stddev=0.02)
+
+    def init(key, shape, dtype=jnp.float32):
+        w = draw(key, shape, dtype).reshape(shape[0], -1, share)
+        return (w - w.mean(-1, keepdims=True)).reshape(shape)
+
+    return init
 
 
 def router_counters(router_state) -> dict[str, jax.Array]:
@@ -593,12 +628,13 @@ def router_counters(router_state) -> dict[str, jax.Array]:
     `moe_rows_walked` (rows the layers' per-row loops covered: each layer's
     rows in use rounded up to whole chunks of ROW_CHUNK),
     `moe_load_max_over_mean` (the fullest of all the router's experts over
-    the mean, worst layer), `moe_bias_abs_max`."""
+    the mean, worst layer), `moe_bias_abs_max`, and, where a layer sows a
+    balance loss, `moe_balance_loss` (its value, summed over the layers)."""
     from flax.traverse_util import flatten_dict
 
     flat = flatten_dict(router_state)
     of = lambda name: [v for path, v in flat.items() if path[-1] == name]  # noqa: E731
-    return {
+    counters = {
         "moe_rows_here": sum(r.astype(jnp.float32) for r in of("rows_here")),
         "moe_rows_walked": sum((_live_chunks(r) * ROW_CHUNK).astype(jnp.float32)
                                for r in of("rows_here")),
@@ -606,6 +642,9 @@ def router_counters(router_state) -> dict[str, jax.Array]:
             [c.max() / jnp.maximum(c.mean(), 1e-9) for c in of("counts")]).max(),
         "moe_bias_abs_max": jnp.stack([jnp.abs(b).max() for b in of("bias")]).max(),
     }
+    if of("balance_loss"):  # the layers that sow a balance loss keep its value
+        counters["moe_balance_loss"] = sum(of("balance_loss"))
+    return counters
 
 
 class HeldExpertsMlp(nn.Module):
@@ -616,7 +655,12 @@ class HeldExpertsMlp(nn.Module):
         shared(x) + sum over e in S(x), lo <= e < hi, of w_e(x) expert_e(x)
 
     S and w come from the router `score_func` names (`ROUTERS`: sigmoids, or
-    a softmax over all `num_experts`, the K chosen normalised either way).
+    a softmax over all `num_experts`), the K chosen scores normalised over the K
+    where `renormalise` (else the weights are the scores themselves). Where
+    `balance_loss` is not 0 the layer sows that coefficient times
+    `sequence_balance_loss` into the `losses` collection, which the Trainer adds
+    to the objective, and keeps its value in ROUTER_STATE (`balance_loss`) for the
+    step's counters; at 0 nothing is sown and no such variable exists.
     Tokens x top_k (token, choice) pairs are sorted by expert, the pairs of
     absent experts last; the held experts' rows are a prefix of the sorted
     order and the grouped products run over that prefix only. With
@@ -638,6 +682,8 @@ class HeldExpertsMlp(nn.Module):
     dtype: Any = jnp.float32
     score_func: str = "sigmoid"                   # a key of ROUTERS
     router_init: Callable = nn.initializers.normal(stddev=0.02)
+    renormalise: bool = True                      # the K chosen weights sum to route_scale
+    balance_loss: float = 0.0                     # coefficient of the sown loss
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
@@ -656,14 +702,21 @@ class HeldExpertsMlp(nn.Module):
         counts = self.variable(ROUTER_STATE, "counts", zeros_e)
         rows_here = self.variable(ROUTER_STATE, "rows_here",
                                   lambda: jnp.zeros((), jnp.int32))
+        balance = self.variable(ROUTER_STATE, "balance_loss", lambda: jnp.zeros(
+            (), jnp.float32)) if self.balance_loss else None
 
         b, l, _ = x.shape
         xt = x.reshape(b * l, h)
         with jax.named_scope("moe.route"):
-            idx, weights, _ = ROUTERS[self.score_func](
-                xt, router, bias.value, k, self.route_scale)
+            idx, weights, scores = ROUTERS[self.score_func](
+                xt, router, bias.value, k, self.route_scale, self.renormalise)
             flat = idx.reshape(-1)                               # (T*K,)
             load = (flat[:, None] == jnp.arange(e)).sum(0, dtype=jnp.int32)
+            if self.balance_loss:
+                aux = self.balance_loss * sequence_balance_loss(
+                    scores.reshape(b, l, e), idx.reshape(b, l, k), e)
+                self.sow("losses", "moe_balance", aux,
+                         reduce_fn=lambda a, b: a + b, init_fn=lambda: 0.0)
         with jax.named_scope("moe.dispatch"):
             # sort the (token, choice) pairs by local expert, the absent
             # experts' last: the held experts' rows are a prefix of the order
@@ -699,6 +752,8 @@ class HeldExpertsMlp(nn.Module):
             load = load.astype(jnp.float32)
             counts.value = load
             rows_here.value = n_rows
+            if balance is not None:
+                balance.value = jax.lax.stop_gradient(aux)
             if self.bias_update_rate:
                 bias.value = bias.value + self.bias_update_rate * jnp.sign(load.mean() - load)
         if shared is not None:

@@ -132,10 +132,10 @@ def _finalize(o_acc, m, l, dtype):
     return (o_acc / l.transpose(0, 2, 1, 3)).astype(dtype)
 
 
-def _init_carry(q):
+def _init_carry(q, dv: int | None = None):
     b, lq, h, d = q.shape
     return (
-        jnp.zeros((b, lq, h, d), jnp.float32),
+        jnp.zeros((b, lq, h, dv or d), jnp.float32),  # the output has v's head size
         jnp.full((b, h, lq, 1), NEG_INF, jnp.float32),
         jnp.zeros((b, h, lq, 1), jnp.float32),
     )
@@ -149,7 +149,7 @@ def _kv_blocks(k, v, bias, block):
     if n_blocks * block != lk:  # ragged tail: fall back to one block
         n_blocks, block = 1, lk
     kb = k.reshape(b, n_blocks, block, h, d).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(b, n_blocks, block, h, d).transpose(1, 0, 2, 3, 4)
+    vb = v.reshape(b, n_blocks, block, h, -1).transpose(1, 0, 2, 3, 4)
     bias_b = bias.reshape(b, 1, 1, n_blocks, block).transpose(3, 0, 1, 2, 4)
     k_pos = jnp.arange(lk).reshape(n_blocks, block)
     return kb, vb, bias_b, k_pos, block
@@ -169,10 +169,10 @@ def _block_scores(q, k_blk, bias_blk, scale, q_pos, kp, window):
     return s
 
 
-def _blockwise_fwd_impl(q, k, v, bias, block, causal, window):
+def _blockwise_fwd_impl(q, k, v, bias, block, causal, window, scale=None):
     """Online-softmax scan over KV blocks -> (out, lse (B,H,Lq,1) f32)."""
     kb, vb, bias_b, k_pos, _ = _kv_blocks(k, v, bias, block)
-    scale = 1.0 / (q.shape[-1] ** 0.5)
+    scale = 1.0 / (q.shape[-1] ** 0.5) if scale is None else scale
     q_pos = jnp.arange(q.shape[1]) if causal else None
 
     def step(carry, kv):
@@ -183,7 +183,7 @@ def _blockwise_fwd_impl(q, k, v, bias, block, causal, window):
         ), None
 
     (o_acc, m, l), _ = jax.lax.scan(
-        step, _init_carry(q), (kb, vb, bias_b, k_pos)
+        step, _init_carry(q, v.shape[-1]), (kb, vb, bias_b, k_pos)
     )
     return _finalize(o_acc, m, l, q.dtype), m + jnp.log(l)
 
@@ -212,18 +212,18 @@ def _block_grads(q, k_blk, v_blk, bias_blk, g, gf, dd, lse, scale,
     return dq_blk, dk_blk, dv_blk, dbias_rows
 
 
-def _blockwise_bwd_impl(q, k, v, bias, out, lse, g, block, causal, window):
+def _blockwise_bwd_impl(q, k, v, bias, out, lse, g, block, causal, window,
+                        scale=None):
     """FlashAttention-2-style backward: recompute p = exp(s − lse) block
     by block from the saved logsumexp; residual memory is O(L), not the
     O(L²/block · n_blocks) probability tiles reverse-AD of the forward
     scan would save. Also the gradient path ring/ulysses local attention
-    actually trains through — kept out of reverse-AD entirely because
-    the r5 hardware forensics (probe_flash_r5b, docs/perf.md §Round 5)
-    implicate the scan-autodiff max/exp chain for dq/dk/dbias NaNs on
-    Mosaic."""
+    actually trains through — kept out of reverse-AD entirely because the
+    r5 hardware forensics (probe_flash_r5b, docs/perf.md §Round 5) implicate
+    the scan-autodiff max/exp chain for dq/dk/dbias NaNs on Mosaic."""
     kb, vb, bias_b, k_pos, _ = _kv_blocks(k, v, bias, block)
     b, lk, h, d = k.shape
-    scale = 1.0 / (q.shape[-1] ** 0.5)
+    scale = 1.0 / (q.shape[-1] ** 0.5) if scale is None else scale
     q_pos = jnp.arange(q.shape[1]) if causal else None
     gf = g.astype(jnp.float32)
     # D_i = Σ_d dO∘O — the dv-free half of ds = p·(dp − D)
@@ -240,40 +240,40 @@ def _blockwise_bwd_impl(q, k, v, bias, out, lse, g, block, causal, window):
         step, jnp.zeros(q.shape, jnp.float32), (kb, vb, bias_b, k_pos)
     )
     dk = dks.transpose(1, 0, 2, 3, 4).reshape(b, lk, h, d)
-    dv = dvs.transpose(1, 0, 2, 3, 4).reshape(b, lk, h, d)
+    dv = dvs.transpose(1, 0, 2, 3, 4).reshape(b, lk, h, -1)
     dbias = dbs.transpose(1, 0, 2).reshape(b, lk)[:, None, None, :]
     return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
             dbias.astype(bias.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _blockwise_cvjp(block, causal, window, q, k, v, bias):
-    out, _ = _blockwise_fwd_impl(q, k, v, bias, block, causal, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _blockwise_cvjp(block, causal, window, scale, q, k, v, bias):
+    out, _ = _blockwise_fwd_impl(q, k, v, bias, block, causal, window, scale)
     return out
 
 
-def _blockwise_cvjp_fwd(block, causal, window, q, k, v, bias):
-    out, lse = _blockwise_fwd_impl(q, k, v, bias, block, causal, window)
+def _blockwise_cvjp_fwd(block, causal, window, scale, q, k, v, bias):
+    out, lse = _blockwise_fwd_impl(q, k, v, bias, block, causal, window, scale)
     return out, (q, k, v, bias, out, lse)
 
 
-def _blockwise_cvjp_bwd(block, causal, window, res, g):
+def _blockwise_cvjp_bwd(block, causal, window, scale, res, g):
     q, k, v, bias, out, lse = res
     return _blockwise_bwd_impl(q, k, v, bias, out, lse, g, block, causal,
-                               window)
+                               window, scale)
 
 
 _blockwise_cvjp.defvjp(_blockwise_cvjp_fwd, _blockwise_cvjp_bwd)
 
 
 def blockwise_attention(q, k, v, bias, block: int = 256, causal: bool = False,
-                        window: int = 0, vjp: str | None = None):
+                        window: int = 0, vjp: str | None = None, scale=None):
     """Memory-efficient attention: lax.scan over KV blocks, online softmax.
 
-    The numerics reference for both the pallas kernel and the ring path.
-    causal=True masks k_pos > q_pos (global positions; the ring path
-    reconstructs per-shard positions itself). window > 0 (requires causal)
-    is the Mistral sliding window: query i sees keys in (i - window, i].
+    The numerics reference for both the pallas kernel and the ring path. v
+    may have another head size than q and k (the output has v's); `scale`
+    multiplies the scores (None: 1/sqrt(q's head size)). causal=True masks
+    k_pos > q_pos (global positions); window > 0 (causal only): (i - window, i].
 
     vjp selects the gradient path (default: KFT_BLOCKWISE_VJP, validated
     at import time):
@@ -291,11 +291,11 @@ def blockwise_attention(q, k, v, bias, block: int = 256, causal: bool = False,
     if vjp is None:
         vjp = BLOCKWISE_VJP
     if vjp == "autodiff":
-        out, _ = _blockwise_fwd_impl(q, k, v, bias, block, causal, window)
+        out, _ = _blockwise_fwd_impl(q, k, v, bias, block, causal, window, scale)
         return out
     if vjp != "custom":
         raise ValueError(f"unknown blockwise vjp {vjp!r}")
-    return _blockwise_cvjp(block, causal, window, q, k, v, bias)
+    return _blockwise_cvjp(block, causal, window, scale, q, k, v, bias)
 
 
 # ------------------------------------------------------------------------ ring
@@ -619,22 +619,23 @@ class FlashTiling(NamedTuple):
         return f"flash_fwd_{branch}_q{self.block_q}_k{self.block_k}{group}"
 
 
-def _flash_fwd_vmem_bytes(tiling: FlashTiling, lk: int, d: int, dtype) -> int:
+def _flash_fwd_vmem_bytes(tiling: FlashTiling, lk: int, d: int, dtype,
+                          dv: int | None = None) -> int:
     """VMEM one grid step of the forward holds: the pipeline's two buffers of
-    every block, lanes padded to 128 and sublanes to a tile, plus the float32
-    score tile three times over (scores, probabilities, and the cast or mask
-    beside them) and the running maximum, sum and accumulator."""
+    every block, lanes padded to 128 (q's and k's `d`, v's and the output's `dv`,
+    None: `d`) and sublanes to a tile, plus the float32 score tile three times over
+    (scores, probabilities, the cast or mask) and the maximum, sum and accumulator."""
     item = jnp.dtype(dtype).itemsize
-    lanes = -(-d // 128) * 128
+    lanes, lanes_v = (-(-n // 128) * 128 for n in (d, dv or d))
     bq, bk, g = tiling.block_q, tiling.block_k, tiling.heads
     kv_rows = lk if tiling.resident else bk
     blocks = (
-        2 * g * bq * lanes * item            # q in, out
-        + 2 * g * kv_rows * lanes * item     # k, v
+        g * bq * (lanes + lanes_v) * item            # q in, out
+        + g * kv_rows * (lanes + lanes_v) * item     # k, v
         + g * bq * 128 * 4                   # lse: one lane of 128 used
         + 8 * kv_rows * 4                    # bias row: one sublane of 8 used
     )
-    work = 3 * bq * max(bk, 128) * 4 + bq * (lanes + 2 * 128) * 4
+    work = 3 * bq * max(bk, 128) * 4 + bq * (lanes_v + 2 * 128) * 4
     return 2 * blocks + work
 
 
@@ -647,23 +648,23 @@ def _largest_tile(n: int, target: int, granule: int) -> int:
 
 def flash_forward_tiling(lq: int, lk: int, d: int, dtype, mask=None, *,
                          block_q: int = 128, block_k: int = 128,
-                         heads: int = 1,
+                         heads: int = 1, dv: int | None = None,
                          vmem_budget: int = FLASH_FWD_VMEM_BUDGET
                          ) -> FlashTiling:
     """The forward's tile, from what the call can see. `block_q`/`block_k`
     are the caller's granules (they tile `lq`/`lk`; every tile is a multiple
-    of them, so a tiny test shape stays legal); `heads` is the head count,
-    which a head group has to divide to share one bias row. `mask` is a
-    mask of `attention_mask.py` or None, whose `period` the tiles divide (a
-    block-diffusion tile lies in one half). A window moves no tile (timed
-    at 4,096 positions, window 1,024): the loop's bounds skip what it
-    hides."""
+    of them, so a tiny test shape stays legal); `heads` is the head count, which
+    a head group has to divide to share a bias row; `d` is q's and k's head
+    size, `dv` v's (None: `d`). `mask` is a mask of `attention_mask.py` or None,
+    whose `period` the tiles divide (a block-diffusion tile lies in one half).
+    A window moves no tile (timed at 4,096 positions, window 1,024): the loop's
+    bounds skip what it hides."""
     # what a tile has to divide: the length, or the mask's period of it
     pq, pk = (lq, lk) if mask is None else (mask.period(lq), mask.period(lk))
     gq, gk = min(block_q, pq), min(block_k, pk)
 
     def fits(tiling):
-        return _flash_fwd_vmem_bytes(tiling, lk, d, dtype) <= vmem_budget
+        return _flash_fwd_vmem_bytes(tiling, lk, d, dtype, dv) <= vmem_budget
 
     target_q, target_k = _FLASH_FWD_RESIDENT_TARGET[mask is not None]
     bq, bk = _largest_tile(pq, target_q, gq), _largest_tile(pk, target_k, gk)
@@ -742,7 +743,7 @@ def _flash_resident_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
             return jax.lax.fori_loop(
                 lo, hi, functools.partial(tile, hidden=hidden), carry)
 
-        carry = _flash_tile_init(block_q, d)
+        carry = _flash_tile_init(block_q, v_ref.shape[2])
         if mask is not None:
             for band, (lo, lo_full, hi_full, hi) in zip(
                     mask.bands(jnp), kv_runs(mask, row0, block_q, block_k, n_kv, jnp)):
@@ -773,7 +774,7 @@ def _flash_kvgrid_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
     @pl.when(ik == 0)
     def _():
         m_scr[:], l_scr[:], acc_scr[:] = _flash_tile_init(
-            block_q, q_ref.shape[2])
+            block_q, v_ref.shape[2])
 
     def step(hidden):
         m_scr[:], l_scr[:], acc_scr[:] = _flash_tile(
@@ -799,45 +800,46 @@ def _flash_kvgrid_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         lse_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
 
 
-def _flash_forward_tiled(q, k, v, bias, tiling: FlashTiling, mask=None):
-    """The forward kernel at a given tiling -> (out (B,Lq,H,D), lse
-    (B*H,Lq,1) f32). The tiling has to divide the lengths (the mask's period
-    of them), and its head group the head count. `mask`: a mask of
-    `attention_mask.py`, or None."""
+def _flash_forward_tiled(q, k, v, bias, tiling: FlashTiling, mask=None, scale=None):
+    """The forward kernel at a given tiling -> (out (B,Lq,H,Dv), lse
+    (B*H,Lq,1) f32): q and k share a head size, v and the output another. The
+    tiling has to divide the lengths (the mask's period of them), its head group the
+    head count. `mask`: of `attention_mask.py`, or None; `scale`: None is 1/sqrt(D)."""
     b, lq, h, d = q.shape
-    lk = k.shape[1]
+    lk, dv = k.shape[1], v.shape[3]
     resident, block_q, block_k, group = tiling
     if mask is not None and (mask.period(lq) % block_q or mask.period(lk) % block_k):
         raise ValueError(f"tiles of {block_q} x {block_k} straddle the mask's periods")
-    scale = 1.0 / (d**0.5)
+    scale = 1.0 / (d**0.5) if scale is None else scale
     # fold heads into batch: (B*H, L, D)
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
+    vf = v.transpose(0, 2, 1, 3).reshape(b * h, lk, dv)
     n_q, n_kv = lq // block_q, lk // block_k
     common = dict(
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, lq, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, lq, 1), jnp.float32),
         ],
         # branch, tile and the mask's tag, in every trace of the call
-        name=tiling.name + name_suffix(mask),
+        name=tiling.name + name_suffix(mask) + head_sizes_suffix(d, dv),
         interpret=jax.default_backend() == "cpu",
     )
     if resident:
-        kv_spec = pl.BlockSpec((group, lk, d), lambda g, iq: (g, 0, 0))
+        kv_spec = lambda n: pl.BlockSpec(  # noqa: E731
+            (group, lk, n), lambda g, iq: (g, 0, 0))
         of, lse = pl.pallas_call(
             functools.partial(_flash_resident_kernel, scale=scale,
                               mask=mask, block_k=block_k),
             grid=(b * h // group, n_q),
             in_specs=[
                 pl.BlockSpec((group, block_q, d), lambda g, iq: (g, iq, 0)),
-                kv_spec, kv_spec,
+                kv_spec(d), kv_spec(dv),
                 pl.BlockSpec((1, 1, 1, lk),
                              lambda g, iq: (g * group // h, 0, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((group, block_q, d), lambda g, iq: (g, iq, 0)),
+                pl.BlockSpec((group, block_q, dv), lambda g, iq: (g, iq, 0)),
                 pl.BlockSpec((group, block_q, 1), lambda g, iq: (g, iq, 0)),
             ],
             compiler_params=pltpu.CompilerParams(
@@ -857,26 +859,26 @@ def _flash_forward_tiled(q, k, v, bias, tiling: FlashTiling, mask=None):
                 tile = jnp.where(ik < hi, jnp.clip(ik, lo, hi - 1), tile)
             return tile
 
-        kv_spec = pl.BlockSpec(
-            (1, block_k, d), lambda bh, iq, ik: (bh, kv_tile(iq, ik), 0))
+        kv_spec = lambda n: pl.BlockSpec(  # noqa: E731
+            (1, block_k, n), lambda bh, iq, ik: (bh, kv_tile(iq, ik), 0))
         of, lse = pl.pallas_call(
             functools.partial(_flash_kvgrid_kernel, scale=scale, mask=mask),
             grid=(b * h, n_q, n_kv),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
-                kv_spec, kv_spec,
+                kv_spec(d), kv_spec(dv),
                 pl.BlockSpec(
                     (1, 1, 1, block_k),
                     lambda bh, iq, ik: (bh // h, 0, 0, kv_tile(iq, ik))),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
+                pl.BlockSpec((1, block_q, dv), lambda bh, iq, ik: (bh, iq, 0)),
                 pl.BlockSpec((1, block_q, 1), lambda bh, iq, ik: (bh, iq, 0)),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
-                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, dv), jnp.float32),
             ],
             # the KV axis is a sequential accumulation (scratch carries
             # m/l/acc across ik); heads and query tiles are independent
@@ -884,11 +886,11 @@ def _flash_forward_tiled(q, k, v, bias, tiling: FlashTiling, mask=None):
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             **common,
         )(qf, kf, vf, bias)
-    return of.reshape(b, h, lq, d).transpose(0, 2, 1, 3), lse
+    return of.reshape(b, h, lq, dv).transpose(0, 2, 1, 3), lse
 
 
 def _flash_forward(q, k, v, bias, block_q: int, block_k: int,
-                   mask=None, want_lse: bool = False):
+                   mask=None, want_lse: bool = False, scale=None):
     """`block_q`/`block_k` are the backward's tile and the fallback's; of
     the forward they decide only whether the lengths tile at all (the
     backward consumes `lse` at that tile) and the granule of its own tile,
@@ -899,11 +901,11 @@ def _flash_forward(q, k, v, bias, block_q: int, block_k: int,
     if pq % min(block_q, pq) or pk % min(block_k, pk):
         causal, window = causal_window(mask)
         out = blockwise_attention(q, k, v, bias, causal=causal,
-                                  window=window)
+                                  window=window, scale=scale)
         return (out, None) if want_lse else out
-    tiling = flash_forward_tiling(lq, lk, d, q.dtype, mask,
+    tiling = flash_forward_tiling(lq, lk, d, q.dtype, mask, dv=v.shape[3],
                                   block_q=block_q, block_k=block_k, heads=h)
-    out, lse = _flash_forward_tiled(q, k, v, bias, tiling, mask)
+    out, lse = _flash_forward_tiled(q, k, v, bias, tiling, mask, scale)
     return (out, lse) if want_lse else out
 
 
@@ -1171,7 +1173,8 @@ def _flash_backward_xla(qf, kf, vf, bias, gf, lse, dd, *, b, h, lq, lk, d,
             return dq_acc, dkj, dvj, dbj
 
         zeros = jnp.zeros((b * h, block_k, d), jnp.float32)
-        acc = (dq_acc, zeros, zeros, jnp.zeros((b, block_k), jnp.float32))
+        acc = (dq_acc, zeros, _zeros_of_width(zeros, vf),
+               jnp.zeros((b, block_k), jnp.float32))
         if one:
             acc = q_step(0, acc)
         else:
@@ -1182,14 +1185,15 @@ def _flash_backward_xla(qf, kf, vf, bias, gf, lse, dd, *, b, h, lq, lk, d,
 
     # the scope names what ran in any trace, as the forward's kernel name does
     with jax.named_scope(f"flash_bwd_xla_q{block_q}_k{block_k}"
-                         f"_live{len(pairs)}of{n_q * n_kv}{name_suffix(mask)}"):
+                         f"_live{len(pairs)}of{n_q * n_kv}{name_suffix(mask)}"
+                         + head_sizes_suffix(d, vf.shape[2])):
         dq_acc, (dks, dvs, dbs) = jax.lax.scan(
             kv_step, by_block(jnp.zeros((b * h, lq, d), jnp.float32)),
             (jnp.arange(n_kv), first, count))
         dqf = (dq_acc.reshape(b * h, lq, d) * scale).astype(dq_dtype)
         # scan stacks (n_kv, BH, bk, d): move the block axis back into Lk
         dkf = jnp.moveaxis(dks, 0, 1).reshape(b * h, lk, d).astype(dk_dtype)
-        dvf = jnp.moveaxis(dvs, 0, 1).reshape(b * h, lk, d).astype(dv_dtype)
+        dvf = jnp.moveaxis(dvs, 0, 1).reshape(b * h, lk, -1).astype(dv_dtype)
         dbias = jnp.moveaxis(dbs, 0, 1).reshape(b, lk)[:, None, None, :]
     return dqf, dkf, dvf, dbias.astype(bias_dtype)
 
@@ -1530,15 +1534,15 @@ def _dd_prekernel(gf, of, *, b, h, lq, d, block_q, n_q, interpret):
 
 
 def _flash_backward(q, k, v, bias, o, lse, g, block_q, block_k, mask,
-                    impl: str | None = None):
+                    impl: str | None = None, scale=None):
     if (impl or FLASH_BWD_IMPL) != "xla":
-        causal, window = causal_window(mask)
+        causal, window = _pallas_backward_masks(mask, q, v, scale)
     b, lq, h, d = q.shape
     lk = k.shape[1]
-    scale = 1.0 / (d**0.5)
+    scale = 1.0 / (d**0.5) if scale is None else scale
     block_q = min(block_q, lq)
     block_k = min(block_k, lk)
-    fold = lambda t, L: t.transpose(0, 2, 1, 3).reshape(b * h, L, d)  # noqa: E731
+    fold = lambda t, L: t.transpose(0, 2, 1, 3).reshape(b * h, L, -1)  # noqa: E731
     qf, kf, vf = fold(q, lq), fold(k, lk), fold(v, lk)
     of, gf = fold(o, lq), fold(g, lq)
     n_q, n_kv = lq // block_q, lk // block_k
@@ -1559,7 +1563,7 @@ def _flash_backward(q, k, v, bias, o, lse, g, block_q, block_k, mask,
             scale=scale, block_q=xla_q, block_k=xla_k, mask=mask,
             out_dtypes=(q.dtype, k.dtype, v.dtype), bias_dtype=bias.dtype,
         )
-        unfold = lambda t, L: t.reshape(b, h, L, d).transpose(0, 2, 1, 3)  # noqa: E731
+        unfold = lambda t, L: t.reshape(b, h, L, -1).transpose(0, 2, 1, 3)  # noqa: E731
         return unfold(dqf, lq), unfold(dkf, lk), unfold(dvf, lk), dbias
 
     if (impl or FLASH_BWD_IMPL) == "loop2":
@@ -1652,32 +1656,32 @@ def _flash_backward(q, k, v, bias, o, lse, g, block_q, block_k, mask,
     return unfold(dqf, lq), unfold(dkf, lk), unfold(dvf, lk), dbias
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash(q, k, v, bias, block_q, block_k, mask):
-    return _flash_forward(q, k, v, bias, block_q, block_k, mask)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash(q, k, v, bias, block_q, block_k, mask, scale=None):
+    return _flash_forward(q, k, v, bias, block_q, block_k, mask, scale=scale)
 
 
-def _flash_fwd(q, k, v, bias, block_q, block_k, mask):
+def _flash_fwd(q, k, v, bias, block_q, block_k, mask, scale=None):
     # one source of truth for the fused-vs-fallback decision: the forward
     # itself — lse is None exactly when it took the blockwise fallback
     out, lse = _flash_forward(
-        q, k, v, bias, block_q, block_k, mask, want_lse=True,
+        q, k, v, bias, block_q, block_k, mask, want_lse=True, scale=scale,
     )
     return _flash_residuals(q, k, v, bias, out, lse)
 
 
-def _flash_bwd(block_q, block_k, mask, residuals, g):
+def _flash_bwd(block_q, block_k, mask, scale, residuals, g):
     q, k, v, bias, o, lse = residuals
     if lse is not None:
         # fused pallas backward: recompute probability tiles from the saved
         # logsumexp — no O(L²) residuals, no full forward replay
         return _flash_backward(q, k, v, bias, o, lse, g, block_q, block_k,
-                               mask)
+                               mask, scale=scale)
     # ragged shapes fell back to blockwise in the forward: mirror it here
     causal, window = causal_window(mask)
     _, vjp = jax.vjp(
         lambda q, k, v, bias: blockwise_attention(
-            q, k, v, bias, block_k, causal=causal, window=window
+            q, k, v, bias, block_k, causal=causal, window=window, scale=scale
         ),
         q, k, v, bias,
     )
@@ -1689,23 +1693,23 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, bias, dropout_rng=None, dropout_rate=0.0,
                     block: int = 128, causal: bool = False,
-                    window: int = 0, mask=None):
-    """Pallas flash attention (single device / per-shard): a pallas
-    forward and a backward from its saved (out, lse), by default XLA's
-    (FLASH_BWD_IMPL); attention dropout unsupported. `mask` is a rule over
-    (row, column) of `parallel/attention_mask.py`: `Causal(window)` (with a
-    window the Mistral sliding window, query i sees keys in (i - window, i]),
-    `BlockDiffusion(half, block)`, or None for every key; `causal` and
-    `window` spell the first two of these for the callers that always did,
-    and `bias` (B, 1, 1, Lk) is added over the keys whatever the mask. Tiles
-    the mask hides whole are skipped in forward and backward (`kv_runs`,
-    `flash_backward_live_pairs`), so the attention costs what is visible:
-    O(L·window) under a window, L² + L·B of 4 L² under block diffusion; no
-    (Lq, Lk) bias is ever built. `block` is the granule of the backward's
+                    window: int = 0, mask=None, scale: float | None = None):
+    """Pallas flash attention (single device / per-shard): a pallas forward
+    and a backward from its saved (out, lse), by default XLA's (FLASH_BWD_IMPL);
+    attention dropout unsupported. q and k (B, L, H, D) share a head size, v
+    (B, Lk, H, Dv) may have another, which is the output's (latent attention:
+    192 | 128; nothing is padded in HBM); `scale` multiplies the scores, None
+    is 1/sqrt(D). `mask` is a rule over (row, column) of `attention_mask.py`:
+    `Causal(window)` (query i sees keys in (i - window, i]), `BlockDiffusion(half,
+    block)`, or None for every key; `causal` and `window` spell the first for the
+    callers that always did; `bias` (B, 1, 1, Lk) is added over the keys whatever
+    the mask. Tiles the mask hides whole are skipped in forward and backward
+    (`kv_runs`, `flash_backward_live_pairs`): the attention costs what is visible,
+    and no (Lq, Lk) bias is ever built. `block` is the granule of the backward's
     blocks (flash_backward_xla_blocks widens them from the shapes) and the
     blockwise fallback's (lengths it does not tile take the fallback, which
     knows the causal and window masks only); the forward kernel chooses its
-    own tile from the shapes (flash_forward_tiling)."""
+    own tile from the shapes and the two head sizes (flash_forward_tiling)."""
     if dropout_rate:
         raise NotImplementedError("attention dropout unsupported in flash path")
     if mask is None:
@@ -1714,7 +1718,7 @@ def flash_attention(q, k, v, bias, dropout_rng=None, dropout_rate=0.0,
         raise ValueError("give the mask, or causal and window, not both")
 
     def per_device(q, k, v, bias):
-        return _flash(q, k, v, bias, block, block, mask)
+        return _flash(q, k, v, bias, block, block, mask, scale)
 
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1 or in_manual_region():
@@ -1824,3 +1828,29 @@ def _flash_residuals(q, k, v, bias, out, lse):
         return out, (q, k, v, bias, None, None)
     out, lse = map(checkpoint_name, (out, lse), FLASH_RESIDUAL_NAMES)
     return out, (q, k, v, bias, out, lse)
+
+
+def head_sizes_suffix(d: int, dv: int) -> str:
+    """What a kernel's name says of its head sizes: nothing where q, k and v
+    share one (every name stays what it was), `_d192v128` where v's differs."""
+    return "" if dv == d else f"_d{d}v{dv}"
+
+
+def _zeros_of_width(zeros, like):
+    """`zeros` itself where `like` has its last size (one head size: the XLA
+    backward's program as it was), else zeros at `like`'s last size: dv's
+    accumulator under keys wider than the values."""
+    if like.shape[-1] == zeros.shape[-1]:
+        return zeros
+    return jnp.zeros((*zeros.shape[:-1], like.shape[-1]), zeros.dtype)
+
+
+def _pallas_backward_masks(mask, q, v, scale):
+    """(causal, window) for the four pallas backwards, which know one head size,
+    the scale 1/sqrt(D) and the causal and window masks only (ROADMAP D3)."""
+    if v.shape[-1] != q.shape[-1] or scale is not None:
+        raise NotImplementedError(
+            "the pallas flash backwards take one head size for q, k and v and no "
+            f"given scale (got {q.shape[-1]} | {v.shape[-1]}, scale {scale}): the "
+            "XLA backward (KFT_FLASH_BWD_IMPL=xla, the default) takes both")
+    return causal_window(mask)

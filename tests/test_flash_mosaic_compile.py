@@ -50,19 +50,30 @@ CALLS = [
      "flash_fwd_resident_q256_k512_blockdiff"),
     ((1, 32768, 4, 128), jnp.bfloat16, BlockDiffusion(16384, 4),
      "flash_fwd_kvgrid_q512_k1024_blockdiff"),
+    # dsv2lite-train-8k: keys of 192 (256 lanes), values of 128, (b, lq, h, d, dv): K and V
+    # resident count 15.0 MiB, over the rule's budget, so the KV axis is on the grid
+    ((1, 8192, 16, 192, 128), jnp.bfloat16, Causal(), "flash_fwd_kvgrid_q512_k1024_d192v128"),
 ]
+#: the scale latent attention gives its cell: 192^-0.5 x YaRN's mscale squared
+MLA_SCALE = 0.1147214
+
+
+def _shapes(shape):
+    """(q's and k's shape, v's): a fifth number is v's head size."""
+    return (shape[:4], (*shape[:3], shape[4])) if len(shape) == 5 else (shape, shape)
 
 
 @pytest.mark.parametrize("shape,dtype,mask,name", CALLS)
 def test_forward_kernel_compiles_for_the_v5e(one_chip, monkeypatch, shape, dtype, mask, name):
-    b, length, _, _ = shape
-    qkv = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    (b, length, _, _), v_shape = _shapes(shape)
+    qk = jax.ShapeDtypeStruct(shape[:4], dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct(v_shape, dtype, sharding=one_chip)
     bias = jax.ShapeDtypeStruct((b, 1, 1, length), jnp.float32, sharding=one_chip)
     # `_flash_forward_tiled` asks the backend whether to interpret; here the CPU answers
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = jax.jit(lambda q, k, v, bias: ra._flash_forward(
-        q, k, v, bias, 128, 128, mask, want_lse=True)
-    ).lower(qkv, qkv, qkv, bias).compile()
+        q, k, v, bias, 128, 128, mask, want_lse=True, scale=MLA_SCALE if len(shape) == 5 else None)
+    ).lower(qk, qk, v, bias).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     # the branch and the tile, where a trace and the ledger's `device_ops` show them
@@ -97,17 +108,20 @@ def test_grouped_expert_product_compiles_for_the_v5e(one_chip, monkeypatch, widt
     ((8, 512, 12, 64), None, "flash_bwd_xla_q512_k128_live4of4"),  # BERT-base: nothing to skip
     # sdar30b-train-4k: 36 clean-clean + 36 noisy-clean + 8 noisy-noisy pairs
     ((1, 8192, 32, 128), BlockDiffusion(4096, 4), "flash_bwd_xla_q512_k512_live80of256_blockdiff"),
+    # dsv2lite-train-8k: dq and dk at 192, dv and the cotangent at 128
+    ((1, 8192, 16, 192, 128), Causal(), "flash_bwd_xla_q512_k512_live136of256_d192v128"),
 ])
 def test_xla_backward_compiles_for_the_v5e_under_its_scope(one_chip, shape, mask, name):
     """The shipped backward (XLA's, not a kernel) at the rule's blocks: the scope a trace
     shows, and temporaries of a few score tiles, not of the square."""
-    b, length, h, d = shape
-    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    (b, length, h, d), v_shape = _shapes(shape)
+    qk = jax.ShapeDtypeStruct(shape[:4], jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct(v_shape, jnp.bfloat16, sharding=one_chip)
     bias = jax.ShapeDtypeStruct((b, 1, 1, length), jnp.float32, sharding=one_chip)
     lse = jax.ShapeDtypeStruct((b * h, length, 1), jnp.float32, sharding=one_chip)
     compiled = jax.jit(lambda q, k, v, bias, o, lse, g: ra._flash_backward(
-        q, k, v, bias, o, lse, g, 128, 128, mask, impl="xla")
-    ).lower(qkv, qkv, qkv, bias, qkv, lse, qkv).compile()
+        q, k, v, bias, o, lse, g, 128, 128, mask, impl="xla", scale=MLA_SCALE if len(shape) == 5 else None)
+    ).lower(qk, qk, v, bias, v, lse, v).compile()
     assert f"/{name}/" in compiled.as_text()
     block_q, block_k = ra.flash_backward_xla_blocks(length, length, 128, 128, mask)
     tile = b * h * block_q * block_k * 4

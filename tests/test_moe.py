@@ -197,3 +197,80 @@ def test_moe_state_checkpoint_roundtrip(tmp_path, cpu_devices):
     wu = restored[1].params["encoder"]["layer_0"]["moe"]["w_up"]
     np.testing.assert_allclose(np.asarray(wu), want, atol=1e-6)
     assert wu.sharding.spec[0] == "expert"
+
+
+# ------------------------------------------ the router's raw weights and the balance loss
+
+@pytest.mark.parametrize("top_k,scale", [(2, 1.0), (4, 1.0), (6, 2.5)])
+def test_route_without_renormalisation_keeps_the_softmaxs_own_values(top_k, scale):
+    """`norm_topk_prob` false: the weights are the chosen probabilities times the scale,
+    and sum to less than the scale; renormalised they sum to it."""
+    from kubeflow_tpu.parallel.moe import route_softmax
+
+    x = jax.random.normal(jax.random.PRNGKey(4), (10, 32))
+    kernel = jax.random.normal(jax.random.PRNGKey(5), (32, 16))
+    probs = np.asarray(jax.nn.softmax(x @ kernel, -1))
+    idx, raw, scores = route_softmax(x, kernel, jnp.zeros((16,)), top_k, scale, renormalise=False)
+    np.testing.assert_allclose(scores, probs, rtol=1e-5)
+    np.testing.assert_array_equal(np.sort(idx, -1), np.sort(np.argsort(-probs, -1)[:, :top_k], -1))
+    np.testing.assert_allclose(raw, scale * np.take_along_axis(probs, np.asarray(idx), -1), rtol=1e-5)
+    assert float(raw.sum(-1).max()) < scale
+    same_idx, normed, _ = route_softmax(x, kernel, jnp.zeros((16,)), top_k, scale)
+    np.testing.assert_array_equal(same_idx, idx)
+    np.testing.assert_allclose(normed.sum(-1), scale, rtol=1e-5)
+    np.testing.assert_allclose(normed, raw / (raw.sum(-1, keepdims=True) / scale), rtol=1e-5)
+
+
+@pytest.mark.parametrize("rows,length,experts,top_k", [(1, 16, 8, 2), (3, 12, 16, 4), (2, 64, 64, 6)])
+def test_sequence_balance_loss_equals_a_numpy_loop(rows, length, experts, top_k):
+    from kubeflow_tpu.parallel.moe import sequence_balance_loss
+
+    scores = np.asarray(jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(rows), (rows, length, experts)), -1))
+    idx = np.argsort(-scores, -1)[..., :top_k]
+    want = 0.0
+    for r in range(rows):  # f_e = count_e E / (K L), P_e = mean_t p_te, sum_e f_e P_e, mean over rows
+        for e in range(experts):
+            count = sum(int(e in idx[r, t]) for t in range(length))
+            want += count * experts / (top_k * length) * scores[r, :, e].mean() / rows
+    got = sequence_balance_loss(jnp.asarray(scores), jnp.asarray(idx), experts)
+    assert float(got) == pytest.approx(want, rel=1e-5)
+    # a uniform router reads 1; every token on the same K with certainty reads E / K
+    uniform = jnp.full((rows, length, experts), 1.0 / experts)
+    assert float(sequence_balance_loss(uniform, jnp.asarray(idx), experts)) == pytest.approx(1.0, rel=1e-5)
+    certain = jnp.zeros((rows, length, experts)).at[..., :top_k].set(1.0 / top_k)
+    first = jnp.broadcast_to(jnp.arange(top_k), (rows, length, top_k))
+    assert float(sequence_balance_loss(certain, first, experts)) == pytest.approx(experts / top_k, rel=1e-5)
+    # the load carries no gradient: the loss is linear in the scores
+    grad = jax.grad(lambda s: sequence_balance_loss(s, jnp.asarray(idx), experts))(jnp.asarray(scores))
+    assert float(jnp.abs(grad).max()) > 0 and float((grad * scores).sum()) == pytest.approx(want, rel=1e-4)
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.01])
+def test_the_layer_sows_its_balance_loss_only_at_a_rate(rate):
+    """Rate 0 (the accepted models): nothing is sown and ROUTER_STATE has no `balance_loss`,
+    their programs and state trees as they were; at a rate the layer sows rate x the loss
+    and keeps the value for the step's counters."""
+    from kubeflow_tpu.parallel.moe import (ROUTER_STATE, HeldExpertsMlp, route_softmax,
+                                           router_counters, sequence_balance_loss)
+
+    layer = HeldExpertsMlp(hidden_size=32, expert_dim=16, num_experts=8, top_k=2, experts_held=(2, 6),
+                           score_func="softmax", num_shared_experts=2, bias_update_rate=0.0,
+                           renormalise=False, balance_loss=rate)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 32), jnp.float32)
+    variables = layer.init(jax.random.PRNGKey(2), x)
+    state = {"params": variables["params"], ROUTER_STATE: variables[ROUTER_STATE]}
+    _, updates = layer.apply(state, x, True, mutable=[ROUTER_STATE, "losses"])
+    counters = router_counters({"layer_1": {"moe": updates[ROUTER_STATE]}})
+    if not rate:
+        assert "losses" not in updates and "losses" not in variables
+        assert set(updates[ROUTER_STATE]) == {"bias", "counts", "rows_here"}
+        assert "moe_balance_loss" not in counters
+        return
+    idx, _, scores = route_softmax(x.reshape(-1, 32), variables["params"]["router"], jnp.zeros((8,)), 2, 1.0, False)
+    want = rate * float(sequence_balance_loss(scores.reshape(2, 24, 8), idx.reshape(2, 24, 2), 8))
+    assert float(updates["losses"]["moe_balance"]) == pytest.approx(want, rel=1e-5) and want > 0.5 * rate
+    assert float(updates[ROUTER_STATE]["balance_loss"]) == pytest.approx(want, rel=1e-5)
+    assert float(counters["moe_balance_loss"]) == pytest.approx(want, rel=1e-5)
+    # evaluation sows it too where the caller collects it, and moves no state
+    _, sown = layer.apply(state, x, False, mutable=["losses"])
+    assert float(sown["losses"]["moe_balance"]) == pytest.approx(want, rel=1e-5)

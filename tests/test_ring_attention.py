@@ -1229,3 +1229,113 @@ class TestRingCustomVJP:
         for name, a, b in zip(("dq", "dk", "dv"), gr, gd):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=2e-4, err_msg=name)
+
+
+# --------------------------------------- two head sizes and a given scale (latent attention)
+
+from kubeflow_tpu.parallel import ring_attention as ra  # noqa: E402
+
+def _dense_two_sizes(q, k, v, bias, mask, scale):
+    """float32 attention with keys wider than values: (out, lse (B, H, L))."""
+    s = jnp.einsum("blhd,bmhd->bhlm", q, k, precision="highest") * scale + bias
+    if mask is not None:
+        at = jnp.arange(q.shape[1])
+        s = jnp.where(mask.hidden(at[:, None], at[None, :]), -jnp.inf, s)
+    return (jnp.einsum("bhlm,bmhd->blhd", jax.nn.softmax(s, -1), v, precision="highest"),
+            jax.nn.logsumexp(s, -1))
+
+
+def _two_sizes(length=64, d=24, dv=16, seed=7):
+    rng = np.random.RandomState(seed)
+    q, k = (jnp.asarray(rng.normal(0, 1, (2, length, 4, d)).astype(np.float32)) for _ in range(2))
+    v = jnp.asarray(rng.normal(0, 1, (2, length, 4, dv)).astype(np.float32))
+    bias = jnp.asarray(rng.normal(0, 0.3, (2, 1, 1, length)).astype(np.float32))
+    return q, k, v, bias
+
+
+@pytest.mark.parametrize("mask", [Causal(), Causal(24), None], ids=["causal", "window", "none"])
+@pytest.mark.parametrize("what", ["out", "lse", "dq", "dk", "dv"])
+def test_flash_at_unequal_head_sizes_and_a_given_scale_matches_dense(mask, what):
+    """q and k 24 wide, v and the output 16, scale 0.3: the pallas forward (interpreted) and
+    the XLA backward against float32 attention."""
+    q, k, v, bias = _two_sizes()
+    scale = 0.3
+    if what in ("out", "lse"):
+        out, lse = ra._flash_forward(q, k, v, bias, 16, 16, mask, want_lse=True, scale=scale)
+        want_out, want_lse = _dense_two_sizes(q, k, v, bias, mask, scale)
+        assert out.shape == (2, 64, 4, 16) and lse.shape == (8, 64, 1)
+        got, want = (out, want_out) if what == "out" else (lse.reshape(2, 4, 64), want_lse)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+        return
+    argnum = ("dq", "dk", "dv").index(what)
+
+    def loss(attention, *qkv):
+        return (attention(*qkv) ** 2).sum()
+
+    flash = lambda q, k, v: flash_attention(q, k, v, bias, block=16, mask=mask, scale=scale)  # noqa: E731
+    dense = lambda q, k, v: _dense_two_sizes(q, k, v, bias, mask, scale)[0]  # noqa: E731
+    got = jax.jit(jax.grad(functools.partial(loss, flash), argnums=argnum))(q, k, v)
+    want = jax.grad(functools.partial(loss, dense), argnums=argnum)(q, k, v)
+    assert got.shape == (q, k, v)[argnum].shape
+    np.testing.assert_allclose(got, want, atol=2e-4)
+
+
+@pytest.mark.parametrize("mask", [Causal(), Causal(24), None], ids=["causal", "window", "none"])
+def test_the_blockwise_fallback_takes_unequal_head_sizes_and_a_scale(mask):
+    """40 positions do not tile by 16: forward and backward take the fallback."""
+    q, k, v, bias = _two_sizes(length=40)
+    out, lse = ra._flash_forward(q, k, v, bias, 16, 16, mask, want_lse=True, scale=0.3)
+    assert lse is None and out.shape == (2, 40, 4, 16)
+    np.testing.assert_allclose(out, _dense_two_sizes(q, k, v, bias, mask, 0.3)[0], atol=2e-5)
+    loss = lambda attention: lambda q, k, v: (attention(q, k, v) ** 2).sum()  # noqa: E731
+    got = jax.grad(loss(lambda q, k, v: flash_attention(q, k, v, bias, block=16, mask=mask, scale=0.3)),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: _dense_two_sizes(q, k, v, bias, mask, 0.3)[0]), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-4)
+
+
+@pytest.mark.parametrize("what", ["arrays", "names", "tiling", "count", "pallas_backwards"])
+def test_equal_head_sizes_and_no_scale_are_what_they_were(what):
+    q, k, v, bias = _two_sizes(d=16, dv=16)
+    if what == "arrays":  # None is 1/sqrt(d), to the bit, forward and backward
+        def grads(scale):
+            return jax.grad(lambda q, k, v: (flash_attention(
+                q, k, v, bias, block=16, causal=True, scale=scale) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(grads(None), grads(1.0 / 16 ** 0.5)):
+            np.testing.assert_array_equal(a, b)
+    elif what == "names":  # the kernels' names gain the head sizes only where they differ
+        assert ra.head_sizes_suffix(128, 128) == "" and ra.head_sizes_suffix(192, 128) == "_d192v128"
+        equal = jax.jit(lambda *a: ra._flash_forward(*a, 16, 16, Causal())).lower(q, k, v, bias).as_text(debug_info=True)
+        assert "flash_fwd_resident_q64_k64_g4" in equal and "_d16v16" not in equal
+        q24, k24, v16, _ = _two_sizes()
+        text = jax.jit(jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, bias, block=16, causal=True, scale=0.3).sum(), argnums=(0, 1, 2))
+        ).lower(q24, k24, v16).as_text(debug_info=True)
+        assert "flash_fwd_resident_q64_k64_g4_d24v16" in text
+        assert "flash_bwd_xla_q16_k16_live10of16_d24v16" in text
+    elif what == "tiling":  # the cell's shape counts over the budget: the KV axis on the grid; the accepted shapes as before
+        t = ra.flash_forward_tiling(8192, 8192, 192, jnp.bfloat16, Causal(), heads=16, dv=128)
+        assert t == ra.FlashTiling(False, 512, 1024) and t.name == "flash_fwd_kvgrid_q512_k1024"
+        assert ra.flash_forward_tiling(8192, 8192, 128, jnp.bfloat16, Causal(), heads=32) \
+            == ra.FlashTiling(True, 256, 512)
+        assert ra.flash_forward_tiling(8192, 8192, 192, jnp.bfloat16, Causal(), heads=16) \
+            == ra.FlashTiling(False, 512, 1024)  # values of 192 too
+        assert ra.flash_forward_tiling(16384, 16384, 128, jnp.bfloat16, Causal(), heads=32) \
+            == ra.FlashTiling(False, 512, 1024)
+    elif what == "count":  # K's and V's lanes counted apart: 192 pads to 256, 128 stays
+        t = ra.FlashTiling(True, 256, 512)
+        two = ra._flash_fwd_vmem_bytes(t, 8192, 192, jnp.bfloat16, 128)
+        blocks = 2 * (256 * (256 + 128) * 2 + 8192 * (256 + 128) * 2 + 256 * 128 * 4 + 8 * 8192 * 4)
+        assert two == blocks + 3 * 256 * 512 * 4 + 256 * (128 + 256) * 4 == 15_728_640  # 15.0 MiB
+        assert two > ra.FLASH_FWD_VMEM_BUDGET
+        assert ra._flash_fwd_vmem_bytes(t, 8192, 128, jnp.bfloat16, 128) \
+            == ra._flash_fwd_vmem_bytes(t, 8192, 128, jnp.bfloat16) == 11_403_264
+    else:  # the four pallas backwards know one head size and their own scale, and say so
+        q24, k24, v16, _ = _two_sizes()
+        out, lse = ra._flash_forward(q24, k24, v16, bias, 16, 16, Causal(), want_lse=True, scale=0.3)
+        for impl in ("loop2", "ddpre", "loop", "scratch"):
+            with pytest.raises(NotImplementedError, match="one head size"):
+                ra._flash_backward(q24, k24, v16, bias, out, lse, out, 16, 16, Causal(), impl=impl, scale=0.3)
+            with pytest.raises(NotImplementedError, match="no given scale"):
+                ra._flash_backward(q, k, v, bias, v, lse, v, 16, 16, Causal(), impl=impl, scale=0.3)

@@ -237,3 +237,62 @@ def test_a_models_corruption_runs_under_its_scope_with_the_steps_rng(toy, what):
         assert float(first["kept_share"]) != float(second["kept_share"])
         resumed = trainer.init_state(x).replace(step=jnp.ones((), jnp.int32))
         assert float(trainer.train_step(resumed, (x, x))[1]["kept_share"]) == float(second["kept_share"])
+
+
+# ------------------------------------------------- latent attention's scopes and parts
+
+MLA_SCOPES = ("mla.kv_down", "mla.kv_norm", "mla.kv_up", "mla.rope")
+
+
+@pytest.fixture(scope="module")
+def mla():
+    from kubeflow_tpu.models import DeepseekV2Config, DeepseekV2LM
+    from kubeflow_tpu.models.gpt import causal_lm_eval_metrics, causal_lm_loss
+    from kubeflow_tpu.train import Trainer, TrainerConfig
+
+    trainer = Trainer(DeepseekV2LM(DeepseekV2Config.tiny(vocab_size=64)),
+                      TrainerConfig(batch_size=8, learning_rate=1e-3, seed=1),
+                      loss_fn=causal_lm_loss, eval_metrics_fn=causal_lm_eval_metrics)
+    x = np.random.default_rng(0).integers(1, 64, size=(8, 16)).astype(np.int32)
+    return trainer, x, set(step_op_names(trainer, x).values())
+
+
+def test_the_mla_scopes_change_no_instruction_of_the_step(mla, monkeypatch):
+    """`mla.kv_down`, `mla.kv_norm`, `mla.kv_up` and `mla.rope` are metadata, like the
+    Trainer's own scopes: the step lowers to the same text with and without them."""
+    from kubeflow_tpu.models import deepseek_v2
+
+    trainer, x, _ = mla
+    with_scopes = lowered_step(trainer, x, x)
+    named = with_scopes.as_text(debug_info=True)
+    assert all(scope in named for scope in MLA_SCOPES)
+    monkeypatch.setattr(deepseek_v2, "jax", JaxWithoutScopes())
+    without = lowered_step(trainer, x, x)
+    monkeypatch.undo()
+    assert not any(scope in without.as_text(debug_info=True) for scope in MLA_SCOPES)
+    assert with_scopes.as_text() == without.as_text()
+
+
+@pytest.mark.parametrize("under,parts", [
+    ("/kv_latent/", {"block_dense"}),                       # down, norm, up: projections
+    ("/kv_latent/mla.kv_down/down/", {"block_dense"}),
+    ("/kv_latent/mla.kv_norm/norm/", {"block_dense"}),
+    ("/kv_latent/mla.kv_up/up/", {"block_dense"}),
+    ("/attention/query/", {"block_dense"}),
+    ("/attention/attn_out/", {"block_dense"}),
+    ("/attention/mla.rope/", {"attn_core_fwd", "attn_core_bwd"}),  # rotation, broadcast, concatenations
+    ("/layer_1/moe/moe.route/", {"block_dense"}),           # the balance loss is the router's
+])
+def test_every_operation_of_the_latent_path_reads_block_dense(mla, under, parts):
+    """`train_parts.ATTENTION_CORE` counts what lies under `attention/` and under none of
+    its projections: the latent path is a module beside `attention`, so its products land
+    with the block's dense work, and the rotation stays with the core."""
+    from benchmarks.layer_metrics.train_parts import part_of
+
+    _, _, names = mla
+    found = {n for n in names if under in n and re.search(r"/layer_\d+/", n)}
+    assert found, under
+    assert {part_of(n) for n in found} <= parts, {n: part_of(n) for n in found if part_of(n) not in parts}
+    if under == "/kv_latent/":  # forward and backward both, in every layer
+        assert any("transpose(" in n for n in found) and any("transpose(" not in n for n in found)
+        assert {m.group(0) for n in found for m in [re.search(r"layer_\d+", n)]} == {"layer_0", "layer_1", "layer_2"}
