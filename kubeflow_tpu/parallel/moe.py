@@ -448,13 +448,17 @@ def _sum_over_choices(rows, inverse, held, scale, n_rows):
     pair, all T x K of them, but what a pair costs follows the STATIC size of
     the gather's source (SMALL_SOURCE_BYTES), and the rows in use are the
     prefix below `n_rows`: where they fit a source of that size, a branch on
-    `n_rows` gathers from the prefix alone."""
+    `n_rows` gathers from the prefix alone. The gather is choice-major, (K, T,
+    H): the sum adds K dense (T, H) slabs whatever K is, where (T, K, H) puts
+    the choices on the tiled second-minor dimension and, at a K that does not
+    fill the tile (6), costs a relayout of every gathered row (PERF.md, PR 35)."""
+    inverse, held = inverse.T, held.T
+    scale = None if scale is None else jnp.where(held, scale.T, 0.0)
 
     def over(src):
         picked = jnp.where(held[..., None], src[jnp.minimum(inverse, src.shape[0] - 1)],
                            jnp.zeros((), src.dtype)).astype(jnp.float32)
-        return (picked if scale is None
-                else picked * jnp.where(held, scale, 0.0)[..., None]).sum(1)
+        return (picked if scale is None else picked * scale[..., None]).sum(0)
 
     cap = SMALL_SOURCE_BYTES // (rows.shape[1] * rows.dtype.itemsize)
     if not 0 < cap < rows.shape[0]:
@@ -528,8 +532,11 @@ def _combine(y, weights, order, inverse, held, n_rows):
     held experts' only (`order`, `inverse`, `held` as `_dispatch` takes them).
     The gradient is written in row space, over the chunks below `n_rows`:
     d_y[r] = w[r] * d_out[token(r)], a gather from the T rows of d_out,
-    written over y, and d_w[r] = <y[r], d_out[token(r)]> in float32, brought
-    back to (T, K) by a gather of scalars; no (T, K, H) cotangent exists."""
+    written over y, and d_w[r] = <y[r], d_out[token(r)]> in float32; no
+    (T, K, H) cotangent exists. The weights reach row order and d_w goes back
+    to (T, K) as values of a sort keyed on the permutation (`inverse`,
+    `order`): a gather of T x K single scalars costs as much as the gather of
+    as many whole rows (8-10 ns an element: PERF.md, PR 35)."""
     return _combine_fwd(y, weights, order, inverse, held, n_rows)[0]
 
 
@@ -540,15 +547,19 @@ def _combine_fwd(y, weights, order, inverse, held, n_rows):
 
 def _combine_bwd(res, g):
     y, weights, order, inverse, held, n_rows = res
-    flat = weights.reshape(-1)
+    pairs = held.size
+    _, by_row = jax.lax.sort((inverse.reshape(-1), weights.reshape(-1)), num_keys=1)
 
-    def d_rows(y, pair):
+    def d_rows(y, pair, w):
         d = g[pair // held.shape[1]]
-        return ((flat[pair][:, None] * d).astype(y.dtype),
-                (y.astype(jnp.float32) * d).sum(-1))
+        return ((w[:, None] * d).astype(y.dtype), (y.astype(jnp.float32) * d).sum(-1))
 
-    d_y, d_w = _per_live_chunk(d_rows, n_rows, y, order, over=1)
-    return d_y, jnp.where(held, d_w[inverse], 0.0), None, None, None, None
+    d_y, d_w = _per_live_chunk(d_rows, n_rows, y, order,
+                               jnp.pad(by_row, (0, order.size - pairs)), over=1)
+    # rows at and past n_rows hold anything, NaN included: the sort carries
+    # them as values it never compares, and `held` masks them
+    _, d_w = jax.lax.sort((order[:pairs], d_w[:pairs]), num_keys=1)
+    return d_y, jnp.where(held, d_w.reshape(held.shape), 0.0), None, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -566,7 +577,14 @@ def _route_top_k(score, x, kernel, bias, top_k: int, scale: float,
                      precision=jax.lax.Precision.HIGHEST)
     scores = score(logits)
     _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
-    picked = jnp.take_along_axis(scores, idx, axis=-1)
+    # the chosen scores by a select over the expert axis, exact (one term of
+    # the sum is not zero) and not `take_along_axis`: its gather of T x K
+    # single scalars, forward, and the scatter-add back cost what gathering
+    # as many whole rows does. No one-hot product: the MXU would round to bf16.
+    # Behind a barrier, or XLA folds the normalisation's sum over the K into
+    # this one and adds the chosen scores in the experts' order, not the choices'
+    picked = jax.lax.optimization_barrier(jnp.where(
+        idx[..., None] == jnp.arange(scores.shape[-1]), scores[:, None, :], 0.0).sum(-1))
     if not renormalise:
         return idx, scale * picked, scores
     weights = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)

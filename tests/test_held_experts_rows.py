@@ -23,12 +23,12 @@ SHARE = (4, 8)                   # a share that holds four of the sixteen expert
 BALANCED = TOKENS * K * (SHARE[1] - SHARE[0]) // E
 
 
-def _layer(held):
-    return HeldExpertsMlp(hidden_size=H, expert_dim=M, num_experts=E, top_k=K,
+def _layer(held, k=K):
+    return HeldExpertsMlp(hidden_size=H, expert_dim=M, num_experts=E, top_k=k,
                           experts_held=held, route_scale=SCALE)
 
 
-def _routed(rows_wanted: int, held, seed: int = 0):
+def _routed(rows_wanted: int, held, seed: int = 0, k=K):
     """x (1, TOKENS, H), the layer's parameters and a selection bias of zero, with a router
     made so that exactly `rows_wanted` of the (token, choice) pairs fall on experts of
     `held`: the tokens are fewer than the hidden size, so a router that gives any wanted
@@ -36,9 +36,9 @@ def _routed(rows_wanted: int, held, seed: int = 0):
     the held experts far above the rest and the others far below, token by token."""
     lo, hi = held
     x = jax.random.normal(jax.random.PRNGKey(seed), (1, TOKENS, H))
-    variables = _layer(held).init(jax.random.PRNGKey(seed + 1), x)
+    variables = _layer(held, k).init(jax.random.PRNGKey(seed + 1), x)
     params = dict(variables["params"])
-    per_token = min(K, hi - lo)
+    per_token = min(k, hi - lo)
     assert 0 <= rows_wanted <= TOKENS * per_token
     want = np.full((TOKENS,), rows_wanted // TOKENS)
     want[:rows_wanted % TOKENS] += 1          # how many of its K choices token t has here
@@ -90,9 +90,9 @@ def _value_and_grads(fn, params, x):
     return jax.jit(jax.value_and_grad(lambda p, x: (fn(p, x) * cot).sum(), argnums=(0, 1)))(params, x)
 
 
-def _program(held, state):
+def _program(held, state, k=K):
     def fn(p, x):
-        return _layer(held).apply({"params": p, ROUTER_STATE: state}, x)
+        return _layer(held, k).apply({"params": p, ROUTER_STATE: state}, x)
     return fn
 
 
@@ -141,15 +141,22 @@ def test_output_and_gradients_at_any_fill_are_the_whole_bounds(monkeypatch, held
     assert all(float(jnp.abs(d_params[n]).max()) > 0 for n in reached)
 
 
-@pytest.mark.parametrize("fill", ["one", "chunk+1", "balanced"])
-def test_rows_no_one_wrote_may_hold_nan(monkeypatch, fill):
+@pytest.mark.parametrize("k,rows", [
+    *[(K, FILLS[fill]) for fill in ("one", "chunk+1", "balanced")],
+    # six choices a token do not fill a tile's sublanes, eight do; 144 and 192 pairs, the
+    # share's four experts at most 96 of them: fills below and above the prefix's 40 rows
+    *[(k, rows) for k in (6, 8) for rows in (1, CHUNK + 1, 36, 48, 96)],
+])
+def test_rows_no_one_wrote_may_hold_nan(monkeypatch, k, rows):
     """Every buffer of the layer starts as NaN, and the rows the dispatch gathered past
     the rows in use (the last live chunk's tail) are NaN too: output and every gradient
-    stay finite, and are what they were."""
+    stay finite, and are what they were. The NaNs ride through the combine's gradient as
+    values of its sorts (the weights' gradient in row order, brought back to pair order),
+    which never compare them."""
     monkeypatch.setattr(moe, "ROW_CHUNK", CHUNK)
     monkeypatch.setattr(moe, "SMALL_SOURCE_BYTES", 40 * H * 4)
-    x, params, state = _routed(FILLS[fill], SHARE)
-    want = _value_and_grads(_program(SHARE, state), params, x)
+    x, params, state = _routed(rows, SHARE, k=k)
+    want = _value_and_grads(_program(SHARE, state, k), params, x)
 
     dispatch = moe._dispatch
 
@@ -159,7 +166,7 @@ def test_rows_no_one_wrote_may_hold_nan(monkeypatch, fill):
 
     monkeypatch.setattr(moe, "_unwritten", lambda shape, dtype, after: jnp.full(shape, jnp.nan, dtype))
     monkeypatch.setattr(moe, "_dispatch", poisoned)
-    got = _value_and_grads(_program(SHARE, state), params, x)
+    got = _value_and_grads(_program(SHARE, state, k), params, x)
     assert all(bool(jnp.isfinite(a).all()) for a in jax.tree.leaves(got))
     _assert_close(got, want, "poisoned")
 
