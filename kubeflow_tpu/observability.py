@@ -415,10 +415,11 @@ def render_metrics(platform) -> str:
     # render ZERO-valued on an idle platform so the golden exposition
     # pins a stable surface (KFTPU-METRIC contract).
     from kubeflow_tpu.train.data import loader_metrics_snapshot
-    from kubeflow_tpu.utils.compile_cache import compile_metrics_snapshot
+    from kubeflow_tpu.utils.compile_cache import compile_counts
 
-    for mname, v in sorted(compile_metrics_snapshot().items()):
-        counter(f"kftpu_train_compile_{mname}", v)
+    for mname, v in sorted(compile_counts().items()):
+        counter(f"kftpu_train_compile_{mname}",
+                v if isinstance(v, int) else f"{v:.6f}")
     loader_snap = loader_metrics_snapshot()
     live_loaders = loader_snap.pop("live_loaders")
     for mname, v in sorted(loader_snap.items()):
@@ -429,26 +430,6 @@ def render_metrics(platform) -> str:
         help_="AsyncLoader producer threads still running "
               "(a wedged loader thread shows here)",
     )
-    # gradient-communication ledger (parallel/partitioner.py, docs/
-    # partitioner.md "Overlap mechanics"): host-visible comm time left ON
-    # the step critical path, and the latest overlapped/serialized
-    # step-time ratio the grad_overlap machinery measured. Process-global
-    # and zero-valued when idle, like the loader/compile families above.
-    from kubeflow_tpu.parallel.partitioner import comm_metrics_snapshot
-
-    comm_snap = comm_metrics_snapshot()
-    counter("kftpu_train_comm_seconds_total",
-            f"{comm_snap['comm_seconds_total']:.6f}",
-            help_="gradient-collective wall time charged to step "
-                  "critical paths (train.comm spans)")
-    counter("kftpu_train_comm_overlap_measurements_total",
-            comm_snap["overlap_measurements_total"])
-    gauge(
-        "kftpu_train_overlap_ratio", comm_snap["overlap_ratio"],
-        help_="latest overlapped/serialized step-time ratio from the "
-              "grad_overlap measurement (lower is better; 0 = none yet)",
-    )
-
     # liveness layer (kubeflow_tpu/health.py): lease expiries and straggler
     # declarations counted apart from crash deaths, plus per-incarnation
     # heartbeat age straight from the kubelet layer's side table

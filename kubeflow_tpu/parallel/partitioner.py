@@ -206,44 +206,6 @@ def path_str_of(path) -> str:
     return "/".join(parts)
 
 
-# --------------------------------------------------------- comm accounting
-
-#: process-global gradient-communication ledger (observability renders it
-#: as kftpu_train_comm_* — zero-valued on an idle process, so the golden
-#: exposition pins a stable surface). comm_seconds counts host-visible
-#: time spent blocked on gradient collectives that did NOT overlap
-#: compute; overlap_ratio is the latest overlapped/serialized step-time
-#: ratio measured by the grad_overlap machinery (1.0 = no overlap won).
-_COMM_METRICS = {
-    "comm_seconds_total": 0.0,
-    "overlap_measurements_total": 0,
-}
-_LAST_OVERLAP_RATIO = 0.0
-
-
-def record_comm(seconds: float, overlap_ratio: float | None = None) -> None:
-    """Account gradient-communication wall time (and optionally a new
-    overlap-ratio measurement) into the process-global ledger."""
-    global _LAST_OVERLAP_RATIO
-    _COMM_METRICS["comm_seconds_total"] += float(seconds)
-    if overlap_ratio is not None:
-        _COMM_METRICS["overlap_measurements_total"] += 1
-        _LAST_OVERLAP_RATIO = float(overlap_ratio)
-
-
-def comm_metrics_snapshot() -> dict:
-    return dict(_COMM_METRICS, overlap_ratio=_LAST_OVERLAP_RATIO)
-
-
-def reset_comm_metrics() -> None:
-    """Test hook: zero the ledger (the golden-exposition test pins the
-    zero-valued families)."""
-    global _LAST_OVERLAP_RATIO
-    _COMM_METRICS["comm_seconds_total"] = 0.0
-    _COMM_METRICS["overlap_measurements_total"] = 0
-    _LAST_OVERLAP_RATIO = 0.0
-
-
 @dataclass
 class Partitioner:
     """Derives every PartitionSpec the trainer needs from one rule set.

@@ -29,10 +29,23 @@ import time
 from typing import Any
 
 import jax
-import orbax.checkpoint as ocp
 
 from kubeflow_tpu.analysis.lockcheck import make_lock
 from kubeflow_tpu.health import CKPT_MANIFEST_NAME, ckpt_verify_bump
+
+
+def _ocp():
+    """`orbax.checkpoint`, imported where a checkpoint is first made and not
+    with the trainer: the import pulls in `google.cloud.logging`, whose
+    packages check their dependencies' versions as they are imported
+    (`importlib.metadata.packages_distributions()`, twice: every installed
+    distribution's RECORD parsed, every listed file stat-ed). On the
+    benchmark's machines the import was 26-35 s of every process that
+    imported `kubeflow_tpu.train`, 24-32 s of it those checks, and it moved
+    by 8 s with nothing but the heap's state (PERF.md Findings, PR 36)."""
+    import orbax.checkpoint as ocp
+
+    return ocp
 
 
 class Checkpointer:
@@ -69,9 +82,9 @@ class Checkpointer:
         self._kept: set[int] | None = None
 
     def _open(self):
-        return ocp.CheckpointManager(
+        return _ocp().CheckpointManager(
             self.directory,
-            options=ocp.CheckpointManagerOptions(**self._mgr_kwargs),
+            options=_ocp().CheckpointManagerOptions(**self._mgr_kwargs),
         )
 
     def _reopen(self) -> None:
@@ -97,7 +110,7 @@ class Checkpointer:
         # half-way through one of them, and the next must know which went
         with self._manifest_mu:
             self._mgr.save(
-                step, args=ocp.args.StandardSave(state),
+                step, args=_ocp().args.StandardSave(state),
                 **({"metrics": metrics} if metrics is not None else {}),
             )
             self._kept = set(self._mgr.all_steps())
@@ -149,7 +162,7 @@ class Checkpointer:
                 "steps_verified_total" if verdict
                 else "unverified_restores_total")
         restored = self._mgr.restore(
-            step, args=ocp.args.StandardRestore(abstract_state)
+            step, args=_ocp().args.StandardRestore(abstract_state)
         )
         return step, restored
 
@@ -164,7 +177,7 @@ class Checkpointer:
             if step is None:
                 return None
             return step, self._mgr.restore(
-                step, args=ocp.args.StandardRestore(abstract_state))
+                step, args=_ocp().args.StandardRestore(abstract_state))
 
         quarantined: list[int] = []   # moved out of the tree
         unmovable: list[int] = []     # corrupt but the move itself failed
@@ -196,10 +209,10 @@ class Checkpointer:
                     attrs["skipped_unmovable"] = ",".join(map(str, unmovable))
                 with get_tracer().span("checkpoint.fallback", **attrs):
                     restored = self._mgr.restore(
-                        step, args=ocp.args.StandardRestore(abstract_state))
+                        step, args=_ocp().args.StandardRestore(abstract_state))
             else:
                 restored = self._mgr.restore(
-                    step, args=ocp.args.StandardRestore(abstract_state))
+                    step, args=_ocp().args.StandardRestore(abstract_state))
             return step, restored
         return None
 

@@ -39,6 +39,7 @@ from kubeflow_tpu.parallel.sharding import (
     stacked_batch_sharding,
 )
 from kubeflow_tpu.tracing import get_tracer, init_worker_from_env
+from kubeflow_tpu.utils import compile_cache as cc
 from kubeflow_tpu.utils.device import device_summary
 from kubeflow_tpu.utils.envvars import ENV_EVENT_DIR, ENV_PROFILE_DIR
 from kubeflow_tpu.train import metrics as metrics_lib
@@ -385,8 +386,10 @@ class Trainer:
         return build, x
 
     def init_state(self, sample_x: np.ndarray) -> TrainState:
-        build, x = self._state_builder(sample_x)
-
+        """The seeded state, built on the device by one jitted program
+        (`jit_init_state` in the start-up log). The region `train.init_state`
+        is the host's time until that program is built and enqueued, not
+        until the device has run it."""
         # Build INSIDE jit with the shardings constrained in-graph: params
         # materialize directly sharded (never replicated on one device first
         # — required for models bigger than a single chip's HBM), and the
@@ -401,14 +404,18 @@ class Trainer:
         # threefry, so the constrained build draws the SAME bits the
         # single-device build would — the layout-invariant-init contract
         # the fsdp-vs-single numerics tests pin (parallel/partitioner.py).
-        with jax.set_mesh(self.mesh), self.partitioner.deterministic_rng():
-            abstract = jax.eval_shape(build, x)
-            shardings = self.partitioner.state_shardings(abstract)
-            return jax.jit(
-                lambda x: jax.tree.map(
-                    jax.lax.with_sharding_constraint, build(x), shardings
-                )
-            )(x)
+        with cc.region("train.init_state"):
+            build, x = self._state_builder(sample_x)
+            with jax.set_mesh(self.mesh), \
+                    self.partitioner.deterministic_rng():
+                abstract = jax.eval_shape(build, x)
+                shardings = self.partitioner.state_shardings(abstract)
+
+                def init_state(x):
+                    return jax.tree.map(
+                        jax.lax.with_sharding_constraint, build(x), shardings)
+
+                return jax.jit(init_state)(x)
 
     @staticmethod
     def param_placement(state: TrainState) -> dict[str, int]:
@@ -661,8 +668,12 @@ class Trainer:
         it. `train.enqueue` is everything the host does in here to start
         the step (with `train.place_batch`, the batch's placement, inside
         it): on the profiler's clock while a jax.profiler session records,
-        in the flight recorder while a Tracer is armed."""
+        in the flight recorder while a Tracer is armed. A step that had to
+        build its program first (the first of a process, a new batch
+        shape) says so: `built`, the programs the backend compiled or
+        loaded under it, which the start-up log names."""
         tracer = get_tracer()
+        built = cc.programs_built()
         # ambient mesh enables P-form with_sharding_constraint pins inside
         # models (bert.constrain) without threading the mesh through
         # modules; deterministic_rng keeps traced random draws (dropout,
@@ -671,21 +682,27 @@ class Trainer:
                 "train.enqueue",
                 path="executable" if self._step_compiled is not None else "jit",
         ) as sp, jax.set_mesh(self.mesh), self.partitioner.deterministic_rng():
-            with tracer.span("train.place_batch"):
-                placed = self._place(batch)
-            if self._step_compiled is not None:
-                try:
-                    # warm_start's executable (reloaded from the compile
-                    # cache on a restarted incarnation, or AOT-compiled at
-                    # setup) — same program as the jit path; a signature
-                    # mismatch falls through to jit dispatch ONCE and
-                    # drops the executable (retrying every step would put
-                    # a raise/catch on the hot path this PR exists to thin)
-                    return self._step_compiled(state, placed)
-                except (TypeError, ValueError):
-                    self._step_compiled = None
-                    sp.set_attribute("path", "jit")
-            return self._jit_train_step(state, placed)
+            try:
+                with tracer.span("train.place_batch"):
+                    placed = self._place(batch)
+                if self._step_compiled is not None:
+                    try:
+                        # warm_start's executable (reloaded from the
+                        # compile cache on a restarted incarnation, or
+                        # AOT-compiled at setup) — same program as the jit
+                        # path; a signature mismatch falls through to jit
+                        # dispatch ONCE and drops the executable (retrying
+                        # every step would put a raise/catch on the hot
+                        # path this PR exists to thin)
+                        return self._step_compiled(state, placed)
+                    except (TypeError, ValueError):
+                        self._step_compiled = None
+                        sp.set_attribute("path", "jit")
+                return self._jit_train_step(state, placed)
+            finally:
+                if cc.programs_built() != built:
+                    sp.set_attribute(
+                        "built", cc.programs_built() - built)
 
     def train_steps_fused(
         self, state: TrainState, batch, n: int
@@ -791,8 +808,6 @@ class Trainer:
         import functools
         import hashlib
 
-        from kubeflow_tpu.utils import compile_cache as cc
-
         c = self.config
 
         def _code_blob(code) -> bytes:
@@ -871,8 +886,6 @@ class Trainer:
         serialize it for the next incarnation. Returns the attribution
         dict fit() stamps on its train.compile span; no-op ({"enabled":
         False}) when no cache dir resolves anywhere."""
-        from kubeflow_tpu.utils import compile_cache as cc
-
         cache_dir = cc.resolve_cache_dir(
             cache_dir or self.config.compile_cache_dir)
         if not cache_dir:
@@ -933,16 +946,22 @@ class Trainer:
                     reloaded.append(f"train_chunk_{fused_k}")
                 self._fused_data_compiled[fused_k] = kc
         after = cc.compile_counts()
+
+        def since(counter):
+            return after[counter] - before[counter]
+
         return {
             "enabled": True,
             "cache_dir": cache_dir,
             "key": key,
             "reloaded": ",".join(reloaded),
             "compiled": ",".join(compiled_now),
-            "backend_misses": (after["backend_misses_total"]
-                               - before["backend_misses_total"]),
-            "backend_requests": (after["requests_total"]
-                                 - before["requests_total"]),
+            "backend_misses": since("backend_misses_total"),
+            "backend_requests": since("requests_total"),
+            "cache_hits": since("cache_hits_total"),
+            "trace_s": since("trace_seconds_total"),
+            "lower_s": since("lower_seconds_total"),
+            "backend_s": since("backend_seconds_total"),
         }
 
     # ------------------------------------------------------------------- fit
@@ -982,27 +1001,25 @@ class Trainer:
         # jax latches the cache state at first use, so enabling it after
         # init_state would leave this process's cache writes silently
         # skipped (see utils/compile_cache.enable_persistent_cache).
-        from kubeflow_tpu.utils import compile_cache as _cc
-
-        cache_dir = _cc.resolve_cache_dir(c.compile_cache_dir)
+        cache_dir = cc.resolve_cache_dir(c.compile_cache_dir)
         if cache_dir:
-            _cc.enable_persistent_cache(cache_dir)
+            cc.enable_persistent_cache(cache_dir)
         # the device this fit actually runs on, as jax reports it — what
         # the smoke test and every benchmark row read instead of guessing
         metrics_lib.emit(**device_summary())
-        state = self.init_state(dataset.x_train[: c.batch_size])
-        metrics_lib.emit(**self.param_placement(state))
-
-        event_dir = c.event_dir or os.environ.get(ENV_EVENT_DIR, "")
-        events = metrics_lib.TfEventsWriter(event_dir) if event_dir else None
-
         # Tracing: the installed tracer, else one from the pod env contract
         # (KFTPU_TRACE_DIR — the controller injects it when the platform
         # traces with a trace_dir; init_worker_from_env keeps an already-
         # installed tracer and is a no-op without the env). Untraced runs
         # get the NOOP tracer: every span call below is then a shared
-        # inert object, off the hot path.
+        # inert object, off the hot path. Before init_state, whose region
+        # and builds are the first spans of a traced job.
         tracer = init_worker_from_env(service="trainer")
+        state = self.init_state(dataset.x_train[: c.batch_size])
+        metrics_lib.emit(**self.param_placement(state))
+
+        event_dir = c.event_dir or os.environ.get(ENV_EVENT_DIR, "")
+        events = metrics_lib.TfEventsWriter(event_dir) if event_dir else None
 
         start_step = 0
         if resume and self.checkpointer is not None:
@@ -1023,7 +1040,7 @@ class Trainer:
         # is a no-op and the first step compiles inline, as before.
         if cache_dir:
             per_epoch = len(dataset.x_train) // c.batch_size
-            with tracer.span("train.compile") as sp:
+            with cc.region("train.compile") as sp:
                 info = self.warm_start(
                     dataset.x_train[:c.batch_size],
                     dataset.y_train[:c.batch_size],

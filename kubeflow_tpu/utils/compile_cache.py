@@ -35,6 +35,16 @@ pods run with their own.
 
 Process-global metrics land in /metrics as the kftpu_train_compile_*
 families (observability.py); `reset_compile_metrics` is the test hook.
+
+The start-up log. Besides the counts, the listener keeps what jax tells
+it of every program it traces, lowers, compiles or loads from the cache:
+which, when, for how long, and what the persistent cache did. That goes
+to the counters, to a bounded process-global log (`startup_log`: a few
+dozen entries a process, none on a step's path, so it is kept always),
+and as `compile.*` spans to a Tracer where one is armed. `region` marks
+the trainer's start-up phases in the same log; `kubeflow_tpu/train`
+installs the listener and notes its own import as the first entry.
+docs/observability.md ("The trainer's spans") has the names.
 """
 
 from __future__ import annotations
@@ -42,9 +52,14 @@ from __future__ import annotations
 import hashlib
 import os
 import pickle
+import re
 import threading
+import time
+from collections import deque
+from contextlib import contextmanager
 from pathlib import Path
 
+from kubeflow_tpu.tracing import armed_tracer, current_context, get_tracer
 from kubeflow_tpu.utils.envvars import ENV_COMPILE_CACHE_DIR
 
 #: suffix of serialized-executable artifacts inside <cache_dir>/executables
@@ -68,8 +83,46 @@ _METRICS = {
     "backend_misses_total": 0,    # backend compiles the XLA compiler ran
     "executable_reloads_total": 0,  # deserialized pre-compiled executables
     "executable_saves_total": 0,    # executables serialized for later runs
+    "cache_hits_total": 0,        # programs the persistent cache served
+    "cache_retrieval_seconds_total": 0.0,  # reading and loading those
+    "trace_seconds_total": 0.0,   # Python traced to jaxprs
+    "lower_seconds_total": 0.0,   # jaxprs lowered to MLIR modules
+    "backend_seconds_total": 0.0,  # XLA compiling, or the cache loading
 }
 _LISTENER_INSTALLED = False
+
+#: jax.monitoring's duration events -> the phase of making a program ready
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+#: entries the start-up log keeps: a cold start of the largest job logs a
+#: few dozen; past it the oldest is dropped and counted, as the flight
+#: recorder does
+STARTUP_LOG_CAPACITY = 512
+_LOG: deque = deque(maxlen=STARTUP_LOG_CAPACITY)
+_LOG_DROPPED = 0
+#: `compile.backend` entries ever logged in this process. `Trainer.
+#: train_step` reads it before and after a dispatch, without the lock: an
+#: int that only grows
+_BUILT = 0
+
+
+class _ThreadState(threading.local):
+    """What the listener pairs by thread: how many of jax's timed blocks
+    are open, what the persistent cache did inside the open backend block,
+    and the open region."""
+
+    depth = 0
+    cache = "off"
+    retrieval_s = 0.0
+    region = ""
+
+
+_THREAD = _ThreadState()
 
 
 #: jax's own variable for the persistent cache directory; where it is set
@@ -124,9 +177,12 @@ def enable_persistent_cache(cache_dir: str | Path) -> None:
 
 
 def install_compile_listener() -> None:
-    """Count backend compile requests/misses process-globally via the
-    jax.monitoring events the compilation cache emits. Idempotent; safe
-    to call before any cache is enabled (events simply don't fire)."""
+    """Keep what jax.monitoring tells of every program made ready: count
+    backend compile requests/misses/hits and the seconds by phase
+    process-globally, and write each trace, lowering and backend compile
+    (or cache load) to the start-up log and to an armed Tracer.
+    Idempotent; safe to call before any cache is enabled (the cache's
+    events simply don't fire and a build reads `cache="off"`)."""
     global _LISTENER_INSTALLED
     with _MU:
         if _LISTENER_INSTALLED:
@@ -134,35 +190,155 @@ def install_compile_listener() -> None:
         _LISTENER_INSTALLED = True
     import jax.monitoring as mon
 
-    def _listener(event: str, **kwargs) -> None:
-        if event == "/jax/compilation_cache/cache_misses":
-            with _MU:
-                _METRICS["backend_misses_total"] += 1
-        elif event == "/jax/compilation_cache/compile_requests_use_cache":
-            with _MU:
-                _METRICS["requests_total"] += 1
-
-    mon.register_event_listener(_listener)
+    mon.register_event_listener(_on_event)
+    mon.register_event_duration_secs_listener(_on_duration)
+    mon.register_scalar_listener(_on_block_entered)
 
 
-def compile_counts() -> dict[str, int]:
+def _on_event(event: str, **kwargs) -> None:
+    # the cache's events fire inside the backend block, on its thread
+    if event == "/jax/compilation_cache/cache_misses":
+        with _MU:
+            _METRICS["backend_misses_total"] += 1
+    elif event == "/jax/compilation_cache/compile_requests_use_cache":
+        # jax fires this with no cache directory too (the key is made, the
+        # read finds nothing to read from); a hit says so before the block
+        # closes
+        import jax
+
+        if jax.config.jax_compilation_cache_dir:
+            _THREAD.cache = "miss"
+        with _MU:
+            _METRICS["requests_total"] += 1
+    elif event == "/jax/compilation_cache/cache_hits":
+        _THREAD.cache = "hit"
+        with _MU:
+            _METRICS["cache_hits_total"] += 1
+
+
+def _on_block_entered(event: str, value, **kwargs) -> None:
+    """jax records a scalar (the start time) as it enters each timed block.
+    A trace or a lowering entered inside another block is part of that
+    one's seconds (a jit called while its caller is traced, a lowering
+    rule that traces jnp code: hundreds to a model); a backend block
+    starts with no word from the cache."""
+    phase = _PHASES.get(event)
+    if phase is not None:
+        _THREAD.depth += 1
+        if phase == "backend":
+            _THREAD.cache, _THREAD.retrieval_s = "off", 0.0
+
+
+def _on_duration(event: str, seconds: float, **kwargs) -> None:
+    phase = _PHASES.get(event)
+    if phase is None:
+        if event == _RETRIEVAL_EVENT:
+            _THREAD.retrieval_s = float(seconds)
+        return
+    # not below 0: the listener may have been installed inside a block
+    _THREAD.depth = max(_THREAD.depth - 1, 0)
+    if _THREAD.depth and phase != "backend":
+        return
+    fields = {"program": program_name(str(kwargs.get("fun_name", "")))}
+    if phase == "backend":
+        fields["cache"] = _THREAD.cache
+        if _THREAD.cache == "hit":
+            fields["retrieval_s"] = _THREAD.retrieval_s
+    fields["region"] = _THREAD.region
+    _record(f"compile.{phase}", time.time() - seconds, float(seconds),
+            fields, phase=phase)
+
+
+def program_name(fun_name: str) -> str:
+    """One name for one program whatever phase reports it: jax names a
+    trace by the function (`_train_step`) and a lowering or a build by the
+    module (`jit(_train_step)`), which the HLO and the device trace spell
+    `jit__train_step`. The log keeps that last spelling for all three."""
+    module = fun_name if fun_name.endswith(")") else f"jit({fun_name})"
+    return re.sub(r"[^\w.-]", "_", module).rstrip("_")
+
+
+def _record(name: str, start: float, seconds: float, fields: dict, *,
+            phase: str = "", span: bool = True) -> None:
+    """One entry of the start-up log, a build's counters (`phase`), and
+    where a Tracer is armed a span under whatever span is open on this
+    thread."""
+    global _LOG_DROPPED, _BUILT
+    with _MU:
+        if phase:
+            _METRICS[f"{phase}_seconds_total"] += seconds
+            _METRICS["cache_retrieval_seconds_total"] += fields.get(
+                "retrieval_s", 0.0)
+            _BUILT += phase == "backend"
+        if len(_LOG) == STARTUP_LOG_CAPACITY:
+            _LOG_DROPPED += 1
+        _LOG.append({"name": name, "start": start, "seconds": seconds,
+                     **fields})
+    tracer = armed_tracer(get_tracer()) if span else None
+    if tracer is not None:
+        tracer.record_span(
+            name, start, seconds,
+            parent=current_context() or tracer.default_parent, **fields)
+
+
+def note_import(name: str, start: float, seconds: float) -> None:
+    """A package's own import as an entry (`train.import`: `start` and
+    `seconds` span its `__init__` from the first line to the last)."""
+    _record(name, start, seconds, {})
+
+
+@contextmanager
+def region(name: str):
+    """A start-up phase of the trainer: `get_tracer().span(name)`, plus an
+    entry `{"name", "start", "seconds"}` in the start-up log on exit; what
+    is built inside carries `region=name`. Yields the span."""
+    outer, _THREAD.region = _THREAD.region, name
+    start, t0 = time.time(), time.perf_counter()
+    try:
+        with get_tracer().span(name) as sp:
+            yield sp
+    finally:
+        _THREAD.region = outer
+        # the live span above is the tracer's; the log alone gets this one
+        _record(name, start, time.perf_counter() - t0, {}, span=False)
+
+
+def startup_log() -> list[dict]:
+    """The log's entries, oldest first (copies: the caller may keep them)."""
+    with _MU:
+        return [dict(e) for e in _LOG]
+
+
+def startup_log_dropped() -> int:
+    """Entries the bounded log has dropped."""
+    return _LOG_DROPPED
+
+
+def programs_built() -> int:
+    """Programs the backend made ready in this process so far, compiled or
+    loaded from the persistent cache. Lock-free: `Trainer.train_step`
+    reads it around every dispatch."""
+    return _BUILT
+
+
+def compile_counts() -> dict[str, int | float]:
     """Snapshot of the process-global counters — subtract two snapshots
-    to get the misses/requests a code region caused (the zero-backend-
-    compilations assertion pattern)."""
+    to get the misses/requests/seconds a code region caused (the
+    zero-backend-compilations assertion pattern; observability.
+    render_metrics prints them as kftpu_train_compile_*)."""
     with _MU:
         return dict(_METRICS)
 
 
-def compile_metrics_snapshot() -> dict[str, int]:
-    """Alias used by observability.render_metrics (kftpu_train_compile_*)."""
-    return compile_counts()
-
-
 def reset_compile_metrics() -> None:
-    """Test hook: zero the counters (the listener stays installed)."""
+    """Test hook: zero the counters and empty the start-up log (the
+    listener stays installed)."""
+    global _LOG_DROPPED, _BUILT
     with _MU:
-        for k in _METRICS:
-            _METRICS[k] = 0
+        for k, v in _METRICS.items():
+            _METRICS[k] = type(v)()
+        _LOG.clear()
+        _LOG_DROPPED = _BUILT = 0
 
 
 def executable_key(**parts) -> str:

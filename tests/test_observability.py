@@ -90,13 +90,11 @@ class TestGoldenExposition:
         # this pins the same fresh-process surface regardless of which
         # tests ran first
         from kubeflow_tpu.analysis.protocheck import reset_protocheck_metrics
-        from kubeflow_tpu.parallel.partitioner import reset_comm_metrics
         from kubeflow_tpu.serving.fleet.podclient import reset_pod_metrics
 
         reset_ckpt_verify_metrics()
         reset_loader_metrics()
         reset_compile_metrics()
-        reset_comm_metrics()
         reset_pod_metrics()
         reset_protocheck_metrics()
         p = Platform(log_dir=str(tmp_path / "logs"))

@@ -392,20 +392,3 @@ class TestTrainerIntegration:
                 f32_losses, bf_losses)
         assert abs(f32_ev["loss"] - bf_ev["loss"]) < 0.2
         assert abs(f32_ev["accuracy"] - bf_ev["accuracy"]) < 0.15
-
-
-class TestCommLedger:
-    def test_record_and_snapshot_roundtrip(self):
-        pt_mod.reset_comm_metrics()
-        try:
-            snap = pt_mod.comm_metrics_snapshot()
-            assert snap["comm_seconds_total"] == 0.0
-            assert snap["overlap_ratio"] == 0.0
-            pt_mod.record_comm(0.25)
-            pt_mod.record_comm(0.5, overlap_ratio=0.6)
-            snap = pt_mod.comm_metrics_snapshot()
-            assert snap["comm_seconds_total"] == pytest.approx(0.75)
-            assert snap["overlap_measurements_total"] == 1
-            assert snap["overlap_ratio"] == pytest.approx(0.6)
-        finally:
-            pt_mod.reset_comm_metrics()
