@@ -15,6 +15,7 @@ from kubeflow_tpu.models.afmoe import AfmoeConfig, AfmoeLM
 from kubeflow_tpu.models.bert_pp import BertPipelineClassifier
 from kubeflow_tpu.models.deepseek_v2 import DeepseekV2Config, DeepseekV2LM
 from kubeflow_tpu.models.gpt_pp import GPTPipelineLM
+from kubeflow_tpu.models.granite_hybrid import GraniteHybridConfig, GraniteHybridLM
 from kubeflow_tpu.models.gpt import (
     GPTConfig,
     GPTLM,
@@ -57,6 +58,8 @@ __all__ = [
     "MnistMLP",
     "MnistCNN",
     "GPTPipelineLM",
+    "GraniteHybridConfig",
+    "GraniteHybridLM",
     "ViTClassifier",
     "ViTConfig",
     "SdarMoeConfig",

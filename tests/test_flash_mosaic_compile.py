@@ -127,3 +127,30 @@ def test_xla_backward_compiles_for_the_v5e_under_its_scope(one_chip, shape, mask
     tile = b * h * block_q * block_k * 4
     operands = 7 * b * h * length * d * 4  # q, k, v, dO, and dq, dk, dv in float32
     assert compiled.memory_analysis().temp_size_in_bytes <= operands + 6 * tile
+
+
+def test_a_mamba2_block_compiles_for_the_v5e_within_its_temporaries(one_chip):
+    """`granite4h-train-8k`'s Mamba-2 block (`models/granite_hybrid.py` under `nn.remat`, the
+    mixer of `parallel/ssm.py`) forward and backward at 8,192 positions and the published
+    widths: the five scopes are in the program, and the temporaries stay near one chunk's
+    working set because the scan's body is checkpointed (1.27 GB; 4.09 GB when the scan
+    kept every chunk's decay and products for its backward)."""
+    import flax.linen as nn
+
+    from kubeflow_tpu.models.granite_hybrid import MAMBA, GraniteHybridBlock, GraniteHybridConfig
+
+    block = nn.remat(GraniteHybridBlock, static_argnums=(2,), policy=ra.FLASH_REMAT_POLICY)(
+        GraniteHybridConfig(num_layers=10, dtype=jnp.bfloat16, remat=True), MAMBA)
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+                          jax.eval_shape(lambda k, x: block.init(k, x, False), jax.random.PRNGKey(0), x))
+
+    def loss(p, x):
+        y, _ = block.apply(p, x, True, mutable=["ssm_state"])
+        return (y.astype(jnp.float32) ** 2).mean()
+
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(params, x).compile()
+    text = compiled.as_text()
+    assert all(f"/mamba/{scope}/" in text for scope in
+               ("ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out_proj"))
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
