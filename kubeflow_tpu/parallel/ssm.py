@@ -13,17 +13,34 @@ groups of heads that share their `B` and `C`:
     y_t = S_t C_t + D x_t
     out = RMSNorm(y * silu(z); g over all H P in each of G groups) W_o
 
-`ssd_chunked` computes the recurrence in chunks of `chunk` positions (the
-state-space duality's block form): within a chunk the outputs are products
-under the segment-sum decay `exp(cum_t - cum_s)`, s <= t, of the chunk's own
-inputs; across chunks the state at each chunk's end is carried, and every
-position reads the carried state through `exp(cum_t)`. One `lax.scan` walks
-the chunks, so a chunk's `(H, Q, Q)` decay is the largest temporary; the
-backward is the scan's own, its body checkpointed: the scan keeps the carried
-states alone and runs each chunk's body again (at 8,192 positions and the
-published widths a block's temporaries are 1.27 GB so, 4.09 GB when every
-chunk's decay and products are kept). Decays and exponents are float32, the
-products' operands are the compute dtype with float32 accumulation.
+`ssd_chunked` computes the recurrence in chunks of Q positions (the
+state-space duality's block form), every chunk at once. With `cum` the running
+sum of `D_t A` within a chunk, position t of chunk k reads
+
+    y_t = sum_{s<=t in k} exp(cum_t - cum_s) (C_t . B_s) D_s x_s + exp(cum_t) C_t . S_{k-1}
+    S_k = exp(cum_end) S_{k-1} + sum_{s in k} exp(cum_end - cum_s) D_s x_s B_s^T
+
+Four pallas kernels and a little XLA, each over (chunk, block of HEAD_BLOCK
+heads) where it is a kernel:
+
+  XLA          the steps' running sums `cum` and each chunk's decay
+               `exp(cum_end)`, batched over the chunks;
+  ssd_state    each chunk's own state, the sum in `S_k`
+               (`ssd_state_fwd_q{Q}_h{heads}`);
+  XLA          the carried state, the one sequential part: an elementwise
+               `lax.scan` of c steps over (H, P, N) in float32;
+  ssd_chunk    each chunk's output (`ssd_chunk_fwd_q{Q}_h{heads}`): `C B^T` once a
+               program, then for each head the (Q, Q) decay and mix built in VMEM
+               on the (t, s) tiles on and below the diagonal (WALK_TILE), their
+               product with x, and the read of the carried state.
+
+Each kernel's backward is a kernel of its own (`..._bwd_...`, `jax.custom_vjp`)
+that keeps the inputs alone and builds what it needs again in VMEM, summing a
+group's `dB` and `dC` over its blocks of heads. No (Q, Q) tensor reaches HBM,
+and the custom backward takes the place of a recomputation: two forwards (the
+step's and the block's `remat`) and one backward a step. Decays and exponents
+are float32, the products' operands are the compute dtype with float32
+accumulation, the carried state is float32.
 
 Device scopes, one per stage, so a trace splits the mixer's time:
 `ssm.in_proj`, `ssm.conv`, `ssm.scan`, `ssm.gate_norm`, `ssm.out_proj`. The
@@ -39,12 +56,15 @@ stream (no reset of the state or the convolution inside it).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from kubeflow_tpu.parallel.mesh import AXIS_FSDP
@@ -83,6 +103,288 @@ def gated_rms_norm(y: jax.Array, z: jax.Array, gain: jax.Array, groups: int,
     return parts.reshape(h.shape) * gain.astype(jnp.float32)
 
 
+#: the walk within a chunk: (t, s) tiles of this many positions where the chunk is a
+#: multiple of it, else the whole chunk as one tile
+WALK_TILE = 128
+#: heads a kernel's program takes, where they divide a group's
+HEAD_BLOCK = 8
+#: dot_general's dimension numbers of `a b^T` and of `a^T b`
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
+
+
+def _walk(q: int):
+    """(rows of t, rows of s, whether the diagonal crosses the tile) for every tile on and
+    below the diagonal of a chunk of `q`, a row of t at a time, its diagonal tile last."""
+    tile = WALK_TILE if q % WALK_TILE == 0 else q
+    spans = [slice(i * tile, (i + 1) * tile) for i in range(q // tile)]
+    return [(spans[i], spans[j], i == j) for i in range(len(spans)) for j in range(i + 1)]
+
+
+def _decay(cum_t, cum_s, diagonal: bool):
+    """`exp(cum_t - cum_s)` on a tile from a (T, 1) column and a (1, T) row; on a tile the
+    diagonal crosses, 0 where s > t."""
+    d = cum_t - cum_s
+    if diagonal:
+        t = jax.lax.broadcasted_iota(jnp.int32, d.shape, 0)
+        s = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+        d = jnp.where(s <= t, d, -jnp.inf)
+    return jnp.exp(d)
+
+
+def _add(into: dict, key, value):
+    into[key] = value if key not in into else into[key] + value
+
+
+def _chunk_fwd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, s_ref, y_ref):
+    """One (chunk, block of heads) of `ssd_chunk`, a head at a time."""
+    heads, q = dt_ref.shape[2:]
+    hp = x_ref.shape[2] // heads
+    dtype = x_ref.dtype
+    cb = jax.lax.dot_general(c_ref[0, 0], b_ref[0, 0], _NT, preferred_element_type=jnp.float32)
+    cum, dt = cum_ref[0, 0], dt_ref[0, 0]                 # (heads, Q)
+    cum_col = cum.T                                       # (Q, heads)
+    for h in range(heads):
+        lanes = slice(h * hp, (h + 1) * hp)
+        carried = jax.lax.dot_general(c_ref[0, 0], s_ref[0, 0, h].astype(dtype), _NT,
+                                      preferred_element_type=jnp.float32)    # (Q, P)
+        acc = {}
+        for t, s, diagonal in _walk(q):
+            decay = _decay(cum_col[t, h:h + 1], cum[h:h + 1, s], diagonal)
+            mix = decay * cb[t, s] * dt[h:h + 1, s]
+            _add(acc, t.start, jnp.dot(mix.astype(dtype), x_ref[0, s, lanes],
+                                       preferred_element_type=jnp.float32))
+            if diagonal:
+                y_ref[0, t, lanes] = acc[t.start] + carried[t] * jnp.exp(cum_col[t, h:h + 1])
+
+
+def _chunk_bwd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, s_ref, dy_ref,
+                      dx_ref, ddt_ref, dcum_ref, db_ref, dc_ref, ds_ref, dcb_ref, dc_acc_ref,
+                      *, per_group: int):
+    """The backward of one (chunk, block of heads), a head at a time: decay, mix and the
+    chunk's output built again from the inputs. With `dmix = dy_t . x_s`: `dx = mix^T dy`;
+    `d dt_s` the column sums of `dmix * decay * CB`; `d cum` the row sums of `dmix * mix`
+    less their column sums, taken as `dy_t . y_t - x_s . dx_s` (the same products summed
+    two ways, so the two sides cancel where they should) and the carried read's
+    `dy_t . y_off_t`; `d S` a head; `d(C B^T)` and the carried read's `dC` summed over the
+    group's heads in VMEM, which the group's last block turns into `dB` and `dC`."""
+    heads, q = dt_ref.shape[2:]
+    hp = x_ref.shape[2] // heads
+    dtype = x_ref.dtype
+    block = pl.program_id(1)
+
+    @pl.when(block * heads % per_group == 0)
+    def _():
+        dcb_ref[...] = jnp.zeros_like(dcb_ref)
+        dc_acc_ref[...] = jnp.zeros_like(dc_acc_ref)
+
+    c = c_ref[0, 0]
+    cb = jax.lax.dot_general(c, b_ref[0, 0], _NT, preferred_element_type=jnp.float32)
+    cum, dt = cum_ref[0, 0], dt_ref[0, 0]
+    cum_col = cum.T
+    by_t = []                                             # each head's d cum, a column
+    for h in range(heads):
+        lanes = slice(h * hp, (h + 1) * hp)
+        dy_f32 = dy_ref[0, :, lanes]                      # (Q, P)
+        # the carried read, exp(cum_t) C_t . S
+        s_h = s_ref[0, 0, h].astype(dtype)
+        grow = jnp.exp(cum_col[:, h:h + 1])
+        y = jax.lax.dot_general(c, s_h, _NT, preferred_element_type=jnp.float32) * grow
+        dcarried = (dy_f32 * grow).astype(dtype)
+        ds_ref[0, 0, h] = jax.lax.dot_general(dcarried, c, _TN, preferred_element_type=jnp.float32
+                                              ).astype(ds_ref.dtype)
+        dc_acc_ref[...] += jnp.dot(dcarried, s_h, preferred_element_type=jnp.float32)
+        # the diagonal block
+        dy = dy_f32.astype(dtype)
+        dx, y_diag, ddt = {}, {}, {}
+        for t, s, diagonal in _walk(q):
+            decay = _decay(cum_col[t, h:h + 1], cum[h:h + 1, s], diagonal)
+            decay_cb = decay * cb[t, s]
+            mix = (decay_cb * dt[h:h + 1, s]).astype(dtype)
+            x_s = x_ref[0, s, lanes]
+            dmix = jax.lax.dot_general(dy[t], x_s, _NT, preferred_element_type=jnp.float32)
+            _add(dx, s.start, jax.lax.dot_general(mix, dy[t], _TN,
+                                                  preferred_element_type=jnp.float32))
+            _add(y_diag, t.start, jnp.dot(mix, x_s, preferred_element_type=jnp.float32))
+            _add(ddt, s.start, (dmix * decay_cb).sum(0, keepdims=True))
+            dcb_ref[t, s] += dmix * decay * dt[h:h + 1, s]
+        starts = sorted(dx)
+        dx_h = jnp.concatenate([dx[k] for k in starts], 0)
+        dx_ref[0, :, lanes] = dx_h.astype(dx_ref.dtype)
+        ddt_ref[0, 0, h:h + 1, :] = jnp.concatenate([ddt[k] for k in starts], 1)
+        y_diag = jnp.concatenate([y_diag[k] for k in starts], 0)
+        by_t.append((dy_f32 * y + dy.astype(jnp.float32) * y_diag
+                     - x_ref[0, :, lanes].astype(jnp.float32) * dx_h).sum(1, keepdims=True))
+    dcum_ref[0, 0] = jnp.concatenate(by_t, 1).T
+
+    @pl.when((block + 1) * heads % per_group == 0)
+    def _():
+        dcb = dcb_ref[...].astype(dtype)
+        dc_ref[0, 0] = (jnp.dot(dcb, b_ref[0, 0], preferred_element_type=jnp.float32)
+                        + dc_acc_ref[...]).astype(dc_ref.dtype)
+        db_ref[0, 0] = jax.lax.dot_general(dcb, c, _TN, preferred_element_type=jnp.float32
+                                           ).astype(db_ref.dtype)
+
+
+def _specs(x, dt, b):
+    """The kernels' grid, block specs and name: x-like (Bt, L, H P), step-like (Bt, c, H,
+    Q), B-like (Bt, G, L, N), state-like (Bt, c, H, P, N). A program takes one chunk of one
+    row and a block of heads inside one group."""
+    bt, chunks, heads, q = dt.shape
+    hp, n = x.shape[2] // heads, b.shape[3]
+    per_group = heads // b.shape[1]
+    block = math.gcd(per_group, HEAD_BLOCK)
+    row = lambda i, j: (i // chunks, i % chunks, j)            # noqa: E731
+    specs = {
+        "x": pl.BlockSpec((1, q, block * hp), row),
+        "step": pl.BlockSpec((1, 1, block, q), lambda i, j: (*row(i, j), 0)),
+        "b": pl.BlockSpec((1, 1, q, n), lambda i, j: (i // chunks, j * block // per_group,
+                                                      i % chunks, 0)),
+        "state": pl.BlockSpec((1, 1, block, hp, n), lambda i, j: (*row(i, j), 0, 0)),
+    }
+    common = dict(grid=(bt * chunks, heads // block), interpret=jax.default_backend() == "cpu")
+    return specs, common, f"q{q}_h{block}", per_group
+
+
+def _state_fwd_kernel(x_ref, dt_ref, cum_ref, b_ref, own_ref):
+    """One (chunk, block of heads) of `ssd_state`."""
+    heads, q = dt_ref.shape[2:]
+    hp = x_ref.shape[2] // heads
+    cum = cum_ref[0, 0]
+    to_end = (jnp.exp(cum[:, q - 1:q] - cum) * dt_ref[0, 0]).T        # (Q, heads)
+    for h in range(heads):
+        weighted = (x_ref[0, :, h * hp:(h + 1) * hp].astype(jnp.float32) * to_end[:, h:h + 1]
+                    ).astype(x_ref.dtype)
+        own_ref[0, 0, h] = jax.lax.dot_general(weighted, b_ref[0, 0], _TN,
+                                               preferred_element_type=jnp.float32)
+
+
+def _state_bwd_kernel(x_ref, dt_ref, cum_ref, b_ref, down_ref,
+                      dx_ref, ddt_ref, dcum_ref, db_ref, db_acc_ref, *, per_group: int):
+    """The backward of one (chunk, block of heads) of `ssd_state`: `dx`, `d dt` and `d cum`
+    a head, and `dB` summed over the group's heads in VMEM."""
+    heads, q = dt_ref.shape[2:]
+    hp = x_ref.shape[2] // heads
+    dtype = x_ref.dtype
+    block = pl.program_id(1)
+
+    @pl.when(block * heads % per_group == 0)
+    def _():
+        db_acc_ref[...] = jnp.zeros_like(db_acc_ref)
+
+    cum = cum_ref[0, 0]
+    decay = jnp.exp(cum[:, q - 1:q] - cum)                # (heads, Q)
+    to_end = decay * dt_ref[0, 0]
+    to_end_col = to_end.T
+    d_to_end = []
+    for h in range(heads):
+        x = x_ref[0, :, h * hp:(h + 1) * hp].astype(jnp.float32)
+        down = down_ref[0, 0, h].astype(dtype)            # (P, N)
+        dw = jax.lax.dot_general(b_ref[0, 0], down, _NT, preferred_element_type=jnp.float32)
+        dx_ref[0, :, h * hp:(h + 1) * hp] = (dw * to_end_col[:, h:h + 1]).astype(dx_ref.dtype)
+        d_to_end.append((dw * x).sum(1, keepdims=True))
+        weighted = (x * to_end_col[:, h:h + 1]).astype(dtype)
+        db_acc_ref[...] += jnp.dot(weighted, down, preferred_element_type=jnp.float32)
+    d_to_end = jnp.concatenate(d_to_end, 1).T            # (heads, Q)
+    ddt_ref[0, 0] = d_to_end * decay
+    # to_end = exp(cum_end - cum_s) dt_s: -g at s, the sum of g at the chunk's end
+    g = d_to_end * to_end
+    end = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1) == q - 1
+    dcum_ref[0, 0] = jnp.where(end, g.sum(1, keepdims=True), 0.0) - g
+
+    @pl.when((block + 1) * heads % per_group == 0)
+    def _():
+        db_ref[0, 0] = db_acc_ref[...].astype(db_ref.dtype)
+
+
+# The four kernels' wrappers are jitted, so that a step traces and lowers each kernel
+# once and not once a layer.
+@jax.jit
+def _state_fwd(x, dt, cum, b):
+    specs, common, tiles, _ = _specs(x, dt, b)
+    bt, chunks, heads, _ = dt.shape
+    return pl.pallas_call(
+        _state_fwd_kernel,
+        in_specs=[specs[k] for k in ("x", "step", "step", "b")], out_specs=specs["state"],
+        out_shape=jax.ShapeDtypeStruct((bt, chunks, heads, x.shape[2] // heads, b.shape[3]),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        name=f"ssd_state_fwd_{tiles}", **common,
+    )(x, dt, cum, b)
+
+
+@jax.jit
+def _state_bwd(residuals, down):
+    x, dt, cum, b = residuals
+    specs, common, tiles, per_group = _specs(x, dt, b)
+    q, n = dt.shape[3], b.shape[3]
+    return pl.pallas_call(
+        functools.partial(_state_bwd_kernel, per_group=per_group),
+        in_specs=[specs[k] for k in ("x", "step", "step", "b", "state")],
+        out_specs=[specs[k] for k in ("x", "step", "step", "b")],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype) for v in residuals],
+        scratch_shapes=[pltpu.VMEM((q, n), jnp.float32)],
+        # a group's blocks of heads, in turn, sum its dB in the scratch
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        name=f"ssd_state_bwd_{tiles}", **common,
+    )(x, dt, cum, b, down)
+
+
+@jax.custom_vjp
+def ssd_state(x, dt, cum, b):
+    """Each chunk's own part of the state at its end, `sum_s exp(cum_end - cum_s) dt_s x_s
+    B_s^T`: x (Bt, L, H P) and b (Bt, G, L, N) in the compute dtype, dt and cum (Bt, c, H,
+    Q) float32 -> (Bt, c, H, P, N) float32. A pallas kernel each way; the backward keeps
+    the inputs alone."""
+    return _state_fwd(x, dt, cum, b)
+
+
+ssd_state.defvjp(lambda *args: (_state_fwd(*args), args), _state_bwd)
+
+
+@jax.jit
+def _chunk_fwd(x, dt, cum, b, c, state):
+    specs, common, tiles, _ = _specs(x, dt, b)
+    return pl.pallas_call(
+        _chunk_fwd_kernel,
+        in_specs=[specs[k] for k in ("x", "step", "step", "b", "b", "state")],
+        out_specs=specs["x"], out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        name=f"ssd_chunk_fwd_{tiles}", **common,
+    )(x, dt, cum, b, c, state)
+
+
+@jax.jit
+def _chunk_bwd(residuals, dy):
+    x, dt, cum, b, c, state = residuals
+    specs, common, tiles, per_group = _specs(x, dt, b)
+    q, n = dt.shape[3], state.shape[4]
+    return pl.pallas_call(
+        functools.partial(_chunk_bwd_kernel, per_group=per_group),
+        in_specs=[specs[k] for k in ("x", "step", "step", "b", "b", "state", "x")],
+        out_specs=[specs[k] for k in ("x", "step", "step", "b", "b", "state")],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype) for v in residuals],
+        scratch_shapes=[pltpu.VMEM((q, q), jnp.float32), pltpu.VMEM((q, n), jnp.float32)],
+        # a group's blocks of heads, in turn, sum its dB and dC in the scratch
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        name=f"ssd_chunk_bwd_{tiles}", **common,
+    )(x, dt, cum, b, c, state, dy)
+
+
+@jax.custom_vjp
+def ssd_chunk(x, dt, cum, b, c, state):
+    """Every chunk's output, `y[t] = sum_{s<=t} exp(cum_t - cum_s) (C_t . B_s) dt_s x_s +
+    exp(cum_t) C_t . S`, from its own inputs and the state S it starts from: x (Bt, L, H P),
+    b and c (Bt, G, L, N) in the compute dtype, dt and cum (Bt, c, H, Q) and the state (Bt,
+    c, H, P, N) float32 -> (Bt, L, H P) float32. Forward and backward are pallas kernels that
+    hold a chunk's (Q, Q) decay, `C B^T` and mix in VMEM; the backward keeps the inputs
+    alone and builds them again."""
+    return _chunk_fwd(x, dt, cum, b, c, state)
+
+
+ssd_chunk.defvjp(lambda *args: (_chunk_fwd(*args), args), _chunk_bwd)
+
+
 def ssd_chunked(x, dt, a, b, c, chunk: int):
     """The recurrence above in chunks of `chunk` positions.
 
@@ -92,51 +394,29 @@ def ssd_chunked(x, dt, a, b, c, chunk: int):
     decays `exp(sum of dt a over the chunk)`, (Bt, chunks, H) float32). The
     chunk, or the row where it is shorter than the chunk, tiles the row."""
     bt, length, heads, hp = x.shape
-    groups, n = b.shape[-2:]
-    per_group = heads // groups
+    n = b.shape[-1]
     q = min(chunk, length)
     if length % q:
         raise ValueError(f"{length} positions are no multiple of the chunk {q}")
     chunks = length // q
-    dtype = x.dtype
 
-    def by_chunk(v):  # (Bt, L, ...) -> (chunks, Bt, Q, ...)
-        return v.reshape(bt, chunks, q, *v.shape[2:]).swapaxes(0, 1)
+    # the steps and their running sums within each chunk, (Bt, c, H, Q)
+    steps = dt.reshape(bt, chunks, q, heads).swapaxes(2, 3)
+    cum = jnp.cumsum(steps * a[:, None], axis=-1)
+    kept = jnp.exp(cum[..., -1])                          # (Bt, c, H)
+    x = x.reshape(bt, length, heads * hp)
+    b, c = (v.astype(x.dtype).swapaxes(1, 2) for v in (b, c))   # (Bt, G, L, N)
+    own = ssd_state(x, steps, cum, b)                     # (Bt, c, H, P, N)
 
-    da = by_chunk(dt * a).transpose(0, 1, 3, 2)          # (chunks, Bt, H, Q)
-    seen = jnp.tril(jnp.ones((q, q), bool))               # (t, s): s <= t
+    # the state each chunk starts from: S_k = kept_k S_{k-1} + own_k, in float32
+    def carry(state, inputs):
+        kept_k, own_k = inputs
+        return state * kept_k[..., None, None] + own_k, state
 
-    @jax.checkpoint
-    def one_chunk(state, inputs):
-        xq, daq, dtq, bq, cq = inputs                     # state (Bt, H, P, N) f32
-        cum = jnp.cumsum(daq, axis=-1)                    # (Bt, H, Q)
-        # within the chunk: C_t . B_s exp(cum_t - cum_s) dt_s, s <= t
-        decay = jnp.exp(jnp.where(seen, cum[..., :, None] - cum[..., None, :], -jnp.inf))
-        cb = jnp.einsum("btgn,bsgn->bgts", cq, bq, preferred_element_type=jnp.float32)
-        mix = (decay.reshape(bt, groups, per_group, q, q) * cb[:, :, None]
-               * dtq.transpose(0, 2, 1).reshape(bt, groups, per_group, 1, q))
-        xg = xq.reshape(bt, q, groups, per_group, hp)
-        y = jnp.einsum("bgkts,bsgkp->btgkp", mix.astype(dtype), xg,
-                       preferred_element_type=jnp.float32)
-        # the carried state, read at every position of the chunk
-        sg = state.reshape(bt, groups, per_group, hp, n).astype(dtype)
-        carried = jnp.einsum("btgn,bgkpn->btgkp", cq, sg, preferred_element_type=jnp.float32)
-        y = y + carried * jnp.exp(cum).transpose(0, 2, 1).reshape(bt, q, groups, per_group, 1)
-        # the chunk's own part of the state at its end
-        to_end = (jnp.exp(cum[..., -1:] - cum) * dtq.transpose(0, 2, 1))  # (Bt, H, Q)
-        weighted = (xg * to_end.transpose(0, 2, 1).reshape(bt, q, groups, per_group, 1)
-                    ).astype(dtype)
-        own = jnp.einsum("bsgkp,bsgn->bgkpn", weighted, bq, preferred_element_type=jnp.float32)
-        kept = jnp.exp(cum[..., -1])                      # (Bt, H)
-        state = state * kept[..., None, None] + own.reshape(bt, heads, hp, n)
-        return state, (y.reshape(bt, q, heads, hp), kept)
-
-    state0 = jnp.zeros((bt, heads, hp, n), jnp.float32)
-    _, (y, kept) = jax.lax.scan(
-        one_chunk, state0,
-        (by_chunk(x), da, by_chunk(dt), by_chunk(b.astype(dtype)), by_chunk(c.astype(dtype))))
-    y = y.swapaxes(0, 1).reshape(bt, length, heads, hp)
-    return y, kept.swapaxes(0, 1)
+    _, before = jax.lax.scan(carry, jnp.zeros((bt, heads, hp, n), jnp.float32),
+                             (kept.swapaxes(0, 1), own.swapaxes(0, 1)))
+    y = ssd_chunk(x, steps, cum, b, c, before.swapaxes(0, 1))
+    return y.reshape(bt, length, heads, hp), kept
 
 
 def _uniform(lo: float, hi: float):

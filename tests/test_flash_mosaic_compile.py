@@ -9,6 +9,7 @@ load the TPU's library, and every xdist worker imports this file."""
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -129,18 +130,27 @@ def test_xla_backward_compiles_for_the_v5e_under_its_scope(one_chip, shape, mask
     assert compiled.memory_analysis().temp_size_in_bytes <= operands + 6 * tile
 
 
-def test_a_mamba2_block_compiles_for_the_v5e_within_its_temporaries(one_chip):
+def test_a_mamba2_block_compiles_for_the_v5e_within_its_temporaries(one_chip, monkeypatch):
     """`granite4h-train-8k`'s Mamba-2 block (`models/granite_hybrid.py` under `nn.remat`, the
     mixer of `parallel/ssm.py`) forward and backward at 8,192 positions and the published
-    widths: the five scopes are in the program, and the temporaries stay near one chunk's
-    working set because the scan's body is checkpointed (1.27 GB; 4.09 GB when the scan
-    kept every chunk's decay and products for its backward)."""
+    widths (64 heads of 64, state 128, chunks of 256): the five scopes are in the program,
+    the scan's four kernels (each chunk's own state and each chunk's output, forward and
+    backward) compile under its scope, `train_ssm.kind_of` finds the kernels' forward, the
+    forward `remat` runs again and the backward, and the temporaries stay under 2 GB
+    (1.39 GB: no chunk's (Q, Q) decay or mix reaches HBM)."""
     import flax.linen as nn
 
+    from benchmarks.layer_metrics import train_ssm
     from kubeflow_tpu.models.granite_hybrid import MAMBA, GraniteHybridBlock, GraniteHybridConfig
 
-    block = nn.remat(GraniteHybridBlock, static_argnums=(2,), policy=ra.FLASH_REMAT_POLICY)(
-        GraniteHybridConfig(num_layers=10, dtype=jnp.bfloat16, remat=True), MAMBA)
+    class Stage(nn.Module):  # one layer, named as the model names its layers
+        @nn.compact
+        def __call__(self, x, train):
+            return nn.remat(GraniteHybridBlock, static_argnums=(2,), policy=ra.FLASH_REMAT_POLICY)(
+                GraniteHybridConfig(num_layers=10, dtype=jnp.bfloat16, remat=True), MAMBA,
+                name="layer_0")(x, train)
+
+    block = Stage()
     x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16, sharding=one_chip)
     params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
                           jax.eval_shape(lambda k, x: block.init(k, x, False), jax.random.PRNGKey(0), x))
@@ -149,8 +159,18 @@ def test_a_mamba2_block_compiles_for_the_v5e_within_its_temporaries(one_chip):
         y, _ = block.apply(p, x, True, mutable=["ssm_state"])
         return (y.astype(jnp.float32) ** 2).mean()
 
-    compiled = jax.jit(jax.value_and_grad(loss)).lower(params, x).compile()
+    def _train_step(p, x):  # the step's name, as `train_ssm` reads a trace's
+        return jax.value_and_grad(loss)(p, x)
+
+    # the kernels ask the backend whether to interpret; here the CPU answers
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(_train_step).lower(params, x).compile()
     text = compiled.as_text()
     assert all(f"/mamba/{scope}/" in text for scope in
                ("ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out_proj"))
+    kernels = set(re.findall(r'op_name="([^"]*/ssd_\w+_q256_h8/pallas_call)"', text))
+    assert {n.rsplit("/", 2)[1] for n in kernels} == {
+        f"ssd_{part}_{way}_q256_h8" for part in ("state", "chunk") for way in ("fwd", "bwd")}
+    assert all("/mamba/ssm.scan/" in n for n in kernels)
+    assert {train_ssm.kind_of(n) for n in kernels} == set(train_ssm.SCAN_KINDS)
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9

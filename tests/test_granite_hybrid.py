@@ -22,8 +22,8 @@ from benchmarks import reference_granite as reference
 from benchmarks.families import granite_hybrid as family
 from kubeflow_tpu.models import GraniteHybridConfig, GraniteHybridLM
 from kubeflow_tpu.models.gpt import causal_lm_loss
-from kubeflow_tpu.parallel.ssm import (SSM_STATE, STEP_INIT, Mamba2Mixer,
-                                       causal_conv1d, ssd_chunked, ssm_counters)
+from kubeflow_tpu.parallel.ssm import (SSM_STATE, STEP_INIT, Mamba2Mixer, causal_conv1d,
+                                       ssd_chunk, ssd_chunked, ssd_state, ssm_counters)
 
 ROOT = Path(__file__).resolve().parents[1]
 #: the benchmark's keys at a test's size: both layer kinds, chunks of 8 over rows of 32
@@ -72,6 +72,106 @@ def test_the_programs_scan_is_the_recurrence_at_any_chunk_length(chunk):
     np.testing.assert_allclose(got[0], want, rtol=1e-4, atol=5e-5)
     q = min(chunk, 40)
     assert kept.shape == (1, 40 // q, 4)
+
+
+@pytest.mark.parametrize("length,chunk,groups", [
+    (32, 8, 1), (32, 8, 2), (64, 32, 1), (64, 32, 2),
+    (32, 64, 2),    # a row of one chunk, shorter than the chunk
+    (512, 256, 1),  # two tiles of the kernels' walk in each chunk
+])
+def test_the_programs_scan_and_its_gradients_are_the_recurrences(length, chunk, groups):
+    """The chunk-parallel scan, its kernels interpreted, and the gradients with respect to
+    x, the steps, a, B and C against `jax.grad` of the recurrence position by position."""
+    x, dt, a, b, c = _scan_inputs(seed=4, length=length, groups=groups)
+    weights = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    got, _ = ssd_chunked(x[None], dt[None], a, b[None], c[None], chunk)
+    np.testing.assert_allclose(got[0], reference.ssd_by_position(x, dt, a, b, c),
+                               rtol=1e-4, atol=5e-5)
+
+    def program(*args):
+        x, dt, a, b, c = args
+        return (ssd_chunked(x[None], dt[None], a, b[None], c[None], chunk)[0][0] * weights).sum()
+
+    def recurrence(*args):
+        return (reference.ssd_by_position(*args) * weights).sum()
+
+    for got, want in zip(jax.grad(program, argnums=range(5))(x, dt, a, b, c),
+                         jax.grad(recurrence, argnums=range(5))(x, dt, a, b, c)):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4 * float(jnp.abs(want).max()))
+
+
+def _chunk_by_position(x, dt, cum, b, c, state):
+    """What `ssd_chunk` computes, position by position: in each chunk the recurrence from
+    the state it is given, `S_t = exp(cum_t - cum_{t-1}) S_{t-1} + dt_t x_t B_t^T` with
+    `cum_{-1} = 0`, and `y_t = S_t C_t`; in `ssd_chunk`'s layouts."""
+    bt, chunks, heads, q = dt.shape
+    hp, n = state.shape[3:]
+    per_group = heads // b.shape[1]
+    xs = x.reshape(bt, chunks, q, heads, hp)
+    bs, cs = (jnp.repeat(v, per_group, axis=1).reshape(bt, heads, chunks, q, n) for v in (b, c))
+
+    def one(xq, dtq, cumq, bq, cq, s0):  # (Q, P), (Q,), (Q,), (Q, N), (Q, N), (P, N)
+        def step(carry, inputs):
+            s, before = carry
+            xt, dtt, cumt, bt_, ct = inputs
+            s = jnp.exp(cumt - before) * s + dtt * xt[:, None] * bt_[None, :]
+            return (s, cumt), s @ ct
+        return jax.lax.scan(step, (s0, 0.0), (xq, dtq, cumq, bq, cq))[1]
+
+    over = jax.vmap(jax.vmap(jax.vmap(one, in_axes=(1, 0, 0, 0, 0, 0)),   # heads
+                             in_axes=(0, 0, 0, 1, 1, 0)),                  # chunks
+                    in_axes=(0, 0, 0, 0, 0, 0))                            # rows
+    y = over(xs, dt, cum, bs, cs, state)                  # (Bt, c, H, Q, P)
+    return y.transpose(0, 1, 3, 2, 4).reshape(x.shape)
+
+
+def _kernel_inputs(q, groups, seed):
+    """x, the steps, their running sums, B, C and a state for `ssd_chunk` and `ssd_state`:
+    two rows of two chunks, four heads of 8, a state of 6."""
+    bt, chunks, heads, hp, n = 2, 2, 4, 8, 6
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    x = jax.random.normal(k[0], (bt, chunks * q, heads * hp))
+    dt = jax.nn.softplus(jax.random.normal(k[1], (bt, chunks, heads, q)) - 1.0)
+    cum = jnp.cumsum(-dt * jax.random.uniform(k[2], (heads, 1), minval=0.2, maxval=1.0), -1)
+    b, c = (jax.random.normal(kk, (bt, groups, chunks * q, n)) for kk in k[3:5])
+    return x, dt, cum, b, c, jax.random.normal(k[5], (bt, chunks, heads, hp, n))
+
+
+def _assert_vjps_agree(f, want_f, args, names):
+    got, pull = jax.vjp(f, *args)
+    want, pull_want = jax.vjp(want_f, *args)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4 * float(jnp.abs(want).max()))
+    cotangent = jax.random.normal(jax.random.PRNGKey(11), want.shape)
+    for name, g, w in zip(names, pull(cotangent), pull_want(cotangent)):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4 * float(jnp.abs(w).max()),
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("q,groups", [(8, 1), (8, 2), (256, 2)])
+def test_the_chunk_kernels_backward_is_the_gradient_of_the_recurrence(q, groups):
+    """`ssd_chunk`'s hand-written backward (the second kernel, interpreted) against
+    `jax.vjp` of the same function written as the recurrence, for every input: x, the
+    steps, `cum` as an input of its own, B, C and the state each chunk starts from."""
+    _assert_vjps_agree(ssd_chunk, _chunk_by_position, _kernel_inputs(q, groups, q + groups),
+                       ("x", "dt", "cum", "b", "c", "state"))
+
+
+def _state_by_einsum(x, dt, cum, b):
+    """What `ssd_state` computes: `sum_s exp(cum_end - cum_s) dt_s x_s B_s^T` a chunk."""
+    bt, chunks, heads, q = dt.shape
+    per_group = heads // b.shape[1]
+    xs = x.reshape(bt, chunks, q, heads, -1)
+    bs = jnp.repeat(b, per_group, axis=1).reshape(bt, heads, chunks, q, -1)
+    to_end = jnp.exp(cum[..., -1:] - cum) * dt            # (Bt, c, H, Q)
+    return jnp.einsum("bchs,bcshp,bhcsn->bchpn", to_end, xs, bs)
+
+
+@pytest.mark.parametrize("q,groups", [(8, 1), (8, 2), (256, 2)])
+def test_the_state_kernels_backward_is_the_gradient_of_the_chunks_state(q, groups):
+    """`ssd_state`, each chunk's own state at its end, and its hand-written backward
+    (interpreted) against `jax.vjp` of the sum written out, for x, the steps, `cum` and B."""
+    x, dt, cum, b, _, _ = _kernel_inputs(q, groups, 2 * q + groups)
+    _assert_vjps_agree(ssd_state, _state_by_einsum, (x, dt, cum, b), ("x", "dt", "cum", "b"))
 
 
 @pytest.mark.parametrize("chunk", [7, 16])
